@@ -97,36 +97,3 @@ def _world_size_from_env():
                 "rank variable: set PADDLE_TRAINER_ID or RANK per process")
         return nnodes
     return 1
-
-
-def _install_shard_map_compat():
-    """jax < 0.6 ships shard_map only under jax.experimental and without the
-    new-API ``axis_names=`` keyword; the compiled pipeline / ring attention
-    (distributed/pipelining.py, distributed/ring_attention.py) use the new
-    top-level spelling. Alias it, mapping ``axis_names`` (the MANUAL axes)
-    onto the old ``auto=`` complement. No-op on jax builds that already have
-    jax.shard_map."""
-    if hasattr(jax, "shard_map"):
-        return
-    try:
-        from jax.experimental.shard_map import shard_map as _sm
-    except Exception:  # noqa: BLE001 - no experimental module: nothing to do
-        return
-
-    def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None, **kw):
-        auto = frozenset()
-        if axis_names:
-            auto = (frozenset(getattr(mesh, "axis_names", ()))
-                    - frozenset(axis_names))
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False, auto=auto)
-
-    jax.shard_map = shard_map
-    if not hasattr(jax.lax, "pcast"):
-        # pcast only adjusts the varying-manual-axes TYPE for the new API's
-        # vma checking; with check_rep=False (the only mode the old
-        # shard_map runs here) it is semantically the identity
-        jax.lax.pcast = lambda x, *a, **k: x
-
-
-_install_shard_map_compat()

@@ -18,7 +18,7 @@ Model (error bars documented in docs/ir_analysis.md):
   invar stays caller-owned and resident throughout;
 - fusion discount: a single-consumer elementwise/layout intermediate
   never materializes (producer-consumer fusion keeps it in registers);
-- call-like eqns (pjit, shard_map, remat) recurse, and the inner walk
+- call-like eqns (jit, shard_map, remat) recurse, and the inner walk
   may free donated operands mid-body — the ZeRO step's full-precision
   grads die into their reduce-scatters long before the gathered
   updates materialize; ``cond`` contributes its max branch,
@@ -42,7 +42,7 @@ from __future__ import annotations
 import json
 import os
 
-from .ir import AnalysisError, _aval_bytes, trace
+from .ir import AnalysisError, _aval_bytes, is_jit_call, trace
 
 __all__ = ["HBMBudgetExceeded", "estimate", "estimate_fn",
            "assert_hbm_budget", "measure_compiled", "load_budgets",
@@ -53,7 +53,7 @@ DEFAULT_BUDGETS = os.path.join(os.path.dirname(os.path.abspath(__file__)),
 
 # eqns whose single body runs exactly once inline — the walk threads
 # liveness (and donation credit) straight through them
-_INLINE_CALLS = {"pjit", "shard_map", "remat", "remat2", "checkpoint",
+_INLINE_CALLS = {"shard_map", "remat", "remat2", "checkpoint",
                  "closed_call", "core_call", "custom_jvp_call",
                  "custom_vjp_call", "custom_vjp_call_jaxpr"}
 
@@ -197,7 +197,7 @@ def _walk(jaxpr, invar_bytes, freeable, greedy):
         eqn = eqns[i]
         name = eqn.primitive.name
         subs = _sub_jaxprs(eqn)
-        if subs and name in _INLINE_CALLS:
+        if subs and (name in _INLINE_CALLS or is_jit_call(eqn)):
             _kind, sub = subs[0]
             consumed = list(eqn.invars)[-len(sub.invars):] \
                 if len(eqn.invars) >= len(sub.invars) else list(eqn.invars)

@@ -149,6 +149,16 @@ def _fraction_of(arg):
     return num / den if den else 1.0
 
 
+def is_jit_call(eqn):
+    """True for the eqn a ``jax.jit`` callable traces to. Matched against
+    the primitive OBJECT, not its name: the name has changed between jax
+    releases (a string match then sees nothing, silently), while a moved
+    or renamed object fails this import loudly."""
+    from jax.extend.core.primitives import jit_p
+
+    return eqn.primitive is jit_p
+
+
 def trace(fn, args, name, donate_argnums=None):
     """Trace ``fn(*args)`` to a :class:`ProgramIR` (abstract eval only —
     no compile, no dispatch). A jitted ``fn`` contributes its REAL
@@ -168,9 +178,9 @@ def trace(fn, args, name, donate_argnums=None):
     fractions = {id(v): _fraction_of(a)
                  for v, a in zip(jaxpr.invars, flat_args)}
 
-    # a jitted callable traces to ONE pjit eqn wrapping the program; its
+    # a jitted callable traces to ONE jit eqn wrapping the program; its
     # params carry the donation mask the runtime actually aliases by
-    if (len(jaxpr.eqns) == 1 and jaxpr.eqns[0].primitive.name == "pjit"
+    if (len(jaxpr.eqns) == 1 and is_jit_call(jaxpr.eqns[0])
             and list(jaxpr.eqns[0].outvars) == list(jaxpr.outvars)):
         eqn = jaxpr.eqns[0]
         inner = eqn.params["jaxpr"].jaxpr

@@ -46,7 +46,7 @@ Importing this module costs stdlib only; jax loads on first use.
 """
 from __future__ import annotations
 
-from .ir import AnalysisError, ProgramIR
+from .ir import AnalysisError, ProgramIR, is_jit_call
 from .passes import EXPENSIVE_PRIMS as _CSE_PRIMS
 from .passes import eqn_structural_key as _cse_key
 
@@ -135,15 +135,15 @@ class _Ctx:
 
 
 def _is_var(v):
-    import jax
+    from jax.extend.core import Var
 
-    return isinstance(v, jax.core.Var)
+    return isinstance(v, Var)
 
 
 def _is_drop(v):
-    import jax
+    from jax._src.core import DropVar
 
-    return isinstance(v, jax.core.DropVar)
+    return isinstance(v, DropVar)
 
 
 def _lossless_roundtrip(src_dtype, mid_dtype):
@@ -217,7 +217,7 @@ def _rewrite_subjaxprs(eqn, path, i, ctx):
     """Recurse the rewrites into an eqn's sub-jaxprs, rebuilding params.
     Sub-jaxpr interfaces (invars/outvars) are never changed, so the
     enclosing eqn's shardings / donation / carry structure stay valid."""
-    import jax
+    from jax.extend.core import ClosedJaxpr
 
     slots = _sub_slots(eqn)
     if not slots:
@@ -234,8 +234,8 @@ def _rewrite_subjaxprs(eqn, path, i, ctx):
                     if path else f"{eqn.primitive.name}[{i}].{slot}")
         new_inner = _rewrite_level(inner, sub_path, ctx)
         if new_inner is not inner:
-            if isinstance(item, jax.core.ClosedJaxpr):
-                items[j] = jax.core.ClosedJaxpr(new_inner, item.consts)
+            if isinstance(item, ClosedJaxpr):
+                items[j] = ClosedJaxpr(new_inner, item.consts)
             else:
                 items[j] = new_inner
             new_params[key] = (tuple(items)
@@ -417,7 +417,9 @@ def _outline(jaxpr, eqns, outvars, path, ctx, min_len=_OUTLINE_MIN):
     as one region. Contiguity keeps the rewrite trivially
     order-preserving; the run's external inputs/outputs become the
     closure's interface."""
-    import jax
+    from jax._src.core import new_jaxpr_eqn
+    from jax.extend.core import ClosedJaxpr
+    from jax.extend.core.primitives import closed_call_p
 
     out = []
     outlined = 0
@@ -459,10 +461,12 @@ def _outline(jaxpr, eqns, outvars, path, ctx, min_len=_OUTLINE_MIN):
             continue
         sub_jaxpr = jaxpr.replace(constvars=[], invars=ext_in,
                                   outvars=ext_out, eqns=run,
-                                  effects=set(), debug_info=None)
-        closed = jax.core.ClosedJaxpr(sub_jaxpr, [])
-        call = jax.core.new_jaxpr_eqn(
-            ext_in, ext_out, jax.core.closed_call_p,
+                                  effects=set(),
+                                  debug_info=jaxpr.debug_info
+                                  .with_unknown_names())
+        closed = ClosedJaxpr(sub_jaxpr, [])
+        call = new_jaxpr_eqn(
+            ext_in, ext_out, closed_call_p,
             dict(call_jaxpr=closed), closed.effects,
             run[-1].source_info)
         out.append(call)
@@ -512,13 +516,13 @@ def optimize_jaxpr(jaxpr, name="<jaxpr>", rules=None, allow_lossy=False):
 def optimize_closed(closed, name="<fn>", rules=None, allow_lossy=False):
     """Rewrite a ClosedJaxpr (consts preserved). Returns
     ``(new_closed, [AppliedRewrite])``."""
-    import jax
+    from jax.extend.core import ClosedJaxpr
 
     new, applied = optimize_jaxpr(closed.jaxpr, name=name, rules=rules,
                                   allow_lossy=allow_lossy)
     if new is closed.jaxpr:
         return closed, applied
-    return jax.core.ClosedJaxpr(new, closed.consts), applied
+    return ClosedJaxpr(new, closed.consts), applied
 
 
 def optimize_program(program, rules=None, allow_lossy=False):
@@ -546,12 +550,13 @@ def optimize_jitted(fn, args, name="<fn>", rules=None, allow_lossy=False,
     callable with the ORIGINAL call signature and output pytree.
 
     With ``rejit=True`` (default) the rebuilt program is one
-    ``jax.jit`` whose donation mask is lifted from the traced pjit eqn
+    ``jax.jit`` whose donation mask is lifted from the traced jit eqn
     — the one-compiled-program invariant holds (warm calls never
     recompile; the tier-1 sanitize test pins it). Returns
     ``(opt_fn, OptimizeResult)``. Raises :class:`AnalysisError` when
     the trace fails (same typing as :func:`~.ir.trace`)."""
     import jax
+    from jax.extend.core import jaxpr_as_fun
 
     try:
         closed = jax.make_jaxpr(fn)(*args)
@@ -569,7 +574,7 @@ def optimize_jitted(fn, args, name="<fn>", rules=None, allow_lossy=False,
                             count_eqns(new_closed.jaxpr), rbefore,
                             count_regions(new_closed.jaxpr))
 
-    raw = jax.core.jaxpr_as_fun(new_closed)
+    raw = jaxpr_as_fun(new_closed)
     if rejit:
         donate = _donated_flat_indices(new_closed.jaxpr)
         raw = jax.jit(raw, donate_argnums=donate)
@@ -584,13 +589,13 @@ def optimize_jitted(fn, args, name="<fn>", rules=None, allow_lossy=False,
 
 
 def _donated_flat_indices(outer_jaxpr):
-    """Map a traced pjit eqn's ``donated_invars`` mask back onto the
+    """Map a traced jit eqn's ``donated_invars`` mask back onto the
     OUTER jaxpr's invar positions (= the flat argument positions of the
     rebuilt callable), so re-jitting preserves the original donation."""
     donate = []
     pos = {id(v): k for k, v in enumerate(outer_jaxpr.invars)}
     for eqn in outer_jaxpr.eqns:
-        if eqn.primitive.name != "pjit":
+        if not is_jit_call(eqn):
             continue
         mask = eqn.params.get("donated_invars")
         if not mask:

@@ -42,7 +42,7 @@ from __future__ import annotations
 import math
 
 from . import collectives as _coll
-from .ir import IRPass
+from .ir import IRPass, is_jit_call
 
 __all__ = ["PrecisionFlow", "NumericHazard", "LossScaleCoverage",
            "REDUCED_FLOATS"]
@@ -612,9 +612,10 @@ class NumericHazard(IRPass):
                     default=1)
             self._set(env, eqn, _VR(0.0, float(max(0, n - 1))))
             return
-        if name in ("pjit", "shard_map", "custom_jvp_call",
-                    "custom_vjp_call", "custom_vjp_call_jaxpr",
-                    "remat", "checkpoint", "closed_call", "core_call"):
+        if is_jit_call(eqn) or name in (
+                "shard_map", "custom_jvp_call", "custom_vjp_call",
+                "custom_vjp_call_jaxpr", "remat", "checkpoint",
+                "closed_call", "core_call"):
             self._call(program, path, i, eqn, ins, env, out,
                        producer, frame)
             return

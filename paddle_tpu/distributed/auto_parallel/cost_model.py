@@ -38,8 +38,8 @@ class HardwareProfile:
         self.hbm_bw = float(hbm_bw)
         self.ici_bw = float(ici_bw)
         self.dcn_bw = float(dcn_bw)
-        # achievable fraction of peak on large matmuls (measured: bench.py
-        # sustains 0.598 MFU on v5e — see PERF.md)
+        # assumed achievable fraction of peak on large matmuls; no measured
+        # figure stands behind it yet (PERF.md)
         self.mfu_ceiling = float(mfu_ceiling)
 
     @classmethod

@@ -37,37 +37,17 @@ def _mp_mesh_axis(group=None):
     return mesh.jax_mesh(), "mp"
 
 
-def _abstract_mesh():
-    """The trace context's abstract mesh, or None when this jax has no usable
-    abstract-mesh API. jax 0.4.37 ships ``jax._src.mesh.get_abstract_mesh`` as
-    a stub that returns None/() and does not re-export it from ``jax.sharding``
-    — calling the re-export raised AttributeError at every traced TP
-    constraint, which broke the whole tensor-parallel training path (the
-    pre-existing gpt_hybrid failure). On such versions the concrete-mesh
-    constraint below is the supported spelling, including inside shard_map
-    bodies whose specs name only non-manual axes."""
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_am is None:
-        return None
-    try:
-        return get_am()
-    except Exception:  # noqa: BLE001 - version skew: fall back to concrete
-        return None
-
-
 def _constrain(v, mesh, spec):
     """Apply a sharding constraint: device_put in eager, with_sharding_constraint traced.
 
     Inside a shard_map body (e.g. TP layers running within the compiled pipeline's
     manual pp axis) the constraint must be expressed on the context's abstract mesh —
     whose axis types mark the manual axes — with manual axes dropped from the spec;
-    a constraint over the concrete mesh would type pp as Auto and fail vma checks.
-    On jax builds without the abstract-mesh API the concrete-mesh constraint is
-    used directly (valid there: manual axes are simply absent from mp specs)."""
+    a constraint over the concrete mesh would type pp as Auto and fail vma checks."""
     if isinstance(v, jax.core.Tracer):
-        am = _abstract_mesh()
-        manual = set(getattr(am, "manual_axes", ()) or ())
-        if am is not None and not getattr(am, "empty", True) and manual:
+        am = jax.sharding.get_abstract_mesh()
+        manual = set(am.manual_axes)
+        if manual:
             cleaned = []
             for entry in tuple(spec):
                 if isinstance(entry, (tuple, list)):

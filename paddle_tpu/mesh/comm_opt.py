@@ -476,7 +476,7 @@ def alltoall_reshard(value, jax_mesh, axis, src_dim, dst_dim,
     ``apply_raw``).
     """
     import jax
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     size = jax_mesh.shape[axis]
@@ -500,7 +500,7 @@ def alltoall_reshard(value, jax_mesh, axis, src_dim, dst_dim,
 
         prog = jax.jit(shard_map(
             body, mesh=jax_mesh, in_specs=P(*cur_spec),
-            out_specs=P(*dst_spec), check_rep=False))
+            out_specs=P(*dst_spec), check_vma=False))
         with _A2A_LOCK:
             # racing builders of the same key collapse to one program
             prog = _A2A_PROGRAMS.setdefault(key, prog)

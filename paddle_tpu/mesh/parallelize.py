@@ -25,7 +25,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 # the collective census shares ONE vocabulary with graftir's GI001 pass
@@ -50,6 +50,18 @@ def _dp_axis_of(ctx):
         if ctx.axis_size(name) > 1:
             return name
     return ctx.manual_axes[0] if ctx.manual_axes else ctx.axis_names[0]
+
+
+def _mesh_spec(x):
+    """The PartitionSpec ``x`` is laid out with over a mesh, or None when it
+    is unsharded (single-device, or replicated on every mesh axis)."""
+    from jax.sharding import NamedSharding
+
+    sh = getattr(x, "sharding", None)
+    if isinstance(sh, NamedSharding) and any(
+            e is not None for e in tuple(sh.spec)):
+        return sh.spec
+    return None
 
 
 def build_mesh_step(model, optimizer, loss_fn, ctx, batch, *,
@@ -297,11 +309,13 @@ def build_mesh_step(model, optimizer, loss_fn, ctx, batch, *,
                               .reshape(1, -1) for p in params]
                              if use_masters else master_values)
                 else:
-                    new_p = [p._value for p in params]
-                    new_a = [[optimizer._accumulators[id(p)][k] for k in ks]
-                             for p, ks in zip(params, acc_keys)]
-                    new_m = ([optimizer._master_weights[id(p)]
-                              for p in params]
+                    new_p = [_pin(p._value, p, sp)
+                             for p, sp in zip(params, tp_specs)]
+                    new_a = [[_pin(optimizer._accumulators[id(p)][k], p, sp)
+                              for k in ks]
+                             for p, ks, sp in zip(params, acc_keys, tp_specs)]
+                    new_m = ([_pin(optimizer._master_weights[id(p)], p, sp)
+                              for p, sp in zip(params, tp_specs)]
                              if use_masters else master_values)
                 out = (jax.lax.pmean(loss.value, dp_axis), new_p, new_a,
                        new_m)
@@ -319,6 +333,20 @@ def build_mesh_step(model, optimizer, loss_fn, ctx, batch, *,
                 for p in params:
                     optimizer._accumulators[id(p)] = saved_a[id(p)]
                 optimizer._master_weights = saved_m
+
+    # the auto (GSPMD) sharding each parameter arrives with, e.g. a TP
+    # weight's P(None, 'mp'). The step's outputs are pinned to it: left to
+    # itself GSPMD may hand a state back sharded on another dimension (seen
+    # on the TPU compiler for the square o_proj moments), and the next call
+    # — its inputs now laid out differently — compiles a second program
+    tp_specs = [_mesh_spec(p.value) for p in params]
+
+    def _pin(v, like, spec):
+        if spec is None or v.shape != tuple(like.shape):
+            return v
+        from ..distributed.fleet.mpu.mp_ops import _constrain
+
+        return _constrain(v, mesh, spec)
 
     p_specs = [P()] * len(params)
     a_specs = [[P(dp_axis) if s else P() for s in sh]
@@ -348,20 +376,22 @@ def build_mesh_step(model, optimizer, loss_fn, ctx, batch, *,
         body, mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
-        check_rep=False,
-        auto=frozenset(ctx.auto_axes))
+        axis_names=frozenset(mesh.axis_names) - frozenset(ctx.auto_axes),
+        check_vma=False)
     jitted = jax.jit(sm, donate_argnums=donate)
 
-    def _prep(v):
+    def _prep(v, like=None):
         """Pre-commit a replicated value to the mesh so the FIRST call's
         input layout already matches the donated outputs' — otherwise the
-        second step would pay a one-time layout-stabilization recompile."""
-        from jax.sharding import NamedSharding
-
-        sh = getattr(v, "sharding", None)
-        if isinstance(sh, NamedSharding) and any(
-                e is not None for e in tuple(sh.spec)):
+        second step would pay a one-time layout-stabilization recompile.
+        ``like`` is the parameter an optimizer state belongs to: a state of
+        its shape takes its mesh sharding (a TP-sharded weight's moments and
+        master are TP-sharded too, not a whole replica on every device)."""
+        if _mesh_spec(v) is not None:
             return v  # keep an existing mesh sharding (TP params)
+        if (like is not None and _mesh_spec(like) is not None
+                and v.shape == like.shape):
+            return jax.device_put(v, like.sharding)
         return ctx.place(v, spec=P())
 
     def state_fn():
@@ -375,7 +405,7 @@ def build_mesh_step(model, optimizer, loss_fn, ctx, batch, *,
                     v = ctx.place(zero.init_sharded_state(v, degree),
                                   spec=P(dp_axis))
                 else:
-                    v = _prep(v)
+                    v = _prep(v, like=p.value)
                 row.append(v)
             av.append(row)
         if use_masters:
@@ -384,7 +414,7 @@ def build_mesh_step(model, optimizer, loss_fn, ctx, batch, *,
                           optimizer._master_weights[id(p)], degree),
                           spec=P(dp_axis)) for p in params]
             else:
-                mv = [_prep(optimizer._master_weights[id(p)])
+                mv = [_prep(optimizer._master_weights[id(p)], like=p.value)
                       for p in params]
         else:
             mv = []

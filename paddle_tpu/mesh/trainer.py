@@ -125,7 +125,9 @@ class MeshTrainer:
     def _run_step(self, batch, record):
         self._last_batch = batch
         epoch = self._epoch
-        if self._dog is not None:
+        # the first call compiles, and a compile is not a hang: it can
+        # outlast any timeout sized for a step, so it runs unwatched
+        if self._dog is not None and self.handle._jitted._cache_size():
             with self._dog.watch(f"mesh.step[{self.step_idx}]"):
                 val = self._step_body(epoch, batch)
         else:

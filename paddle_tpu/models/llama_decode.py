@@ -134,6 +134,13 @@ class LlamaDecodeEngine:
         head = model.lm_head
         self.head_w = (jnp.swapaxes(self.emb, 0, 1) if head._tied
                        else head.weight.value)
+        # every weight the compiled programs read, as ONE pytree, passed to
+        # them as an argument (the last one): an array a jitted function
+        # merely closes over is embedded in the program as a literal, which
+        # at real widths makes a multi-GB module that cannot be serialized
+        # and a second copy of the weights on the device
+        self.weights = {"layers": self.layers, "emb": self.emb,
+                        "norm_w": self.norm_w, "head_w": self.head_w}
 
     # -- cache ---------------------------------------------------------------
     def init_cache(self, batch):
@@ -218,20 +225,20 @@ class LlamaDecodeEngine:
             attn = self._attend(q, ck, cv, pos_mask)
         return self._post_attn(p, x, attn), new_cache
 
-    def _forward(self, ids, cache, start_pos):
+    def _forward(self, ids, cache, start_pos, w):
         """ids: (B, S) absolute positions start_pos..start_pos+S-1."""
         B, S = ids.shape
-        x = self.emb[ids]
+        x = w["emb"][ids]
         positions = start_pos + jnp.arange(S)
         t = jnp.arange(self.max_len)[None, None, :]          # cache slots
         s = positions[None, :, None]                          # query slots
         pos_mask = jnp.broadcast_to(t <= s, (B, S, self.max_len))
         new_cache = []
-        for p, ckv in zip(self.layers, cache):
+        for p, ckv in zip(w["layers"], cache):
             x, ckv = self._block(p, x, ckv, positions, pos_mask)
             new_cache.append(ckv)
-        x = _rms(x, self.norm_w, self.eps)
-        return x @ self.head_w, new_cache
+        x = _rms(x, w["norm_w"], self.eps)
+        return x @ w["head_w"], new_cache
 
     # -- paged forward paths (models/paged_kv.py pool + tables) --------------
     def _qkv_rope(self, p, x, positions):
@@ -363,20 +370,20 @@ class LlamaDecodeEngine:
         False (speculation off) the flags are all zero and row 0 is the
         plain mixed step — one program serves both modes, so greedy
         outputs are bit-identical with speculation on or off."""
-        def run(pack, pools, tables, slot_ids, valid, chain):
+        def run(pack, pools, tables, slot_ids, valid, chain, w):
             # pack (2, T) int32: row 0 = token ids, row 1 = positions
             # (one fused upload per step — these are the only per-step
             # transfers; slot_ids/valid/chain are cached per composition)
             token_ids, positions = pack[0], pack[1]
-            x = self.emb[token_ids][:, None]        # (T, 1, hidden)
+            x = w["emb"][token_ids][:, None]        # (T, 1, hidden)
             row_tables = tables[slot_ids]           # (T, max_blocks)
             new_pools = []
-            for p, pool in zip(self.layers, pools):
+            for p, pool in zip(w["layers"], pools):
                 x, pool = self._block_paged_mixed(p, x, pool, row_tables,
                                                   positions, valid)
                 new_pools.append(pool)
-            x = _rms(x, self.norm_w, self.eps)
-            logits = (x @ self.head_w)[:, -1]
+            x = _rms(x, w["norm_w"], self.eps)
+            logits = (x @ w["head_w"])[:, -1]
             # argmax INSIDE the program: the scheduler transfers one
             # (2, T) int32 lane matrix per step, never a vocab logits row
             nt = jnp.argmax(logits, -1).astype(jnp.int32)
@@ -406,21 +413,21 @@ class LlamaDecodeEngine:
         emits ``k`` tokens per slot instead of one. Inactive rows write
         into the reserved null block (their table rows are zero), exactly
         like the single-step path."""
-        def run(pack, pools, tables):
+        def run(pack, pools, tables, w):
             # pack (2, B) int32: row 0 = current tokens, row 1 = per-row
             # positions (one fused upload per burst)
             tokens, lens = pack[0][:, None], pack[1]
 
             def body(carry, _):
                 toks, pools_c, lens_c = carry
-                x = self.emb[toks]
+                x = w["emb"][toks]
                 new_pools = []
-                for p, pool in zip(self.layers, pools_c):
+                for p, pool in zip(w["layers"], pools_c):
                     x, pool = self._block_paged_decode(p, x, pool, tables,
                                                        lens_c)
                     new_pools.append(pool)
-                x = _rms(x, self.norm_w, self.eps)
-                logits = (x @ self.head_w)[:, -1]
+                x = _rms(x, w["norm_w"], self.eps)
+                logits = (x @ w["head_w"])[:, -1]
                 nxt = jnp.argmax(logits, -1).astype(jnp.int32)
                 return (nxt[:, None], new_pools, lens_c + 1), nxt
 
@@ -432,30 +439,30 @@ class LlamaDecodeEngine:
 
     @functools.cached_property
     def _prefill_paged_jit(self):
-        def run(ids, pools, tables, lens):
-            x = self.emb[ids]
+        def run(ids, pools, tables, lens, w):
+            x = w["emb"][ids]
             new_pools = []
-            for p, pool in zip(self.layers, pools):
+            for p, pool in zip(w["layers"], pools):
                 x, pool = self._block_paged_prefill(p, x, pool, tables, lens)
                 new_pools.append(pool)
-            x = _rms(x, self.norm_w, self.eps)
-            return x @ self.head_w, new_pools
+            x = _rms(x, w["norm_w"], self.eps)
+            return x @ w["head_w"], new_pools
 
         return jax.jit(run, donate_argnums=(1,))
 
     @functools.cached_property
     def _step_paged_jit(self):
-        def run(token, pools, tables, pos):
+        def run(token, pools, tables, pos, w):
             # lens derives from pos INSIDE the trace: the engine decodes in
             # lockstep, so no per-token host-built array is needed
             lens = jnp.full((token.shape[0],), pos, jnp.int32)
-            x = self.emb[token]
+            x = w["emb"][token]
             new_pools = []
-            for p, pool in zip(self.layers, pools):
+            for p, pool in zip(w["layers"], pools):
                 x, pool = self._block_paged_decode(p, x, pool, tables, lens)
                 new_pools.append(pool)
-            x = _rms(x, self.norm_w, self.eps)
-            return (x @ self.head_w)[:, -1], new_pools
+            x = _rms(x, w["norm_w"], self.eps)
+            return (x @ w["head_w"])[:, -1], new_pools
 
         return jax.jit(run, donate_argnums=(1,))
 
@@ -479,13 +486,13 @@ class LlamaDecodeEngine:
     # -- public API ----------------------------------------------------------
     @functools.cached_property
     def _prefill_jit(self):
-        return jax.jit(lambda ids, cache: self._forward(ids, cache, 0))
+        return jax.jit(lambda ids, cache, w: self._forward(ids, cache, 0, w))
 
     @functools.cached_property
     def _step_jit(self):
         @functools.partial(jax.jit, donate_argnums=(1,))
-        def step(token, cache, pos):
-            logits, cache = self._forward(token, cache, pos)
+        def step(token, cache, pos, w):
+            logits, cache = self._forward(token, cache, pos, w)
             return logits[:, -1], cache
 
         return step
@@ -499,10 +506,10 @@ class LlamaDecodeEngine:
             pager.ensure_capacity([S] * B)
             lens = jnp.full((B,), S, jnp.int32)
             logits, pools = self._prefill_paged_jit(
-                ids, pools, pager.block_tables, lens)
+                ids, pools, pager.block_tables, lens, self.weights)
             return logits[:, -1], _PagedCache(pager, pools), S
         cache = self.init_cache(B)
-        logits, cache = self._prefill_jit(ids, cache)
+        logits, cache = self._prefill_jit(ids, cache, self.weights)
         return logits[:, -1], cache, S
 
     def decode_step(self, token, cache, pos):
@@ -538,10 +545,11 @@ class LlamaDecodeEngine:
                 raise
             logits, pools = self._step_paged_jit(
                 jnp.asarray(token, jnp.int32), pools,
-                pager.block_tables, jnp.asarray(pos, jnp.int32))
+                pager.block_tables, jnp.asarray(pos, jnp.int32),
+                self.weights)
             return logits, _PagedCache(pager, pools)
         return self._step_jit(jnp.asarray(token, jnp.int32), cache,
-                              jnp.asarray(pos, jnp.int32))
+                              jnp.asarray(pos, jnp.int32), self.weights)
 
     def _select(self, logits, temperature, top_k, top_p, key):
         """Greedy (temperature 0) or temperature/top-k/top-p sampling —
@@ -655,7 +663,7 @@ class LlamaDecodeEngine:
             pager.ensure_capacity(need)
             logits, pools = self._prefill_paged_jit(
                 ids, pools, pager.block_tables[::K],
-                jnp.full((B,), S, jnp.int32))
+                jnp.full((B,), S, jnp.int32), self.weights)
             logits = logits[:, -1]
             cache = _PagedCache(pager, pools)
             pos = S

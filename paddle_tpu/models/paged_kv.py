@@ -620,8 +620,14 @@ def paged_attention_decode(q, cache_k, cache_v, block_tables, seq_lens,
     Gathers each sequence's blocks into a [B, T_max, kv, D] view
     (T_max = max_blocks_per_seq * block_size) and masks t <= seq_lens[b]
     (inclusive: the current token was just written at position seq_lens).
-    XLA fuses gather + QK + softmax + PV; bandwidth matches the dense cache
-    read — the block indirection costs the index arithmetic only."""
+
+    QK and PV are written as multiply + reduce, not as einsums: every
+    (lane, kv head) pair has its OWN gathered K and V, so as a matmul each
+    is ``groups`` rows tall — one row for MHA — and the TPU compiler pads
+    that to 8 sublanes and materializes the padded f32 operand
+    ([8, B, T_max, kv, D]: 18 GB at 136 lanes x 1024 x 32 x 128, refused
+    outright for a v5e). The fused multiply-reduce reads the gathered
+    bf16 blocks once and keeps nothing wider than the logits."""
     B, n_q, D = q.shape
     nb, bs, n_kv, _ = cache_k.shape
     groups = n_q // n_kv
@@ -634,12 +640,11 @@ def paged_attention_decode(q, cache_k, cache_v, block_tables, seq_lens,
         scale = 1.0 / np.sqrt(D)
     # promote, don't demote: bf16 -> f32 for a stable softmax, f64 stays f64
     ct = jnp.promote_types(q.dtype, jnp.float32)
-    qg = q.reshape(B, n_kv, groups, D)
-    logits = jnp.einsum("bhgd,bthd->bhgt", qg.astype(ct),
-                        k.astype(ct)) * scale
-    t = jnp.arange(T)[None, None, None, :]
+    qg = q.reshape(B, 1, n_kv, groups, D).astype(ct)
+    logits = (qg * k.astype(ct)[:, :, :, None, :]).sum(-1) * scale  # B,T,h,g
+    t = jnp.arange(T)[None, :, None, None]
     mask = t <= seq_lens[:, None, None, None]
     logits = jnp.where(mask, logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhgt,bthd->bhgd", probs, v.astype(ct))
+    probs = jax.nn.softmax(logits, axis=1)
+    out = (probs[..., None] * v.astype(ct)[:, :, :, None, :]).sum(1)  # B,h,g,D
     return out.reshape(B, n_q, D).astype(q.dtype)

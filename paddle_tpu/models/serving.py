@@ -1388,7 +1388,7 @@ class ContinuousBatchingEngine:
         step = self._step_jit()
         out_dev, self._pools = step(
             jnp.asarray(pack_np), self._pools, self._pager.block_tables,
-            slots_dev, valid_dev, chain_dev)
+            slots_dev, valid_dev, chain_dev, self._inner.weights)
         if _sanitizers._state.numerics:
             self._san_steps += 1
             _sanitizers.numsan_check(
@@ -1626,7 +1626,8 @@ class ContinuousBatchingEngine:
         pack[0] = self._last_tok
         pack[1] = self.lens
         toks_dev, self._pools = self._burst_jit()(
-            jnp.asarray(pack), self._pools, self._pager.block_tables)
+            jnp.asarray(pack), self._pools, self._pager.block_tables,
+            self._inner.weights)
         if _sanitizers._state.numerics:
             self._san_steps += 1
             _sanitizers.numsan_check(
@@ -2068,16 +2069,16 @@ class StaticBatchEngine:
                 san.note_compile(f"serving.prefill[{self._san_tag}]",
                                  signature=key)
 
-            def run(ids, pools, row_tables, length):
-                x = e.emb[ids]
+            def run(ids, pools, row_tables, length, w):
+                x = w["emb"][ids]
                 lens1 = jnp.asarray([length], jnp.int32)
                 new_pools = []
-                for p, pool in zip(e.layers, pools):
+                for p, pool in zip(w["layers"], pools):
                     x, pool = e._block_paged_prefill(p, x, pool, row_tables,
                                                      lens1)
                     new_pools.append(pool)
-                x = _rms(x, e.norm_w, e.eps)
-                logits = x @ e.head_w
+                x = _rms(x, w["norm_w"], e.eps)
+                logits = x @ w["head_w"]
                 tok = jnp.argmax(logits[0, length - 1], -1)
                 return tok.astype(jnp.int32), new_pools
 
@@ -2093,14 +2094,14 @@ class StaticBatchEngine:
                 san.note_compile(f"serving.decode_step[{self._san_tag}]",
                                  signature="step")
 
-            def run(tokens, pools, tables, lens):
-                x = e.emb[tokens]
+            def run(tokens, pools, tables, lens, w):
+                x = w["emb"][tokens]
                 new_pools = []
-                for p, pool in zip(e.layers, pools):
+                for p, pool in zip(w["layers"], pools):
                     x, pool = e._block_paged_decode(p, x, pool, tables, lens)
                     new_pools.append(pool)
-                x = _rms(x, e.norm_w, e.eps)
-                logits = (x @ e.head_w)[:, -1]
+                x = _rms(x, w["norm_w"], e.eps)
+                logits = (x @ w["head_w"])[:, -1]
                 return jnp.argmax(logits, -1).astype(jnp.int32), new_pools
 
             cache["step"] = jax.jit(run, donate_argnums=(1,))
@@ -2145,7 +2146,7 @@ class StaticBatchEngine:
             row_tables = self._pager.block_tables[b:b + 1]
             tok_dev, self._pools = self._prefill_slot_jit(bucket)(
                 jnp.asarray(padded), self._pools, row_tables,
-                jnp.asarray(L, jnp.int32))
+                jnp.asarray(L, jnp.int32), self._inner.weights)
             tok = int(tok_dev)
             req.prefill_pos = L
             req.last_token = tok
@@ -2186,7 +2187,7 @@ class StaticBatchEngine:
         step = self._step_all_jit()
         toks_dev, self._pools = step(
             jnp.asarray(tokens), self._pools, self._pager.block_tables,
-            jnp.asarray(self.lens, jnp.int32))
+            jnp.asarray(self.lens, jnp.int32), self._inner.weights)
         toks = np.asarray(toks_dev)
         for b in active:
             req = self._slots[b]

@@ -57,20 +57,39 @@ def _math_sdpa(q, k, v, attn_mask=None, causal=False, dropout_key=None, dropout_
 def _use_pallas(q):
     if os.environ.get("PADDLE_TPU_DISABLE_PALLAS"):
         return False
-    try:
-        return jax.devices()[0].platform not in ("cpu",) and q.shape[1] >= 128
-    except Exception:
-        return False
+    return jax.devices()[0].platform != "cpu" and q.shape[1] >= 128
+
+
+def _flash(q, k, v, causal, scale):
+    """The Pallas kernel, run per head shard where the trace has auto
+    (GSPMD) mesh axes: a Mosaic kernel cannot be partitioned automatically,
+    and under ``mesh.parallelize``'s dp-manual / mp-auto step the TP layers
+    shard exactly the head dimension. Attention is independent per head, so
+    a nested shard_map over the auto axes is exact whatever they shard."""
+    from jax.sharding import PartitionSpec as P
+
+    from ...ops.pallas.flash_attention import flash_attention_fwd
+
+    def kernel(q, k, v):
+        return flash_attention_fwd(q, k, v, causal=causal, scale=scale)
+
+    am = jax.sharding.get_abstract_mesh()
+    auto = tuple(a for a in am.axis_names
+                 if a not in am.manual_axes and am.shape[a] > 1)
+    if not auto:
+        return kernel(q, k, v)
+    heads = P(None, None, auto if len(auto) > 1 else auto[0], None)
+    return jax.shard_map(kernel, in_specs=(heads, heads, heads),
+                         out_specs=heads, axis_names=frozenset(auto),
+                         check_vma=False)(q, k, v)
 
 
 @defop("flash_attention", amp_category="white")
 def _sdpa(q, k, v, attn_mask=None, dropout_key=None, dropout_p=0.0, causal=False,
           scale=None, use_pallas=False):
     if use_pallas and attn_mask is None and dropout_p == 0.0:
-        from ...ops.pallas.flash_attention import flash_attention_fwd
-
         try:
-            return flash_attention_fwd(q, k, v, causal=causal, scale=scale)
+            return _flash(q, k, v, causal, scale)
         except ValueError:
             # documented fallback contract: unsupported shapes -> math path.
             # anything else (lowering/VMEM/compile errors) must surface, not

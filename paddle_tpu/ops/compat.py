@@ -353,11 +353,9 @@ def standard_gamma(x, name=None):
 def binomial(count, prob, name=None):
     c = count.value if isinstance(count, Tensor) else jnp.asarray(count)
     p = prob.value if isinstance(prob, Tensor) else jnp.asarray(prob)
-    # float64 internally: jax<=0.4.37's BTRS sampler mixes python-float
-    # constants (f64 under x64) with the count dtype, so f32 counts hit
-    # "lax.clamp requires arguments to have the same dtypes"
-    return Tensor(jax.random.binomial(rng.next_key(), c.astype(jnp.float64),
-                                      p.astype(jnp.float64))
+    dt = p.dtype if jnp.issubdtype(p.dtype, jnp.floating) else jnp.float32
+    return Tensor(jax.random.binomial(rng.next_key(), c.astype(dt),
+                                      p.astype(dt))
                   .astype(jnp.int64))
 
 

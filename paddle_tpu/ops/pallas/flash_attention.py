@@ -28,18 +28,16 @@ def _i32(x):
 
 
 def _cdiv_i32(a, b):
-    # explicit int32 lax arithmetic: jnp operator promotion recurses inside the
-    # pallas kernel trace under x64 mode on some jax versions
+    # explicit int32 lax arithmetic: under x64 a python scalar in a jnp
+    # expression would promote the kernel's index math to i64, which Mosaic
+    # does not lower
     return jax.lax.div(jax.lax.add(a, _i32(b - 1)), _i32(b))
 
 
 def _interpret():
     if os.environ.get("PADDLE_TPU_PALLAS_INTERPRET"):
         return True
-    try:
-        return jax.devices()[0].platform != "tpu"
-    except Exception:
-        return True
+    return jax.devices()[0].platform == "cpu"
 
 
 # ---------------------------------------------------------------------------
@@ -307,8 +305,8 @@ def flash_attention_fwd(q, k, v, causal=False, scale=None,
     """
     B, Sq, Hq, D = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]
-    # 512x512 measured best on v5e at bench shapes (S=2048, D=128): 0.594 MFU
-    # vs 0.458 at 128x128 — bigger q/k tiles amortize the loop and fill the MXU
+    # 512x512 default tiles: bigger q/k tiles amortize the loop and fill the
+    # MXU. Not re-measured since the port to jax 0.9.0 (PERF.md)
     if block_q is None:
         block_q = int(os.environ.get("PADDLE_TPU_FLASH_BLOCK_Q", "512"))
     if block_k is None:

@@ -12,20 +12,14 @@ event times onto the host clock so both layers land in one timeline.
 
 Clock model: collect_device_events normalizes every event onto a
 trace-relative clock (earliest collected event = 0): the raw xplane epoch
-differs across builds (trace start on some, PROCESS start on the
-jax 0.4.37 CPU tracer), so the only portable anchor is the trace's own
-first event. The Profiler records host perf_counter_ns immediately after
+differs across tracers (trace start on some, PROCESS start on others), so
+the only portable anchor is the trace's own first event. The Profiler
+records host perf_counter_ns immediately after
 jax.profiler.start_trace returns (xla_t0_ns); device-absolute =
 xla_t0_ns + event.start_ns — the same translate-to-host-clock correlation
 the reference applies to CUPTI timestamps.
 
-Readers, tried in order (first available wins):
-
-1. ``jax.profiler.ProfileData`` — newer jax wheels bundle the xplane
-   reader;
-2. the raw ``xplane.pb`` proto via tensorflow's bundled
-   ``tsl.profiler.protobuf.xplane_pb2`` — jax 0.4.37 ships no reader, but
-   the wire format is the same XSpace proto.
+The reader is ``jax.profiler.ProfileData``, which the jax wheel bundles.
 """
 from __future__ import annotations
 
@@ -49,8 +43,8 @@ def _is_device_plane(name):
 
 
 def _iter_events_profile_data(path):
-    """(plane, line, name, start_ns, dur_ns, stats) via the bundled reader
-    of newer jax wheels. Raises ImportError when unavailable."""
+    """(plane, line, name, start_ns, dur_ns, stats) via jax's bundled
+    xplane reader."""
     from jax.profiler import ProfileData
 
     pd = ProfileData.from_file(path)
@@ -66,55 +60,11 @@ def _iter_events_profile_data(path):
                        float(ev.start_ns), float(ev.duration_ns), stats)
 
 
-def _stat_value(stat, stat_metadata):
-    """Decode one XStat: strings usually arrive as ref_value indices into
-    the plane's stat_metadata (string interning), scalars as oneof fields."""
-    which = stat.WhichOneof("value")
-    if which is None:
-        return None
-    if which == "ref_value":
-        meta = stat_metadata.get(stat.ref_value)
-        return meta.name if meta is not None else None
-    return getattr(stat, which)
-
-
-def _iter_events_proto(path):
-    """(plane, line, name, start_ns, dur_ns, stats) straight off the
-    XSpace proto — jax 0.4.37 writes the trace but ships no reader, so
-    parse with tensorflow's tsl xplane_pb2 (same wire format). Raises
-    ImportError when tensorflow's protos are unavailable."""
-    from tensorflow.tsl.profiler.protobuf import xplane_pb2
-
-    xs = xplane_pb2.XSpace()
-    with open(path, "rb") as f:
-        xs.ParseFromString(f.read())
-    for plane in xs.planes:
-        emeta = plane.event_metadata
-        smeta = plane.stat_metadata
-        for line in plane.lines:
-            base_ns = float(line.timestamp_ns)
-            for ev in line.events:
-                meta = emeta.get(ev.metadata_id)
-                name = meta.name if meta is not None else ""
-                stats = {}
-                for s in ev.stats:
-                    sm = smeta.get(s.metadata_id)
-                    if sm is not None:
-                        stats[sm.name] = _stat_value(s, smeta)
-                yield (plane.name, line.name, name,
-                       base_ns + ev.offset_ps / 1e3, ev.duration_ps / 1e3,
-                       stats)
-
-
 def _iter_events(path):
-    for reader in (_iter_events_profile_data, _iter_events_proto):
-        try:
-            return list(reader(path))
-        except ImportError:
-            continue
-        except Exception:  # noqa: BLE001 - partial/foreign traces: skip file
-            return []
-    return []
+    try:
+        return list(_iter_events_profile_data(path))
+    except Exception:  # noqa: BLE001 - partial/foreign traces: skip file
+        return []
 
 
 def collect_device_events(trace_dir, limit=200000):
@@ -159,7 +109,7 @@ _CLUSTER_GAP_NS = 5e9   # a >5s hole in device activity marks a foreign epoch
 
 def _normalize_clock(events):
     """Shift start_ns onto a trace-relative clock (earliest event of the
-    DOMINANT cluster = 0). The jax 0.4.37 CPU tracer stamps a handful of
+    DOMINANT cluster = 0). A CPU tracer has been seen to stamp a handful of
     events without the session base (they land seconds away from the real
     cluster); anchoring on the raw min would shove the whole timeline off
     the host window. Only GLITCH-sized minorities are dropped: at a >5s

@@ -32,9 +32,9 @@ def _model():
 
 class TestEstimatorProperties:
     def test_flagship_matches_measured_band(self):
-        """The model must reproduce the measured v5e flagship throughput
-        (32,235 tok/s at MFU 0.598, PERF.md) within a loose band — it is the
-        same roofline bench.py uses."""
+        """The estimate for the old bench.py shape on a v5e must land in a
+        loose plausibility band (a sanity bound on the roofline arithmetic:
+        no measured figure exists to match, PERF.md)."""
         est = estimate_cost(_model(), ParallelConfig(
             micro_batch_size=8, recompute=True), _v5e())
         assert 15_000 < est.tokens_per_sec_per_chip < 60_000, est

@@ -80,7 +80,7 @@ class TestGI001CollectiveConsistency:
 
         mesh = Mesh(np.array(mesh8), ("dp",))
         sm = jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=(P("dp"),),
-                                   out_specs=P("dp"), check_rep=False))
+                                   out_specs=P("dp"), check_vma=False))
         return gi.trace(sm, (x,), "fixture.gi001")
 
     def test_branch_divergent_psum_fires(self, mesh8):
@@ -123,7 +123,7 @@ class TestGI001CollectiveConsistency:
         sm = jax.jit(jax.shard_map(body, mesh=mesh,
                                    in_specs=(P("dp", "mp"),),
                                    out_specs=P("dp", "mp"),
-                                   check_rep=False))
+                                   check_vma=False))
         prog = gi.trace(sm, (jnp.ones((8, 4)),), "fixture.gi001.axes")
         new = gi.analyze_program(prog, _pass("GI001"))
         assert len(new) == 1 and "diverges" in new[0].message
@@ -461,7 +461,7 @@ class TestGI007LossScaleCoverage:
 
         mesh = Mesh(np.array(mesh8), ("dp",))
         sm = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                           out_specs=P("dp"), check_rep=False)
+                           out_specs=P("dp"), check_vma=False)
         return gi.trace(sm, args, "fixture.GI007")
 
     def test_unscaled_fp16_psum_fires(self, mesh8):

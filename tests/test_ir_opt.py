@@ -163,7 +163,7 @@ class TestRewriteFixtures:
             return jax.lax.psum(x * 2.0, "dp") + 1.0
 
         fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("dp"),),
-                                   out_specs=P(), check_rep=False))
+                                   out_specs=P(), check_vma=False))
         x = jnp.arange(16, dtype=jnp.float32)
         prog = gi.trace(fn, (x,), "coll")
         oprog, _res = gopt.optimize_program(prog)
@@ -504,7 +504,7 @@ class TestCollectiveBytes:
             return jax.lax.psum(x, "dp")
 
         fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("dp"),),
-                                   out_specs=P(), check_rep=False))
+                                   out_specs=P(), check_vma=False))
         x = jnp.zeros((8, 4), jnp.float32)
         prog = gi.trace(fn, (x,), "psum")
         census = coll.byte_census_jaxpr(prog.jaxpr)

@@ -919,8 +919,36 @@ class TestCommEfficientTraining:
 
     def test_overlap_only_is_bit_identical(self, mesh8):
         """compression=none + overlap: the SAME elementwise reductions,
-        grouped per-bucket — losses bit-identical to the legacy
-        per-param exchange, for both ZeRO-1 and plain DP."""
+        grouped per-bucket. The exchange itself is bit-identical to the
+        legacy per-param one (pinned directly below, on the reduced
+        slices); the losses are NOT pinned bit for bit: the bucketed
+        program hands the optimizer a slice of the bucket, XLA:CPU then
+        fuses the Adam update into one kernel where the legacy program
+        gets three, and fused multiply-add contraction differs between
+        them by one fp32 ulp (seen on jax 0.9.0 in the ZeRO-1 layout
+        from step 2 on). A few ulps is the bound."""
+        from jax.sharding import Mesh, PartitionSpec as P
+        from paddle_tpu.mesh import comm_opt, zero
+
+        r = np.random.RandomState(0)
+        shapes = [(16, 32), (32,), (32, 16), (16,)]
+        grads = [r.randn(8, *s).astype("float32") for s in shapes]
+
+        def both(*gs):
+            gs = [g[0] for g in gs]           # this replica's gradient
+            legacy = [zero.scatter_grad(g, "dp", 8) for g in gs]
+            bucketed, _, _ = comm_opt.bucket_reduce(
+                [comm_opt.blockify(g, 8) for g in gs], "dp", 8, "none",
+                "slice")
+            return legacy, bucketed
+
+        legacy, bucketed = jax.jit(jax.shard_map(
+            both, mesh=Mesh(np.array(mesh8), ("dp",)),
+            in_specs=(P("dp"),) * len(grads), out_specs=P("dp"),
+            check_vma=False))(*grads)
+        for a, b in zip(legacy, bucketed):
+            assert np.array_equal(np.asarray(a), np.asarray(b))
+
         batch = self._batch(3)
         for extra in ({"shard_optimizer": True}, {}):
             cfg = {"dp_degree": 8, **extra}
@@ -928,7 +956,9 @@ class TestCommEfficientTraining:
             h, over = self._run(
                 {**cfg, "overlap_grad_comm": True, "bucket_bytes": 1024},
                 batch)
-            assert over == base, (extra, over, base)
+            np.testing.assert_allclose(
+                over, base, rtol=8 * np.finfo(np.float32).eps, atol=0,
+                err_msg=str(extra))
             rep = h.comm_report(*batch)
             assert rep["bucket_count"] >= 2
             assert rep["compression"] == "none"

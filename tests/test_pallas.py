@@ -130,3 +130,44 @@ def test_causal_sq_gt_sk_rejected():
     kv = jnp.zeros((1, 4, 2, 16))
     with pytest.raises(ValueError, match="Sq<=Sk"):
         flash_attention_fwd(q, kv, kv, causal=True)
+
+
+def test_flash_runs_per_head_shard_under_an_auto_mesh_axis(mesh8):
+    """Inside mesh.parallelize's step (dp manual, mp auto) GSPMD cannot
+    partition a Mosaic kernel: the dispatcher wraps it in a nested shard_map
+    over the auto axis, heads sharded. Loss and grads equal the math path."""
+    import sys
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    fa = sys.modules["paddle_tpu.nn.functional.flash_attention"]
+    mesh = Mesh(np.array(mesh8[:4]).reshape(2, 2), ("dp", "mp"))
+    r = np.random.RandomState(0)
+    q, k, v = (jnp.asarray(r.randn(2, 128, 4, 32), jnp.float32)
+               for _ in range(3))
+
+    def body(q, k, v):
+        def loss(q, k, v):
+            return (fa._flash(q, k, v, True, None) ** 2).sum()
+
+        val, grads = jax.value_and_grad(loss, (0, 1, 2))(q, k, v)
+        return jax.lax.psum(val, "dp"), grads
+
+    step = jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=(P("dp"),) * 3,
+        out_specs=(P(), (P("dp"),) * 3), axis_names=frozenset({"dp"}),
+        check_vma=False))
+    args = [jax.device_put(x, NamedSharding(mesh, P("dp", None, "mp", None)))
+            for x in (q, k, v)]
+    # the outer dp shard_map, plus the nested one around forward and backward
+    assert str(jax.make_jaxpr(step)(*args)).count("shard_map") > 1
+    val, grads = step(*args)
+
+    def ref(q, k, v):
+        return (_ref_sdpa(q, k, v, True) ** 2).sum()
+
+    want, want_grads = jax.value_and_grad(ref, (0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(float(val), float(want), rtol=1e-5)
+    for a, b in zip(grads, want_grads):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-3, atol=1e-3)

@@ -1,0 +1,137 @@
+"""The main path's kernels compile for a described TPU v5e, at real widths.
+
+No chip is needed: the TPU compiler is installed and compiles for a topology
+that is described, not attached (on-chip-measurement guide, section 2.3). What
+interpret mode cannot show — a slice the tiling refuses, more VMEM than a
+kernel may use, an operand the compiler pads past HBM — fails here, at no chip
+time. A compile that passes is not a chip run.
+
+All of these live in ONE file, and the topology is described only inside the
+module-scoped fixture: one process at a time may load the chip's library, so
+nothing here touches it at import, in ``parametrize`` or in ``skipif``.
+"""
+import os
+import sys
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+import paddle_tpu  # noqa: F401
+from paddle_tpu.models import paged_kv
+from paddle_tpu.ops.pallas.flash_attention import flash_attention_fwd
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here: skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """The kernel asks jax.devices() whether to interpret, and sees the CPU
+    here: steer it to the real lowering (through sys.modules — the package
+    re-exports a function under the module's name)."""
+    mod = sys.modules["paddle_tpu.ops.pallas.flash_attention"]
+    monkeypatch.setattr(mod, "_interpret", lambda: False)
+
+
+FLASH_SHAPES = [
+    pytest.param(1, 2048, 32, 32, 128, id="S2048-mha-D128"),   # chip_smoke
+    pytest.param(1, 4096, 32, 8, 128, id="S4096-gqa32:8-D128"),
+    pytest.param(1, 2048, 32, 32, 64, id="S2048-mha-D64"),
+]
+
+
+def _qkv(one_chip, B, S, Hq, Hkv, D):
+    q = jax.ShapeDtypeStruct((B, S, Hq, D), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((B, S, Hkv, D), jnp.bfloat16, sharding=one_chip)
+    return q, kv, kv
+
+
+@pytest.mark.parametrize("B,S,Hq,Hkv,D", FLASH_SHAPES)
+def test_flash_forward_compiles_for_v5e(one_chip, mosaic, B, S, Hq, Hkv, D):
+    fwd = jax.jit(lambda q, k, v: flash_attention_fwd(q, k, v, causal=True))
+    hlo = fwd.lower(*_qkv(one_chip, B, S, Hq, Hkv, D)).compile().as_text()
+    assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 1
+
+
+@pytest.mark.parametrize("B,S,Hq,Hkv,D", FLASH_SHAPES)
+def test_flash_backward_compiles_for_v5e(one_chip, mosaic, B, S, Hq, Hkv, D):
+    """jax.grad adds the dq and the dk/dv kernels to the forward one."""
+    def loss(q, k, v):
+        out = flash_attention_fwd(q, k, v, causal=True)
+        return out.astype(jnp.float32).sum()
+
+    bwd = jax.jit(jax.grad(loss, (0, 1, 2)))
+    hlo = bwd.lower(*_qkv(one_chip, B, S, Hq, Hkv, D)).compile().as_text()
+    assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 3
+
+
+def test_flash_backward_is_refused_past_its_vmem(one_chip, mosaic):
+    """The kernels keep whole (Sk, D) K and V blocks in VMEM: at D128 the
+    backward stops compiling between Sk 4096 and 8192. When a tiled kernel
+    lifts that, this test is the one to turn around."""
+    def loss(q, k, v):
+        out = flash_attention_fwd(q, k, v, causal=True)
+        return out.astype(jnp.float32).sum()
+
+    bwd = jax.jit(jax.grad(loss, (0, 1, 2)))
+    with pytest.raises(Exception, match="vmem"):
+        bwd.lower(*_qkv(one_chip, 1, 8192, 32, 8, 128)).compile()
+
+
+def test_paged_decode_attention_fits_the_mixed_step(one_chip):
+    """The serving mixed step's attention at chip_smoke's shape: 136 lanes
+    (max_batch 8 + chunk 128) x max_len 1024 x 32 kv heads x 128. Written as
+    einsums the compiler padded the one-row matmuls to 8 sublanes and asked
+    for 18 GB; as multiply + reduce it stays under a quarter of the HBM."""
+    T, width, bs, kv, D, nb = 136, 16, 64, 32, 128, 129
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(paged_kv.paged_attention_decode).lower(
+        sds((T, kv, D), jnp.bfloat16), sds((nb, bs, kv, D), jnp.bfloat16),
+        sds((nb, bs, kv, D), jnp.bfloat16), sds((T, width), jnp.int32),
+        sds((T,), jnp.int32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2 ** 30
+
+
+def test_no_module_describes_the_topology_at_import():
+    """get_topology_desc loads the chip's library; only a fixture or a test
+    of THIS file may call it (see the module docstring)."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    hits = []
+    for sub in ("paddle_tpu", "tests", "tools"):
+        for dirpath, _dirs, files in os.walk(os.path.join(root, sub)):
+            for name in files:
+                path = os.path.join(dirpath, name)
+                if name.endswith(".py") and path != os.path.abspath(__file__):
+                    with open(path, encoding="utf-8") as f:
+                        if "get_topology_desc" in f.read():
+                            hits.append(os.path.relpath(path, root))
+    assert hits == []
