@@ -1,0 +1,56 @@
+"""Operations and bytes of the Llama-shaped decoder, from its shapes alone.
+
+Model FLOPs, not hardware FLOPs: matmul parameters only (the layers'
+projections and the LM head; the embedding table is a lookup), causal attention
+at half the square, and what recomputation runs again is not counted.
+"""
+from __future__ import annotations
+
+import weights as W
+
+
+def _attn_width(cfg):
+    hd = cfg.get("head_dim") or cfg["hidden_size"] // cfg["num_attention_heads"]
+    return cfg["num_attention_heads"] * hd
+
+
+def train_flops_per_token(cfg, seq_len):
+    """Forward + backward of one token in a sequence of ``seq_len``: 6 per
+    matmul parameter, and per layer 3 x (QK^T + PV) over the causal half:
+    3 x 2 x 2 x (seq_len / 2) x heads x head_dim."""
+    attn = 6 * seq_len * _attn_width(cfg) * cfg["num_hidden_layers"]
+    return 6 * W.matmul_param_count(cfg) + attn
+
+
+def forward_flops(cfg, tokens, sum_context):
+    """Forward of ``tokens`` tokens whose context lengths (positions attended
+    to, itself included) add up to ``sum_context``: 2 per matmul parameter and
+    token, and per layer QK^T + PV = 4 x context x heads x head_dim."""
+    attn = 4 * sum_context * _attn_width(cfg) * cfg["num_hidden_layers"]
+    return 2 * W.matmul_param_count(cfg) * tokens + attn
+
+
+def request_forward_flops(cfg, prompt_len, output_len):
+    """A served request feeds prompt + output - 1 tokens through the model (the
+    last token served is not fed back), token i attending to i + 1 positions."""
+    fed = prompt_len + output_len - 1
+    return forward_flops(cfg, fed, fed * (fed + 1) // 2)
+
+
+def flash_attention_costs(cfg, batch, seq_len, bytes_per_el=2):
+    """``{call: (flops, bytes)}`` of the three flash-attention kernels for one
+    layer at (batch, seq_len), causal: forward (QK^T, PV), dQ (QK^T, dO V^T,
+    dS K) and dK/dV (QK^T, P^T dO, dO V^T, dS^T Q), each matmul
+    2 x batch x heads x seq^2 x head_dim / 2. Bytes: every operand read and
+    every result written once, K/V at the KV heads' width."""
+    hd = cfg.get("head_dim") or cfg["hidden_size"] // cfg["num_attention_heads"]
+    h, kv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
+    one = 2 * batch * h * seq_len * seq_len * hd // 2
+    q = batch * seq_len * h * hd * bytes_per_el
+    k = batch * seq_len * kv * hd * bytes_per_el
+    stat = batch * seq_len * h * 4                    # float32 row statistic
+    return {
+        "fwd": (2 * one, 2 * q + 2 * k + stat),               # q,k,v -> o,lse
+        "dq": (3 * one, 3 * q + 2 * k + 2 * stat),            # q,k,v,do,lse,d -> dq
+        "dkv": (4 * one, 2 * q + 4 * k + 2 * stat),           # q,k,v,do,lse,d -> dk,dv
+    }

@@ -1,0 +1,116 @@
+"""One rehearsal of each cell end to end, as the driver runs it, and the rest
+of a run with the timed path broken underneath: ``correct`` has to come out
+false for each fault the cell can have."""
+import numpy as np
+import pytest
+
+import _bench_util as U
+
+BENCH = U.benchmark_json()
+CELLS = [w["name"] for w in BENCH["workloads"]]
+
+
+def _metric_names(group, cell):
+    return {m["name"] for m in BENCH[group]
+            if "workloads" not in m or cell in m["workloads"]}
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_rehearsal_prints_the_contracts_line(cell):
+    rc, res, out, err = U.run_cell(cell, 4294967311, 3)
+    assert rc == 0, err[-3000:]
+    assert U.RESULT_KEYS <= set(res) and list(res)[-1] == "compared"
+    assert res["correct"] is True and res["failed"] == 0 and res["attempted"] > 0
+    assert set(res["metrics"]) == _metric_names("end_to_end", cell)
+    assert all(m["value"] > 0 for m in res["metrics"].values())
+    assert res["device"]["platform"] == "cpu"            # never a tpu
+    assert {"kind", "count", "memory_peak_bytes"} <= set(res["device"])
+    # every number compared stands beside its limit, last on standard error
+    tail = [line for line in err.strip().splitlines()][-len(res["compared"]):]
+    assert all(line.startswith("compared ") and " limit " in line for line in tail)
+    assert res["compared"]["compiled_in_window"]["value"] == 0
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_traced_rehearsal_reads_the_per_layer_metrics_and_the_breakdown(cell):
+    rc, res, out, err = U.run_cell(cell, 12345, 4, trace=1)
+    assert rc == 0, err[-3000:]
+    assert res["correct"] is True
+    # what needs a published peak (mfu, roofline) has nothing to read on the CPU
+    expected = {n for n in _metric_names("per_layer", cell)
+                if "mfu" not in n and "roofline" not in n}
+    assert set(res["metrics"]) == expected
+    assert res["device"]["busy_s"] > 0 and res["device"]["window_s"] > 0
+    assert 0 < len(res["breakdown"]["device_ops"]) <= 10
+    assert len(res["breakdown"]["idle_gaps"]) <= 10
+
+
+def _train_cell():
+    return next(w["name"] for w in BENCH["workloads"] if "train" in w["name"])
+
+
+def _serve_cell():
+    return next(w["name"] for w in BENCH["workloads"] if "serve" in w["name"])
+
+
+def _state_unchanged(handle):
+    import jax.numpy as jnp
+
+    real = handle.step
+
+    def step(*batch):
+        saved = ([jnp.copy(v) for v in handle._pv],
+                 [[jnp.copy(v) for v in row] for row in handle._av],
+                 [jnp.copy(v) for v in handle._mv])
+        loss = real(*batch)
+        handle.set_state(*saved)
+        return loss
+    handle.step = step
+
+
+def _half_batch(handle):
+    real = handle.step
+
+    def step(ids, labels):
+        half = len(ids) // 2
+        # the mean over the first half alone: the rest is left out
+        return real(np.concatenate([ids[:half]] * 2),
+                    np.concatenate([labels[:half]] * 2))
+    handle.step = step
+
+
+def _altered_token(eng):
+    real = eng.step
+
+    def step(*a, **k):
+        out = []
+        for rid, tokens in real(*a, **k):
+            tokens = list(tokens)
+            tokens[len(tokens) // 2] = (tokens[len(tokens) // 2] + 1) % 256
+            out.append((rid, tokens))
+        return out
+    eng.step = step
+
+
+@pytest.mark.parametrize("fault,failing", [
+    (_state_unchanged, "change_norm_gap"),
+    (_half_batch, "grad_norm_gap"),
+])
+def test_a_broken_train_step_reads_not_correct(fault, failing):
+    res, err = U.run_cell_with_fault(_train_cell(), 99, 1, fault)
+    assert res["correct"] is False
+    c = res["compared"][failing]
+    assert c["value"] > c["limit"]
+    assert "NOT OK" in err
+
+
+def test_an_altered_served_token_reads_not_correct():
+    res, err = U.run_cell_with_fault(_serve_cell(), 99, 2, _altered_token)
+    assert res["correct"] is False
+    c = res["compared"]["token_logit_gap"]
+    assert c["value"] > c["limit"]
+
+
+def test_the_unbroken_path_reads_correct_in_process():
+    res, _ = U.run_cell_with_fault(_train_cell(), 99, 1, lambda handle: None)
+    assert res["correct"] is True
