@@ -754,7 +754,10 @@ class SpanNameContract(Rule):
 
     CATALOG = "paddle_tpu/monitor/catalog.py"
     # functions whose first string-literal argument is a span name
-    EMIT_FUNCS = {"span", "start_span", "record_span"}
+    # (phase / _next_phase / then: the trace.phase() helper and the
+    # serving engine's hand-over between a step's phases)
+    EMIT_FUNCS = {"span", "start_span", "record_span", "phase",
+                  "_next_phase", "then"}
 
     load_catalog = staticmethod(MetricNameContract.load_catalog)
 

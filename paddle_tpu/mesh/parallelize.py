@@ -194,7 +194,9 @@ def build_mesh_step(model, optimizer, loss_fn, ctx, batch, *,
                 sliced.append(False)
         return sliced, new_res
 
-    def body(param_values, acc_values, master_values, *rest):
+    def mesh_train_step(param_values, acc_values, master_values, *rest):
+        # (the function's name is the compiled program's: a device trace
+        # lists the step as jit_mesh_train_step)
         if use_res:
             res_values, batch_vals = rest[0], rest[1:]
         else:
@@ -373,7 +375,7 @@ def build_mesh_step(model, optimizer, loss_fn, ctx, batch, *,
         out_specs = (P(), p_specs, a_specs, m_specs)
         donate = (0, 1, 2)
     sm = shard_map(
-        body, mesh=mesh,
+        mesh_train_step, mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
         axis_names=frozenset(mesh.axis_names) - frozenset(ctx.auto_axes),
@@ -608,13 +610,18 @@ class MeshParallel:
                     f"dp={dp}")
             vals.append(v)
         before = self._jitted._cache_size()
-        t0 = _m.now_ns() if (_m._state.on or _m.trace._state.on) else 0
-        if self._rv is not None:
-            loss, self._pv, self._av, self._mv, self._rv = self._jitted(
-                self._pv, self._av, self._mv, self._rv, *vals)
-        else:
-            loss, self._pv, self._av, self._mv = self._jitted(
-                self._pv, self._av, self._mv, *vals)
+        t0 = _m.now_ns() if _m.trace._state.annotate else 0
+        # the call returns once the step is enqueued: mesh.step is the
+        # host's part of it, on the profiler's host plane beside the
+        # device trace (a no-op with both switches off)
+        with _m.trace.phase("mesh.step"):
+            if self._rv is not None:
+                loss, self._pv, self._av, self._mv, self._rv = \
+                    self._jitted(self._pv, self._av, self._mv, self._rv,
+                                 *vals)
+            else:
+                loss, self._pv, self._av, self._mv = self._jitted(
+                    self._pv, self._av, self._mv, *vals)
         self._steps += 1
         if _sanitizers._state.numerics:
             regions = [("loss", loss), ("params", self._pv),
@@ -651,10 +658,14 @@ class MeshParallel:
                         "paddle_tpu_mesh_comm_compressed_bytes_total")
                 self._comm_ctr.inc(rt.get("compressed_bytes", 0))
             if _m.trace._state.on:
+                # the census attrs come from what collective_counts() /
+                # collective_bytes() have ALREADY cached (called outside
+                # a step): a step never lowers or compiles the program
+                # to fill them
                 attrs = {"dp": dp, "step": self._steps,
                          "zero": self.shard_optimizer}
-                attrs.update(self.collective_counts(*batch))
-                for coll, row in self.collective_bytes(*batch).items():
+                attrs.update(self._collectives or {})
+                for coll, row in (self._collective_bytes or {}).items():
                     attrs[f"{coll}_bytes"] = row["bytes"]
                 _m.trace.record_span("comm.mesh_step", t0, t1, attrs=attrs)
                 if rt:

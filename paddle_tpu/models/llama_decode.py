@@ -370,7 +370,10 @@ class LlamaDecodeEngine:
         False (speculation off) the flags are all zero and row 0 is the
         plain mixed step — one program serves both modes, so greedy
         outputs are bit-identical with speculation on or off."""
-        def run(pack, pools, tables, slot_ids, valid, chain, w):
+        def serving_mixed_step(pack, pools, tables, slot_ids, valid, chain,
+                               w):
+            # (the function's name is the compiled program's: a device
+            # trace lists it as jit_serving_mixed_step)
             # pack (2, T) int32: row 0 = token ids, row 1 = positions
             # (one fused upload per step — these are the only per-step
             # transfers; slot_ids/valid/chain are cached per composition)
@@ -404,7 +407,7 @@ class LlamaDecodeEngine:
             accept = acc & chain
             return jnp.stack([nt, accept.astype(jnp.int32)]), new_pools
 
-        return run
+        return serving_mixed_step
 
     def build_decode_burst(self, k):
         """``k`` ragged decode iterations fused into ONE program via
@@ -413,7 +416,8 @@ class LlamaDecodeEngine:
         emits ``k`` tokens per slot instead of one. Inactive rows write
         into the reserved null block (their table rows are zero), exactly
         like the single-step path."""
-        def run(pack, pools, tables, w):
+        def serving_decode_burst(pack, pools, tables, w):
+            # (jit_serving_decode_burst in a device trace)
             # pack (2, B) int32: row 0 = current tokens, row 1 = per-row
             # positions (one fused upload per burst)
             tokens, lens = pack[0][:, None], pack[1]
@@ -435,7 +439,7 @@ class LlamaDecodeEngine:
                 body, (tokens, pools, lens), None, length=k)
             return jnp.swapaxes(outs, 0, 1), pools    # (B, k)
 
-        return run
+        return serving_decode_burst
 
     @functools.cached_property
     def _prefill_paged_jit(self):

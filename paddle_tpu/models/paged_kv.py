@@ -578,6 +578,7 @@ def paged_write_prefill_int8(kq, ks, vq, vs, block_tables, seq_lens,
     return w(kq, k_new_q), w(ks, k_new_s), w(vq, v_new_q), w(vs, v_new_s)
 
 
+@jax.named_scope("paged_attention")
 def paged_attention_decode_int8(q, kq, ks, vq, vs, block_tables, seq_lens,
                                 scale=None):
     """One decode step against the int8 paged cache WITHOUT materializing a
@@ -612,6 +613,7 @@ def paged_attention_decode_int8(q, kq, ks, vq, vs, block_tables, seq_lens,
     return out.reshape(B, n_q, D).astype(q.dtype)
 
 
+@jax.named_scope("paged_attention")
 def paged_attention_decode(q, cache_k, cache_v, block_tables, seq_lens,
                            scale=None):
     """One decode step of attention against the paged cache.
@@ -627,7 +629,11 @@ def paged_attention_decode(q, cache_k, cache_v, block_tables, seq_lens,
     that to 8 sublanes and materializes the padded f32 operand
     ([8, B, T_max, kv, D]: 18 GB at 136 lanes x 1024 x 32 x 128, refused
     outright for a v5e). The fused multiply-reduce reads the gathered
-    bf16 blocks once and keeps nothing wider than the logits."""
+    bf16 blocks once and keeps nothing wider than the logits.
+
+    The whole of it runs under ``jax.named_scope("paged_attention")``, so
+    a device trace can tell the serving programs' attention (gather,
+    scores, softmax, values) from the rest of a layer by name."""
     B, n_q, D = q.shape
     nb, bs, n_kv, _ = cache_k.shape
     groups = n_q // n_kv

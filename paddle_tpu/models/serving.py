@@ -117,10 +117,16 @@ class _Mon:
                  "shed", "tenant_depth", "aborted", "recoveries",
                  "preemptions", "cancelled",
                  "spec_drafted", "spec_accepted", "spec_rate", "pool_bytes",
-                 "jit_compiles", "jit_hits", "jit_sigs")
+                 "jit_compiles", "jit_hits", "jit_sigs",
+                 "phase_ns", "steps", "token_gap")
 
 
 _MON = None
+
+# the `phase` label values of paddle_tpu_serving_step_phase_ns_total, in
+# the order a step runs them (spans: serving.pack_tokens / dispatch /
+# wait / route)
+_STEP_PHASES = ("schedule", "dispatch", "wait", "route")
 
 
 def _mon():
@@ -175,6 +181,13 @@ def _mon():
                                labelnames=("function",))
         o.jit_sigs = m.gauge("paddle_tpu_jit_cached_signatures",
                              labelnames=("function",))
+        o.phase_ns = m.counter("paddle_tpu_serving_step_phase_ns_total",
+                               labelnames=("phase", "kind"))
+        o.steps = m.counter("paddle_tpu_serving_steps_total",
+                            labelnames=("kind",))
+        o.token_gap = m.histogram(
+            "paddle_tpu_serving_token_gap_ns",
+            buckets=m.catalog.TOKEN_GAP_NS_BUCKETS)
         _MON = o
     return _MON
 
@@ -183,8 +196,9 @@ class _Request:
     """Host-side state of one admitted request (one slot)."""
 
     __slots__ = ("rid", "prompt", "prefill_pos", "chunks", "shared_tokens",
-                 "max_new", "last_token", "outputs", "t_submit", "t_admit",
-                 "t_first", "tenant", "priority", "spill")
+                 "max_new", "last_token", "outputs", "token_times",
+                 "t_submit", "t_admit", "t_first", "tenant", "priority",
+                 "spill")
 
     def __init__(self, rid, prompt, max_new, t_submit, tenant="",
                  priority=0):
@@ -196,6 +210,7 @@ class _Request:
         self.max_new = max_new          # per-request cap (None = step's)
         self.last_token = 0
         self.outputs = []
+        self.token_times = []           # now_ns of each output's step fetch
         self.t_submit = t_submit
         self.t_admit = 0
         self.t_first = 0
@@ -418,6 +433,12 @@ class ContinuousBatchingEngine:
         self._stats = collections.OrderedDict()
         # -- resilience state (recover / driving thread / shedding) ------
         self._epoch = 0                 # bumped by every recover()
+        # the running step's open trace.phase, the phases it has been
+        # through and its kind (mixed | burst): written by the driving
+        # thread alone, read back when the step returns
+        self._phase = _mon().trace._NOOP
+        self._phases = ()
+        self._step_kind = None
         self._recover_lock = threading.Lock()
         self._shed = collections.deque(maxlen=4096)     # RequestShed
         self._aborted = collections.deque(maxlen=4096)  # RequestAborted
@@ -816,8 +837,10 @@ class ContinuousBatchingEngine:
 
     def pop_stats(self, rid):
         """Per-request stats (ttft_ns, prefill chunks, shared prefix
-        tokens), retained until popped — the bench reads TTFT percentiles
-        from here after each eviction."""
+        tokens and, once the request has ended, token_times_ns: one
+        now_ns time per returned token, the first equal to submit_ns +
+        ttft_ns), retained until popped — the bench reads TTFT
+        percentiles from here after each eviction."""
         with self._submit_lock:
             _sanitizers.race_access(self._san_tag, "_stats", write=True)
             return self._stats.pop(rid, None)
@@ -1040,20 +1063,32 @@ class ContinuousBatchingEngine:
         (request_id, tokens) pairs evicted this step."""
         epoch = self._epoch
         mon = _mon()
-        # staged controller knobs land here, on the driving thread,
-        # before any slot state is read — never mid-step
-        self._apply_pending_knobs()
-        sp = None
         # the host-side twin of the open serving.step span: set while a
         # step runs, cleared on exit — a fleet health monitor reads its
         # age as the step-staleness signal without needing tracing on
         self.step_open_since = time.monotonic()
-        if mon.tstate.on:
+        step_ctx = self._phase = mon.trace._NOOP
+        self._phases = ()
+        self._step_kind = None
+        if mon.tstate.annotate:
             # an OPEN serving.step span is what a flight dump names when
-            # the driving thread hangs or dies mid-step
-            sp = mon.trace.start_span("serving.step",
-                                      attrs={"engine": self._san_tag})
+            # the driving thread hangs or dies mid-step. It and its four
+            # phases (schedule -> dispatch -> wait -> route, handed over
+            # by _next_phase) are trace.phase()s: profiler annotations on
+            # the device trace's clock under either switch, ring spans
+            # under span tracing
+            step_ctx = mon.trace.phase("serving.step",
+                                       attrs={"engine": self._san_tag})
+            sp = step_ctx.__enter__()
+            self._phase = mon.trace.phase(
+                "serving.pack_tokens", parent=sp, t0_ns=step_ctx.t0_ns)
+            self._phase.__enter__()
+            self._phases = [self._phase]
+        counted = False
         try:
+            # staged controller knobs land here, on the driving thread,
+            # before any slot state is read — never mid-step
+            self._apply_pending_knobs()
             # chaos drills kill/hang the step INSIDE the open span, so
             # the hang dump lists serving.step among its open spans
             _fi.fire("serving.step")
@@ -1086,10 +1121,33 @@ class ContinuousBatchingEngine:
                 # request this step computed for — its results belong
                 # to the dead epoch and must not double-report
                 return []
+            counted = self._step_kind is not None
             return finished
         finally:
             self.step_open_since = None
-            mon.trace.end_span(sp)
+            last = self._phase
+            last.close()
+            step_ctx.close(last.t1_ns)
+            if counted and mon.state.on and len(self._phases) == 4:
+                # a step that ran its program and routed the result: an
+                # early return (no active lane, superseded epoch) or a
+                # raise counts nothing
+                kind = self._step_kind
+                for name, ph in zip(_STEP_PHASES, self._phases):
+                    mon.phase_ns.labels(name, kind).inc(
+                        ph.t1_ns - ph.t0_ns)
+                mon.steps.labels(kind).inc()
+
+    def _next_phase(self, name, kind=None):
+        """Hand the running step over to its next phase at one shared
+        instant (a no-op with both switches off); ``kind`` (mixed |
+        burst) is known from the dispatch phase on."""
+        if kind is not None:
+            self._step_kind = kind
+        ph = self._phase.then(name)
+        if ph is not self._phase:
+            self._phase = ph
+            self._phases.append(ph)
 
     def _ensure(self, need):
         """ensure_capacity with radix-cache relief: pool exhaustion evicts
@@ -1380,15 +1438,16 @@ class ContinuousBatchingEngine:
                 self._lane_cache.clear()
             self._lane_cache[key] = cached
         slots_dev, valid_dev, chain_dev = cached
-        if mon.tstate.on:
-            mon.trace.record_span(
-                "serving.pack_tokens", t0, mon.mod.now_ns(),
-                attrs={"n_decode": nd, "n_draft": n_dec_lanes - nd,
-                       "n_prefill": n_lanes - n_dec_lanes, "budget": T})
         step = self._step_jit()
+        if self._phase.span is not None:
+            self._phase.attrs = {
+                "n_decode": nd, "n_draft": n_dec_lanes - nd,
+                "n_prefill": n_lanes - n_dec_lanes, "budget": T}
+        self._next_phase("serving.dispatch", "mixed")
         out_dev, self._pools = step(
             jnp.asarray(pack_np), self._pools, self._pager.block_tables,
             slots_dev, valid_dev, chain_dev, self._inner.weights)
+        self._next_phase("serving.wait")
         if _sanitizers._state.numerics:
             self._san_steps += 1
             _sanitizers.numsan_check(
@@ -1396,6 +1455,7 @@ class ContinuousBatchingEngine:
                 (("tokens", out_dev), ("kv_pools", self._pools)),
                 step=self._san_steps)
         out = np.asarray(out_dev)
+        self._next_phase("serving.route")
         toks, acc = out[0], out[1]
         if epoch != self._epoch:
             # a hang recovery superseded this step while it sat in
@@ -1625,9 +1685,14 @@ class ContinuousBatchingEngine:
         pack = np.empty((2, self.max_batch), np.int32)
         pack[0] = self._last_tok
         pack[1] = self.lens
-        toks_dev, self._pools = self._burst_jit()(
+        burst = self._burst_jit()
+        if self._phase.span is not None:
+            self._phase.attrs = {"n_decode": len(decode_slots), "burst": K}
+        self._next_phase("serving.dispatch", "burst")
+        toks_dev, self._pools = burst(
             jnp.asarray(pack), self._pools, self._pager.block_tables,
             self._inner.weights)
+        self._next_phase("serving.wait")
         if _sanitizers._state.numerics:
             self._san_steps += 1
             _sanitizers.numsan_check(
@@ -1635,6 +1700,7 @@ class ContinuousBatchingEngine:
                 (("tokens", toks_dev), ("kv_pools", self._pools)),
                 step=self._san_steps)
         toks = np.asarray(toks_dev)            # (B, K)
+        self._next_phase("serving.route")
         if epoch != self._epoch:
             # superseded mid-dispatch: keep the pools rebind (buffer
             # validity + the warm radix blocks), apply no host state —
@@ -1673,6 +1739,12 @@ class ContinuousBatchingEngine:
                     finished, mon, t_now):
         req = self._slots[slot]
         req.outputs.append(tok)
+        # one time per output token: the fetch time of the step that
+        # yielded it (tokens of one step share it)
+        times = req.token_times
+        if times and mon.state.on:
+            mon.token_gap.observe(t_now - times[-1])
+        times.append(t_now)
         req.last_token = tok
         self._last_tok[slot] = tok
         if self._drafter is not None:
@@ -1696,6 +1768,7 @@ class ContinuousBatchingEngine:
             st = self._stats.get(req.rid)
             if st is not None:
                 st["tokens"] = len(req.outputs)
+                st["token_times_ns"] = req.token_times
         t0 = t0 or (mon.mod.now_ns() if entry is not None else 0)
         # last chance to chain the generation's tail blocks: a finishing
         # request's final block-crossings happen inside the same routing
@@ -1879,6 +1952,7 @@ class ContinuousBatchingEngine:
                     if st is not None:
                         st["aborted"] = True
                         st["tokens"] = len(req.outputs)
+                        st["token_times_ns"] = list(req.token_times)
                     entry = self._req_spans.pop(req.rid, None)
                 self._aborted.append(RequestAborted(
                     f"request {req.rid} aborted by engine recovery: "

@@ -57,6 +57,7 @@ class _State:
 
 
 _state = _State()
+trace._metrics_state = _state
 registry = Registry()
 
 # timeline samples for chrome-trace counter export: bounded, so an
@@ -69,11 +70,13 @@ _sample_lock = threading.Lock()
 def enable():
     """Turn collection on process-wide."""
     _state.on = True
+    trace._state.annotate = True    # trace.phase() sites annotate too
 
 
 def disable():
     """Turn collection off (metric values are kept; use reset() to zero)."""
     _state.on = False
+    trace._state.annotate = trace._state.on
 
 
 def enabled():

@@ -74,8 +74,34 @@ METRICS = {
         "steps), nanoseconds."),
     "paddle_tpu_serving_decode_step_latency_ns": (
         "histogram", (),
-        "Wall time of one batched decode step over all active slots, "
-        "nanoseconds."),
+        "One engine step (mixed or burst) from the end of admission to "
+        "the end of the result fetch, nanoseconds: pack assembly, "
+        "dispatch and device wait. It ENDS BEFORE token routing and "
+        "leaves admission out — the whole step is the sum of "
+        "paddle_tpu_serving_step_phase_ns_total's four phases."),
+    "paddle_tpu_serving_step_phase_ns_total": (
+        "counter", ("phase", "kind"),
+        "Nanoseconds of completed engine steps, by phase (schedule = "
+        "step() entry to just before the jitted call; dispatch = the "
+        "jitted call's enqueue and pack upload; wait = the blocking "
+        "result fetch: device execution + download; route = after the "
+        "fetch to step() return) and step kind (mixed | burst). The "
+        "four phases share their edges and cover the whole step; a step "
+        "that returns early (no active lane, superseded epoch, a raise) "
+        "counts nothing."),
+    "paddle_tpu_serving_steps_total": (
+        "counter", ("kind",),
+        "Completed engine steps by kind: mixed (one token per lane, "
+        "prefill chunks aboard) | burst (decode_burst fused decode "
+        "iterations). Counted with the phase nanoseconds, once per "
+        "step() that ran its program and routed the result."),
+    "paddle_tpu_serving_token_gap_ns": (
+        "histogram", (),
+        "Time between one request's consecutive output tokens, observed "
+        "for every token after the request's first: the routing time of "
+        "the step that yielded it minus that of its previous token, so "
+        "0 for the later tokens of one step (a burst's, a verified "
+        "draft's). Grid: TOKEN_GAP_NS_BUCKETS, nanoseconds."),
     "paddle_tpu_serving_generated_tokens_total": (
         "counter", (),
         "Tokens emitted across all requests (prefill first-token included)."),
@@ -353,6 +379,24 @@ METRICS = {
 }
 
 
+def _token_gap_buckets():
+    # a bucket for 0 (tokens that share one step), coarse below 10 ms,
+    # then 10 ms .. 2 s in steps of under 10% — a serving step is 0.05-0.5 s
+    # and a quantile is read by interpolation inside one bucket — then the
+    # long tail
+    grid = [0, 1_000_000, 2_000_000, 5_000_000]
+    edge = 10_000_000.0
+    while edge < 2_000_000_000:
+        grid.append(int(round(edge, -4)))
+        edge *= 1.09
+    return tuple(grid + [2_000_000_000, 5_000_000_000, 10_000_000_000,
+                         60_000_000_000])
+
+
+# bucket grid of paddle_tpu_serving_token_gap_ns (upper bounds, ns)
+TOKEN_GAP_NS_BUCKETS = _token_gap_buckets()
+
+
 def spec(name):
     """(type, labelnames, help) for a cataloged metric name, or None."""
     return METRICS.get(name)
@@ -405,20 +449,38 @@ SPANS = {
         "prompt tokens of one request packed alongside the decode lanes "
         "(child of serving.request). attrs: slot, start, tokens."),
     "serving.pack_tokens": (
-        "Per-step pack assembly of the mixed continuous-batching step: "
-        "how many decode lanes and prefill-chunk lanes filled the token "
-        "budget. attrs: n_decode, n_prefill, budget."),
+        "The SCHEDULE phase of one engine step, mixed or burst (child of "
+        "serving.step): step() entry — cancellations, admission-queue "
+        "drain, admission, block grants, pack assembly — to just before "
+        "the jitted call. attrs: n_decode, n_draft, n_prefill, budget "
+        "(mixed) or n_decode, burst (burst)."),
+    "serving.dispatch": (
+        "The DISPATCH phase of one engine step (child of serving.step): "
+        "the jitted call to its return — enqueue and upload of the pack; "
+        "on a cold engine, the trace + compile."),
+    "serving.wait": (
+        "The WAIT phase of one engine step (child of serving.step): the "
+        "blocking fetch of the program's result to its return — device "
+        "execution + download; the host does nothing else."),
+    "serving.route": (
+        "The ROUTE phase of one engine step (child of serving.step): "
+        "after the fetch to step() return — token routing, evictions, "
+        "prefix registration, gauges and monitor.sample()."),
     "serving.decode_step": (
-        "One mixed serving step, recorded per active decoding request so "
-        "each trace tree carries its own decode timeline. attrs: slot, "
-        "n_active."),
+        "One engine step as seen by ONE decoding request, recorded per "
+        "active request (n_active copies of one interval a step) so each "
+        "request's tree carries its own decode timeline: end of admission "
+        "to end of the result fetch, routing left out. attrs: slot, "
+        "n_active, burst."),
     "serving.evict": (
         "Slot eviction: block free + host state clear (child of "
         "serving.request). attrs: slot, tokens."),
     "serving.step": (
         "One whole engine step, OPEN while the step runs — the span a "
         "flight dump names when the driving thread hangs or dies "
-        "mid-step. attrs: engine."),
+        "mid-step; parent of the four phases serving.pack_tokens -> "
+        "serving.dispatch -> serving.wait -> serving.route, which share "
+        "their edges and sum to it. attrs: engine."),
     "serving.recover": (
         "One engine recovery pass: flight dump, in-flight aborts "
         "(typed RequestAborted with partial tokens), warm restart from "
@@ -499,10 +561,18 @@ SPANS = {
         "buckets, compression, overlap, compressed_bytes, "
         "uncompressed_bytes."),
     "comm.mesh_step": (
-        "One shard_map mesh train-step dispatch (mesh/parallelize.py); "
-        "attrs carry the collective census of the compiled program "
-        "(all_reduce/all_gather/reduce_scatter/all_to_all counts from "
-        "HLO) plus dp degree and the ZeRO knob."),
+        "One shard_map mesh train-step DISPATCH (mesh/parallelize.py): "
+        "the ENQUEUE of the jitted step plus its host bookkeeping, not "
+        "the step's execution — the call returns before the device "
+        "finishes. attrs: dp degree, step, the ZeRO knob, and the "
+        "compiled program's collective census (all_reduce/all_gather/"
+        "reduce_scatter/all_to_all counts, <op>_bytes) once "
+        "collective_counts()/collective_bytes() have been called outside "
+        "a step: a step never lowers the program to fill them."),
+    "mesh.step": (
+        "The jitted mesh train step's call to its return (enqueue), as a "
+        "trace.phase: on the profiler's host plane beside the device "
+        "trace, and a ring span under span tracing."),
     "mesh.reshard": (
         "One explicit redistribution inserted by the SPMD rule engine "
         "where an input's placement disagreed with the op's sharding "

@@ -48,6 +48,7 @@ __all__ = [
     "chrome_span_events", "span_dump", "flight_dump",
     "register_flight_section", "unregister_flight_section",
     "training_step", "set_dispatch_sampling", "dispatch_sample_every",
+    "phase",
 ]
 
 _RING_CAPACITY = 4096
@@ -55,15 +56,20 @@ _RING_CAPACITY = 4096
 
 class _TraceState:
     """The disabled-mode fast path: instrument sites read ``_state.on`` —
-    a single slot load — before doing any span work."""
+    a single slot load — before doing any span work. ``annotate`` is
+    ``on`` OR the metrics monitor's switch (``monitor.enable()`` mirrors
+    itself here): the one slot :func:`phase` sites load."""
 
-    __slots__ = ("on",)
+    __slots__ = ("on", "annotate")
 
     def __init__(self):
         self.on = False
+        self.annotate = False
 
 
 _state = _TraceState()
+_metrics_state = None   # monitor/__init__.py binds its own _state here
+_annotation = None      # jax.profiler.TraceAnnotation, bound on first use
 
 # ring of COMPLETED spans: preallocated slots; writers take an atomic
 # sequence ticket (itertools.count.__next__ is one bytecode under the GIL)
@@ -131,12 +137,13 @@ class Span:
 
 def enable():
     """Turn span collection on process-wide."""
-    _state.on = True
+    _state.on = _state.annotate = True
 
 
 def disable():
     """Turn span collection off (recorded spans are kept; reset() drops)."""
     _state.on = False
+    _state.annotate = _metrics_state is not None and _metrics_state.on
 
 
 def enabled():
@@ -291,7 +298,7 @@ class _SpanCtx:
 
 class _NoopCtx:
     __slots__ = ()
-    span = None
+    span = t0_ns = t1_ns = None
 
     def __enter__(self):
         return None
@@ -299,8 +306,96 @@ class _NoopCtx:
     def __exit__(self, *exc):
         return False
 
+    def close(self, t1_ns=None):
+        pass
+
+    def then(self, name):
+        return self
+
 
 _NOOP = _NoopCtx()
+
+
+class _PhaseCtx:
+    """One phase of a step, on BOTH clocks: a
+    ``jax.profiler.TraceAnnotation`` (the host plane of the profiler's
+    own trace, which shares the device trace's clock) and, when span
+    tracing is on, a ring span with the same edges. ``t0_ns``/``t1_ns``
+    stay readable after the exit — the serving engine's phase counters
+    are fed from them."""
+
+    __slots__ = ("name", "attrs", "span", "t0_ns", "t1_ns", "_parent",
+                 "_ann")
+
+    def __init__(self, name, parent, attrs, t0_ns=None):
+        self.name = name
+        self.attrs = attrs
+        self.span = None
+        self.t0_ns = t0_ns
+        self.t1_ns = None
+        self._parent = parent
+        self._ann = None
+
+    def __enter__(self):
+        global _annotation
+        if _annotation is None:
+            from jax.profiler import TraceAnnotation
+
+            _annotation = TraceAnnotation
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
+        if self.t0_ns is None:
+            self.t0_ns = now_ns()
+        self.span = start_span(self.name, parent=self._parent,
+                               attrs=self.attrs)
+        if self.span is not None:
+            self.span.t0_ns = self.t0_ns    # one reading for both records
+        return self.span
+
+    def close(self, t1_ns=None):
+        """End the phase, at ``t1_ns`` when the caller has already read
+        that instant (a parent closing with its last child). ``attrs``
+        set since the enter are taken now. A second close is a no-op."""
+        if self._ann is None:
+            return
+        self.t1_ns = now_ns() if t1_ns is None else t1_ns
+        if self.span is not None:
+            self.span.attrs = self.attrs
+            end_span(self.span, t1_ns=self.t1_ns)
+        self._ann.__exit__(None, None, None)
+        self._ann = None
+
+    def __exit__(self, *exc):
+        self.close()
+        return False
+
+    def then(self, name):
+        """Close this phase and open the next one — a sibling under the
+        same parent — at the same instant: consecutive phases share
+        their edge, so they sum to the step they decompose."""
+        self.close()
+        nxt = _PhaseCtx(name, self._parent, None, t0_ns=self.t1_ns)
+        nxt.__enter__()
+        return nxt
+
+
+def phase(name, parent=None, attrs=None, t0_ns=None):
+    """Context manager for one phase of a step (the serving engine's
+    ``serving.step`` and its schedule/dispatch/wait/route phases, the
+    mesh train step's enqueue). When EITHER switch is on
+    (``monitor.enable()`` or ``trace.enable()``) the body runs under a
+    ``jax.profiler.TraceAnnotation(name)``, so a profiler capture shows
+    it on the host plane, on the device trace's clock; with span tracing
+    on it is also a ring span (explicit ``parent=`` only — a phase never
+    joins the implicit per-thread stack). ``__enter__`` returns the ring
+    Span (None without span tracing); ``.then(name)`` hands over to the
+    next phase at one shared instant, ``t0_ns=`` opens a first child at
+    its parent's own start and ``.close(t1_ns)`` ends a parent with its
+    last child. Both switches off: the shared no-op, nothing
+    constructed. jax is imported on first use."""
+    if not _state.annotate:
+        return _NOOP
+    return _PhaseCtx(name, parent, attrs, t0_ns)
 
 
 def span(name, parent=None, trace_id=None, attrs=None):

@@ -118,6 +118,7 @@ def _fwd(q, k, v, scale, causal, block_q, block_k):
             jax.ShapeDtypeStruct((B, Hq, Sq, 1), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_attention_fwd",
     )(q, k, v)
     return out, lse
 
@@ -240,6 +241,7 @@ def _bwd(scale, causal, block_q, block_k, res, g):
         out_specs=pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, np.int32(0))),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=_interpret(),
+        name="flash_attention_dq",
     )(q, k, v, do, lse, delta)
 
     # dk/dv computed per q-head, then group-summed over the GQA repeat factor
@@ -265,6 +267,7 @@ def _bwd(scale, causal, block_q, block_k, res, g):
             jax.ShapeDtypeStruct((B, Hq, Sk, D), v.dtype),
         ],
         interpret=_interpret(),
+        name="flash_attention_dkv",
     )(q, k, v, do, lse, delta)
 
     if rep > 1:

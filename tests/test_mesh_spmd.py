@@ -496,6 +496,9 @@ class TestMeshLlamaAcceptance:
         mp = pmesh.parallelize(m, opt, self._loss, (ids, labels),
                                config={"dp_degree": 8})
         got = [float(mp.step(ids, labels))]  # warmup: the one allowed compile
+        # the census is asked for OUTSIDE a step (a traced step only
+        # attaches what is cached; it never lowers the program itself)
+        mp.collective_counts(ids, labels)
         tr_was = trace.enabled()
         trace.enable()
         san.reset()
@@ -1113,9 +1116,17 @@ class TestCommEfficientTraining:
             assert at["compression"] == "int8" and at["overlap"] is True
             assert at["buckets"] == rep["bucket_count"]
             assert 0 < at["compressed_bytes"] < at["uncompressed_bytes"]
+            # a step attaches only the census that is already cached:
+            # nothing until collective_bytes() is asked outside a step
+            mesh_spans = [s for s in trace.spans()
+                          if s.name == "comm.mesh_step"]
+            assert "all_to_all_bytes" not in mesh_spans[-1].attrs
+            h.collective_bytes(*batch)
+            h.step(*batch)
             mesh_spans = [s for s in trace.spans()
                           if s.name == "comm.mesh_step"]
             assert mesh_spans[-1].attrs.get("all_to_all_bytes", 0) > 0
+            assert h._jitted._cache_size() == 1
         finally:
             san.reset()
             san.disable("recompile")
