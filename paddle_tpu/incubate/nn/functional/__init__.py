@@ -895,8 +895,9 @@ def block_multihead_attention(
     """reference block_multihead_attention.py:33 — paged-KV (block-table)
     serving attention. The KV cache is a POOL of fixed-size blocks; each
     sequence's block_tables row lists the blocks it owns. TPU-first: the
-    block indirection is jnp gathers/scatters the compiler fuses into the
-    attention chain (models/paged_kv.py), not a page-table CUDA kernel.
+    writes are jnp scatters, and the decode attention is
+    models/paged_kv.paged_attention_decode — the Pallas page-table kernel
+    on a TPU, the fused gather chain elsewhere.
 
     Layouts follow the reference contract: ``qkv`` is varlen-packed rows
     [token_num, (q_heads + 2*kv_heads) * head_dim]; ``key_cache``/

@@ -318,8 +318,9 @@ class LlamaDecodeEngine:
         MIXED step, where decode lanes (one token per running request) and
         chunked-prefill lanes (consecutive prompt tokens of an admitted
         request) share one compiled program. Writes land before the
-        attention gather, so prefill lanes of the same chunk see each
-        other through the pool (causal by absolute position)."""
+        attention reads the pool (kernel or gather: paged_kv picks), so
+        prefill lanes of the same chunk see each other through it (causal
+        by absolute position)."""
         from . import paged_kv as _pk
 
         B = x.shape[0]

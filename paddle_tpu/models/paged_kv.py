@@ -7,15 +7,20 @@ memory is allocated block-at-a-time, sequences of very different lengths
 don't reserve max_len each, and finished sequences return their blocks).
 The reference implements it as a CUDA serving kernel
 (fluid/operators/fused/block_multi_head_attention_op.cu); TPU-first
-redesign: the pool is a [num_blocks, block_size, kv_heads, head_dim] array,
-the block table drives jnp gathers/scatters, and XLA fuses the
-gather -> attention -> reduce chain — no page-table indirection kernel is
-hand-written, the indexed reads ARE the indirection.
+redesign: the pool is a [num_blocks, block_size, kv_heads, head_dim] array
+and the block table drives jnp scatters for the writes. The decode
+attention over a bf16/f32 pool is a Pallas kernel on the TPU
+(ops/pallas/paged_attention.py: it walks each lane's table row itself and
+reads ``position // block_size + 1`` blocks, once, in the pool's dtype);
+elsewhere — CPU runs, head dims the kernel does not tile, the int8 pool —
+it is the plain path: the table drives a jnp gather of the whole row and
+XLA fuses gather -> attention -> reduce. ``paged_attention_decode`` picks
+between the two from its inputs alone.
 
 Layout note: the reference kernel stores [max_blocks, kv_heads, block_size,
 head_dim]; here blocks are [block_size, kv_heads, head_dim]-major so the
 gathered view reshapes straight to the [B, S, H, D] attention layout with
-no transpose.
+no transpose, and one block is one contiguous DMA for the kernel.
 
 Everything is functional and jit-compatible: cache arrays in, cache arrays
 out (donate-friendly), shapes static, per-sequence lengths as data.
@@ -34,7 +39,8 @@ from ..analysis import faultinject as _fi
 __all__ = ["PagedKVCache", "CowPoolExhausted", "alloc_blocks",
            "read_blocks",
            "paged_write_decode", "paged_write_prefill", "paged_write_mixed",
-           "paged_attention_decode", "paged_write_decode_int8",
+           "paged_attention_decode", "paged_attention_decode_plain",
+           "paged_write_decode_int8",
            "paged_write_prefill_int8", "paged_write_mixed_int8",
            "paged_attention_decode_int8"]
 
@@ -613,15 +619,54 @@ def paged_attention_decode_int8(q, kq, ks, vq, vs, block_tables, seq_lens,
     return out.reshape(B, n_q, D).astype(q.dtype)
 
 
+def _kernel_applies(q, pool):
+    """Whether ``paged_attention_decode`` runs the Pallas kernel for this
+    query and pool: on a TPU, where the kernel's blocks tile (the head dim
+    fills whole 128-lane rows; block_size and kv_heads sit on dims the
+    kernel does not tile), the dtypes are ones it loads, and its four
+    block buffers (K and V, double buffered) fit half of a core's 16 MiB
+    of scoped VMEM. Everything it reads is visible in the inputs: no flag
+    picks the path. The serving engine asks it too, to count the blocks a
+    step's attention reads."""
+    return (jax.devices()[0].platform == "tpu"
+            and q.shape[-1] % 128 == 0
+            and q.dtype in (jnp.bfloat16, jnp.float32)
+            and pool.dtype in (jnp.bfloat16, jnp.float32)
+            and 4 * int(np.prod(pool.shape[1:])) * pool.dtype.itemsize
+            <= 8 * 2 ** 20)
+
+
 @jax.named_scope("paged_attention")
 def paged_attention_decode(q, cache_k, cache_v, block_tables, seq_lens,
                            scale=None):
     """One decode step of attention against the paged cache.
 
-    q: [B, q_heads, head_dim] (GQA: q_heads a multiple of kv_heads).
-    Gathers each sequence's blocks into a [B, T_max, kv, D] view
-    (T_max = max_blocks_per_seq * block_size) and masks t <= seq_lens[b]
-    (inclusive: the current token was just written at position seq_lens).
+    q: [B, q_heads, head_dim] (GQA: q_heads a multiple of kv_heads); row b
+    attends to positions 0..seq_lens[b] INCLUSIVE of its block-table row
+    (the current token was just written at position seq_lens). On a TPU
+    this is the Pallas kernel (``_kernel_applies``), which reads only the
+    blocks up to that position; elsewhere ``paged_attention_decode_plain``,
+    the kernel's reference. Same arithmetic either way: float32 scores,
+    probabilities and accumulation over K and V promoted on the fly.
+
+    The whole of it runs under ``jax.named_scope("paged_attention")``, so
+    a device trace can tell the serving programs' attention from the rest
+    of a layer by name."""
+    if _kernel_applies(q, cache_k):
+        from ..ops.pallas.paged_attention import paged_attention
+
+        return paged_attention(q, cache_k, cache_v, block_tables, seq_lens,
+                               scale)
+    return paged_attention_decode_plain(q, cache_k, cache_v, block_tables,
+                                        seq_lens, scale)
+
+
+def paged_attention_decode_plain(q, cache_k, cache_v, block_tables, seq_lens,
+                                 scale=None):
+    """The gather path of ``paged_attention_decode``: every row reads its
+    WHOLE table. Gathers each sequence's blocks into a [B, T_max, kv, D]
+    view (T_max = max_blocks_per_seq * block_size) and masks
+    t <= seq_lens[b].
 
     QK and PV are written as multiply + reduce, not as einsums: every
     (lane, kv head) pair has its OWN gathered K and V, so as a matmul each
@@ -629,11 +674,7 @@ def paged_attention_decode(q, cache_k, cache_v, block_tables, seq_lens,
     that to 8 sublanes and materializes the padded f32 operand
     ([8, B, T_max, kv, D]: 18 GB at 136 lanes x 1024 x 32 x 128, refused
     outright for a v5e). The fused multiply-reduce reads the gathered
-    bf16 blocks once and keeps nothing wider than the logits.
-
-    The whole of it runs under ``jax.named_scope("paged_attention")``, so
-    a device trace can tell the serving programs' attention (gather,
-    scores, softmax, values) from the rest of a layer by name."""
+    bf16 blocks once and keeps nothing wider than the logits."""
     B, n_q, D = q.shape
     nb, bs, n_kv, _ = cache_k.shape
     groups = n_q // n_kv

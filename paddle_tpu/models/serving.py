@@ -118,7 +118,7 @@ class _Mon:
                  "preemptions", "cancelled",
                  "spec_drafted", "spec_accepted", "spec_rate", "pool_bytes",
                  "jit_compiles", "jit_hits", "jit_sigs",
-                 "phase_ns", "steps", "token_gap")
+                 "phase_ns", "steps", "token_gap", "attn_blocks")
 
 
 _MON = None
@@ -188,6 +188,8 @@ def _mon():
         o.token_gap = m.histogram(
             "paddle_tpu_serving_token_gap_ns",
             buckets=m.catalog.TOKEN_GAP_NS_BUCKETS)
+        o.attn_blocks = m.counter("paddle_tpu_serving_attn_blocks_total",
+                                  labelnames=("extent",))
         _MON = o
     return _MON
 
@@ -1149,6 +1151,23 @@ class ContinuousBatchingEngine:
             self._phase = ph
             self._phases.append(ph)
 
+    def _count_attn_blocks(self, mon, positions, lanes):
+        """How far the paged attention's ragged read engages this step:
+        of the ``lanes`` x table-width blocks its lanes' rows span, the
+        kernel reads ``position // block_size + 1`` per valid lane
+        (``positions``: theirs, every iteration's for a burst); the plain
+        gather path reads them all."""
+        total = lanes * self._pager.max_blocks_per_seq
+        e = self._inner
+        if _pk._kernel_applies(
+                jax.ShapeDtypeStruct((e.num_heads, e.head_dim), e.emb.dtype),
+                self._pools[0][0]):             # (an int8 pool: never)
+            read = int((positions // self.block_size + 1).sum())
+        else:
+            read = total
+        mon.attn_blocks.labels("read").inc(read)
+        mon.attn_blocks.labels("skipped").inc(total - read)
+
     def _ensure(self, need):
         """ensure_capacity with radix-cache relief: pool exhaustion evicts
         exactly the LRU cache-only blocks the grant is short of, then
@@ -1443,6 +1462,8 @@ class ContinuousBatchingEngine:
             self._phase.attrs = {
                 "n_decode": nd, "n_draft": n_dec_lanes - nd,
                 "n_prefill": n_lanes - n_dec_lanes, "budget": T}
+        if mon.state.on:
+            self._count_attn_blocks(mon, positions[:n_lanes], T)
         self._next_phase("serving.dispatch", "mixed")
         out_dev, self._pools = step(
             jnp.asarray(pack_np), self._pools, self._pager.block_tables,
@@ -1688,6 +1709,10 @@ class ContinuousBatchingEngine:
         burst = self._burst_jit()
         if self._phase.span is not None:
             self._phase.attrs = {"n_decode": len(decode_slots), "burst": K}
+        if mon.state.on:
+            self._count_attn_blocks(
+                mon, np.add.outer(self.lens[decode_slots], np.arange(K)),
+                self.max_batch * K)
         self._next_phase("serving.dispatch", "burst")
         toks_dev, self._pools = burst(
             jnp.asarray(pack), self._pools, self._pager.block_tables,

@@ -95,6 +95,17 @@ METRICS = {
         "prefill chunks aboard) | burst (decode_burst fused decode "
         "iterations). Counted with the phase nanoseconds, once per "
         "step() that ran its program and routed the result."),
+    "paddle_tpu_serving_attn_blocks_total": (
+        "counter", ("extent",),
+        "KV blocks under the paged attention of completed engine steps: "
+        "every lane of a step's program spans its table row (lanes x "
+        "table width blocks a layer: the token budget for a mixed step, "
+        "max_batch x decode_burst for a burst). extent=read: the blocks "
+        "the attention has to read, position // block_size + 1 per valid "
+        "lane and burst iteration, where the Pallas kernel runs; all of "
+        "them where the plain gather path runs (it reads the whole "
+        "table). extent=skipped: the rest. Counted in the schedule "
+        "phase, per layer not multiplied out."),
     "paddle_tpu_serving_token_gap_ns": (
         "histogram", (),
         "Time between one request's consecutive output tokens, observed "

@@ -16,7 +16,10 @@ Contracts under test:
 4. ``pop_stats(rid)["token_times_ns"]`` holds one time per token, always;
    ``paddle_tpu_serving_token_gap_ns`` observes the gaps under the monitor;
 5. a profiled slice shows the step and its phases on a ``/host:`` plane;
-6. a traced mesh step never lowers the program to fill its span's attrs.
+6. a traced mesh step never lowers the program to fill its span's attrs;
+7. ``paddle_tpu_serving_attn_blocks_total{extent}`` (ISSUE 30): read +
+   skipped = lanes x table width per step, a burst counts every iteration,
+   the plain path reads everything, the monitor off counts nothing.
 """
 import glob
 import os
@@ -278,6 +281,55 @@ class TestEnginePhases:
         assert _counter("paddle_tpu_serving_step_phase_ns_total") == {}
         assert monitor.snapshot()["metrics"][
             "paddle_tpu_serving_token_gap_ns"]["values"][""]["count"] == 0
+
+
+class TestAttnBlocks:
+    """The engine of ``_engine()``: 2 slots, table 6 wide (max_len 48 /
+    block 8), 10 lanes a mixed step (2 + chunk 8), bursts of 4."""
+    NAME = "paddle_tpu_serving_attn_blocks_total"
+
+    def _step(self, kind, monkeypatch, ragged):
+        from paddle_tpu.models import paged_kv
+
+        eng = _one_step_of(kind)
+        if ragged:
+            # what the engine counts where the kernel runs (a TPU): the
+            # count is host arithmetic over the positions, so turning the
+            # dispatch's predicate is enough (both programs are compiled)
+            monkeypatch.setattr(paged_kv, "_kernel_applies",
+                                lambda q, pool: True)
+        monitor.enable()
+        before = _counter(self.NAME)
+        eng.step()
+        monitor.disable()
+        return _moved(before, _counter(self.NAME))
+
+    def test_it_is_cataloged(self):
+        assert catalog.spec(self.NAME)[:2] == ("counter", ("extent",))
+
+    @pytest.mark.parametrize("kind,lanes,read", [
+        # the 5-token prompt's chunk at positions 0..4: one block each
+        ("mixed", 10, 5),
+        # one decode lane at positions 5, 6, 7 (block 0) and 8 (block 1)
+        ("burst", 2 * 4, 3 * 1 + 2),
+    ])
+    def test_read_and_skipped_add_up_to_lanes_times_table_width(
+            self, monkeypatch, kind, lanes, read):
+        moved = self._step(kind, monkeypatch, ragged=True)
+        assert moved == {"extent=read": float(read),
+                         "extent=skipped": float(lanes * 6 - read)}
+
+    @pytest.mark.parametrize("kind,lanes", [("mixed", 10), ("burst", 8)])
+    def test_the_plain_path_reads_the_whole_table(self, monkeypatch, kind,
+                                                  lanes):
+        moved = self._step(kind, monkeypatch, ragged=False)
+        assert moved == {"extent=read": float(lanes * 6)}
+
+    def test_the_monitor_off_counts_nothing(self):
+        eng = _one_step_of("mixed")
+        eng.step()
+        eng.step()
+        assert _counter(self.NAME) == {}
 
 
 # --------------------------------------------------------------------------- #

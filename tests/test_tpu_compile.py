@@ -22,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 import paddle_tpu  # noqa: F401
 from paddle_tpu.models import paged_kv
 from paddle_tpu.ops.pallas.flash_attention import flash_attention_fwd
+from paddle_tpu.ops.pallas.paged_attention import paged_attention
 
 
 @pytest.fixture(scope="module")
@@ -52,11 +53,12 @@ def one_chip(topo):
 
 @pytest.fixture
 def mosaic(monkeypatch):
-    """The kernel asks jax.devices() whether to interpret, and sees the CPU
-    here: steer it to the real lowering (through sys.modules — the package
+    """The kernels ask jax.devices() whether to interpret, and see the CPU
+    here: steer them to the real lowering (through sys.modules — the package
     re-exports a function under the module's name)."""
-    mod = sys.modules["paddle_tpu.ops.pallas.flash_attention"]
-    monkeypatch.setattr(mod, "_interpret", lambda: False)
+    for name in ("flash_attention", "paged_attention"):
+        mod = sys.modules["paddle_tpu.ops.pallas." + name]
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
 FLASH_SHAPES = [
@@ -104,20 +106,39 @@ def test_flash_backward_is_refused_past_its_vmem(one_chip, mosaic):
         bwd.lower(*_qkv(one_chip, 1, 8192, 32, 8, 128)).compile()
 
 
-def test_paged_decode_attention_fits_the_mixed_step(one_chip):
-    """The serving mixed step's attention at chip_smoke's shape: 136 lanes
-    (max_batch 8 + chunk 128) x max_len 1024 x 32 kv heads x 128. Written as
-    einsums the compiler padded the one-row matmuls to 8 sublanes and asked
-    for 18 GB; as multiply + reduce it stays under a quarter of the HBM."""
-    T, width, bs, kv, D, nb = 136, 16, 64, 32, 128, 129
-
+def _paged_shapes(one_chip, T, width, bs, n_q, kv, D, nb):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    compiled = jax.jit(paged_kv.paged_attention_decode).lower(
-        sds((T, kv, D), jnp.bfloat16), sds((nb, bs, kv, D), jnp.bfloat16),
-        sds((nb, bs, kv, D), jnp.bfloat16), sds((T, width), jnp.int32),
-        sds((T,), jnp.int32)).compile()
+    return (sds((T, n_q, D), jnp.bfloat16), sds((nb, bs, kv, D), jnp.bfloat16),
+            sds((nb, bs, kv, D), jnp.bfloat16), sds((T, width), jnp.int32),
+            sds((T,), jnp.int32))
+
+
+@pytest.mark.parametrize("n_q,kv", [
+    pytest.param(32, 32, id="mha"),            # deepseek-7b-serve-offline
+    pytest.param(32, 8, id="gqa32:8"),         # Mistral-7B's heads
+])
+def test_paged_attention_kernel_compiles_for_v5e(one_chip, mosaic, n_q, kv):
+    """The serving programs' attention at the serve cell's shape: 144 lanes
+    (max_batch 16 + chunk 128), table 16 x block 64, head dim 128, a pool of
+    257 bf16 blocks. ONE kernel, and no gathered copy of the cache beside
+    it: the plain path below asks for 3.8 GB of temporaries here."""
+    compiled = jax.jit(paged_attention).lower(
+        *_paged_shapes(one_chip, 144, 16, 64, n_q, kv, 128, 257)).compile()
+    assert compiled.as_text().count(
+        "custom_call_target=\"tpu_custom_call\"") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2 ** 20
+
+
+def test_paged_decode_attention_fits_the_mixed_step(one_chip):
+    """The PLAIN path (the kernel's reference, and what runs where the kernel
+    does not apply) at chip_smoke's shape: 136 lanes (max_batch 8 + chunk
+    128) x max_len 1024 x 32 kv heads x 128. Written as einsums the compiler
+    padded the one-row matmuls to 8 sublanes and asked for 18 GB; as
+    multiply + reduce it stays under a quarter of the HBM."""
+    compiled = jax.jit(paged_kv.paged_attention_decode_plain).lower(
+        *_paged_shapes(one_chip, 136, 16, 64, 32, 32, 128, 129)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2 ** 30
 
 
