@@ -1,8 +1,9 @@
 """What both drivers need from jax and from the program's public surface:
-compile counting, peak memory, model construction with the benchmark's weights,
-host spans for the profiler. Construction, compile counting and the memory
+compile counting, peak memory, loading a builder's weights into its model, host
+spans for the profiler and the traced slice. Compile counting and the memory
 reading follow ``chip_smoke.py`` (PR 24), which ran on the chip; nothing here
-imports it."""
+imports it, and nothing here names a model: a cell's model and weights are its
+builder's (``benchmarks/builders/<name>.py``)."""
 from __future__ import annotations
 
 import contextlib
@@ -70,51 +71,12 @@ def annotate(name):
     return jax.profiler.TraceAnnotation(name)
 
 
-def construct_model(cfg):
-    """The program's own ``LlamaForCausalLM`` at the configuration's sizes.
-
-    The constructor draws every weight on the host (``Normal.__call__``), in
-    float32, whatever it is given; it runs with the CPU as jax's default
-    device, so that the draw is never shipped to the chip. ``load_weights``
-    then replaces every value."""
-    import jax
-
-    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
-
-    opts = dict(cfg.get("model", {}))
-    dtype = opts.pop("dtype", "bfloat16")
-    heads = cfg["num_attention_heads"]
-    if cfg.get("head_dim") and cfg["head_dim"] * heads != cfg["hidden_size"]:
-        raise ValueError("LlamaConfig derives head_dim as hidden_size / heads")
-    lcfg = LlamaConfig(
-        vocab_size=cfg["vocab_size"], hidden_size=cfg["hidden_size"],
-        intermediate_size=cfg["intermediate_size"],
-        num_hidden_layers=cfg["num_hidden_layers"],
-        num_attention_heads=heads,
-        num_key_value_heads=cfg["num_key_value_heads"],
-        max_position_embeddings=cfg["max_position_embeddings"],
-        initializer_range=cfg.get("initializer_range", 0.02),
-        rms_norm_eps=cfg["rms_norm_eps"], rope_theta=cfg["rope_theta"],
-        tie_word_embeddings=cfg.get("tie_word_embeddings", False),
-        dtype=dtype, **opts)
-    with jax.default_device(jax.devices("cpu")[0]):
-        model = LlamaForCausalLM(lcfg)
-        model.to(dtype=dtype)
-    return model
-
-
-def load_weights(model, cfg, seed):
-    """The benchmark's weights, made on the device from the seed in one jitted
-    call (bfloat16, what is served; a float32 model holds them widened), loaded
-    through ``set_state_dict`` as a checkpoint would be. Returns the number of
-    parameters."""
-    import jax.numpy as jnp
-
+def load_weights(model, made):
+    """``made`` (``{state-dict name: array}``, the cell's builder's, on the
+    device) loaded through ``set_state_dict`` as a checkpoint would be: a name
+    the model lacks or misses is an error. Returns the number of parameters."""
     import paddle_tpu as paddle
 
-    import weights as W
-
-    made = W.make_all(seed, cfg, jnp.bfloat16)
     missing, unexpected = model.set_state_dict(
         {name: paddle.Tensor(value) for name, value in made.items()})
     if missing or unexpected:
@@ -124,15 +86,27 @@ def load_weights(model, cfg, seed):
 
 
 class SliceTracer:
-    """Profile a short slice of the window into ``directory`` and reduce it."""
+    """Profile a short slice of the window into ``directory`` and reduce it.
+
+    ``read_at_edges``, where a driver sets it, is called at the two instants
+    at which the profiler starts and stops (before the one, after the other's
+    clock is read), and ``edges`` keeps what it returned: what the program has
+    counted at each edge of the slice."""
 
     def __init__(self, directory):
         self.directory = directory
         self.t_start = self.t_stop = None
+        self.read_at_edges = None
+        self.edges = []
+
+    def _read_edge(self):
+        if self.read_at_edges is not None:
+            self.edges.append(self.read_at_edges())
 
     def start(self):
         import jax
 
+        self._read_edge()
         jax.profiler.start_trace(self.directory)
         self.t_start = time.perf_counter()
 
@@ -140,6 +114,7 @@ class SliceTracer:
         import jax
 
         self.t_stop = time.perf_counter()
+        self._read_edge()
         jax.profiler.stop_trace()
 
     @property

@@ -13,11 +13,17 @@ has its own first-token and finish time from the same state of the engine as
 the window had; then the peak memory is read, the engine is freed, and the
 reference scores a seeded sample of what finished inside the window.
 
-Engine settings come from the configuration's ``engine`` group. Traffic
+The model and its weights are the cell's builder's (``ctx["builder"]``); the
+engine's settings come from the configuration's ``engine`` group. Traffic
 parameters: see the generator, plus ``prime_steps``, ``trace_seconds``,
 ``check_requests``, ``grace_seconds`` and ``counters`` (the program's monitor
-counters whose increase over the window a traced run reads). Times in the
-records are seconds from the window's start (negative: before it opened).
+counters whose increase over the window a traced run reads). A traced run
+also reads every counter series of the program's monitor at the two instants
+at which the profiler starts and stops, both between two steps with the device
+at rest, and hands the increase to the readers as ``slice_counters``: what the
+program counted for exactly the steps whose operations the trace holds. Times
+in the records are seconds from the window's start (negative: before it
+opened).
 """
 from __future__ import annotations
 
@@ -126,6 +132,26 @@ def check(ref_mod, seed, cfg, sample, control=None):
                                  sample["short"])
 
 
+def counter_series():
+    """Every counter series of the program's monitor, now:
+    ``{counter: {"label=value,...": count}}``, keyed as ``monitor.snapshot()``
+    keys them (no histogram is ranked and nothing is formatted: this is read
+    inside the window)."""
+    from paddle_tpu import monitor
+
+    return {name: {",".join(f"{k}={v}" for k, v in zip(m.labelnames, values)):
+                   child.value for values, child in m.children()}
+            for name, m in monitor.registry.collect() if m.kind == "counter"}
+
+
+def increase(before, after):
+    """``after - before`` of two ``counter_series``; a series that the first
+    reading lacks started at nought."""
+    return {name: {key: value - before.get(name, {}).get(key, 0.0)
+                   for key, value in series.items()}
+            for name, series in after.items()}
+
+
 def build(model, cfg):
     from paddle_tpu.models.serving import ContinuousBatchingEngine
 
@@ -198,11 +224,13 @@ def run(ctx):
 
     cfg, traffic, phases = ctx["config"], ctx["traffic"], ctx["phases"]
     seed, seconds = ctx["seed"], ctx["seconds"]
+    builder, tracer = ctx["builder"], ctx.get("tracer")
 
     with phases.phase("construct_model"):
-        model = common.construct_model(cfg)
+        model = builder.construct(cfg)
     with phases.phase("load_weights"):
-        n_params = common.load_weights(model, cfg, seed)
+        n_params = common.load_weights(
+            model, builder.weights(seed, cfg, cfg["torch_dtype"]))
         model.eval()
     with phases.phase("build_engine"):
         eng = build(model, cfg)
@@ -212,18 +240,18 @@ def run(ctx):
     with phases.phase("prime_lanes"):
         records, by_rid = prime(eng, offered, traffic)
     counters = None
-    if ctx.get("tracer") is not None:
+    if tracer is not None:
         # a traced run reads the program's own counters beside its clock
         from paddle_tpu import monitor
 
         monitor.enable()
         counters = {name: (lambda c=monitor.counter(name): c.value)
                     for name in traffic.get("counters", [])}
+        tracer.read_at_edges = counter_series
     compiles_before = ctx["compiles"].n
 
     ctx["mark_window_start"]()
-    w = window(eng, records, by_rid, seconds, traffic, ctx.get("tracer"),
-               counters)
+    w = window(eng, records, by_rid, seconds, traffic, tracer, counters)
     compiled = ctx["compiles"].n - compiles_before
     attempted = w["attempted"]
     failed = [r for r in attempted
@@ -243,7 +271,12 @@ def run(ctx):
               for r in attempted]
     inside = _finished_inside(attempted, w["window_s"])
     step_s = sorted(t for _, t in w["occupancy_samples"])
+    sliced = {}
+    if tracer is not None and len(tracer.edges) == 2:
+        sliced = {"slice_counters": increase(*tracer.edges),
+                  "slice_seconds": tracer.t_stop - tracer.t_start}
     return {
+        **sliced,
         "attempted": len(attempted), "failed": len(failed),
         "window_s": w["window_s"], "records": public, "counters": w["counters"],
         "occupancy_samples": [n for n, _ in w["occupancy_samples"]],
@@ -277,7 +310,8 @@ def readings(ctx, model, seed, with_controls):
     the float8 control at the same positions of the same prompts and tokens.
     ``model`` is constructed once and reloaded per seed."""
     cfg, traffic = ctx["config"], ctx["traffic"]
-    common.load_weights(model, cfg, seed)
+    common.load_weights(
+        model, ctx["builder"].weights(seed, cfg, cfg["torch_dtype"]))
     model.eval()
     eng = build(model, cfg)
     offered = ctx["generator"].requests(seed, traffic, cfg)

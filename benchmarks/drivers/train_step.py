@@ -8,7 +8,9 @@ new seeded batch each step, made on the host while the device runs the step
 before. Once the window has closed and the peak memory is read, the state is
 freed and the reference follows the same first steps.
 
-Traffic parameters: ``batch``, ``sequence_length``, ``optimizer`` (``name`` of a
+The model and its weights are the cell's builder's (``ctx["builder"]``); the
+gradient checks go on reading ``benchmarks/weights.py``'s leaves. Traffic
+parameters: ``batch``, ``sequence_length``, ``optimizer`` (``name`` of a
 ``paddle_tpu.optimizer`` class, ``learning_rate`` and its other arguments),
 ``parallelize`` (the config handed to ``mesh.parallelize``), ``check_steps``,
 ``trace_steps``.
@@ -94,12 +96,13 @@ def run(ctx):
 
     cfg, traffic, phases = ctx["config"], ctx["traffic"], ctx["phases"]
     seed, seconds = ctx["seed"], ctx["seconds"]
-    feed = ctx["generator"].batches(seed, traffic, cfg)
+    builder, feed = ctx["builder"], ctx["generator"].batches(seed, traffic, cfg)
 
     with phases.phase("construct_model"):
-        model = common.construct_model(cfg)
+        model = builder.construct(cfg)
     with phases.phase("load_weights"):
-        n_params = common.load_weights(model, cfg, seed)
+        n_params = common.load_weights(
+            model, builder.weights(seed, cfg, cfg["torch_dtype"]))
         model.train()
     with phases.phase("build_step"):
         first = next(feed)
@@ -170,7 +173,8 @@ def readings(ctx, model, seed, with_controls):
     from paddle_tpu import mesh as pmesh
 
     cfg, traffic, gen = ctx["config"], ctx["traffic"], ctx["generator"]
-    common.load_weights(model, cfg, seed)
+    common.load_weights(
+        model, ctx["builder"].weights(seed, cfg, cfg["torch_dtype"]))
     model.train()
     feed = gen.batches(seed, traffic, cfg)
     first = next(feed)

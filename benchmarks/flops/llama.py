@@ -54,3 +54,43 @@ def flash_attention_costs(cfg, batch, seq_len, bytes_per_el=2):
         "dq": (3 * one, 3 * q + 2 * k + 2 * stat),            # q,k,v,do,lse,d -> dq
         "dkv": (4 * one, 2 * q + 4 * k + 2 * stat),           # q,k,v,do,lse,d -> dk,dv
     }
+
+
+ATTN_BLOCKS = "paddle_tpu_serving_attn_blocks_total"
+
+
+def paged_attention_costs(cfg, engine, counters):
+    """``(flops, bytes)`` that ALL calls of the serving programs' paged
+    attention in a traced slice need at the least, from what the program
+    counted over that slice (``counters``: ``{counter: {"label=value": n}}``)
+    and the configuration's ``engine`` group; None where nothing was counted.
+
+    The program counts the KV blocks its attention has to read, a layer's
+    once (``extent=read``: ``position // block_size + 1`` per valid lane and
+    burst iteration), and every layer reads them from its own pool. Bytes:
+    each such block of K and of V once, at the pool's item size; plus one
+    query row read and one output row written per lane that read, for the
+    FEWEST lanes that can have read that many blocks (a lane reads at most
+    its whole table row; the program counts no lanes). A lane that pads a
+    step, and a block that the 128 lanes of a prefill chunk each read again
+    though they share a table row, ARE counted by the program and so are
+    here: what the kernel moves above its algorithm's least is its loss, not
+    this count's. FLOPs: QK^T and PV, 4 x query heads x head_dim for every
+    position of a block read (a lane's last block counts whole, up to
+    block_size - 1 positions more than it attends to: at one FLOP a byte
+    against the chip's 240 this side never bounds the kernel)."""
+    import jax.numpy as jnp
+
+    read = int(counters.get(ATTN_BLOCKS, {}).get("extent=read", 0))
+    if not read:
+        return None
+    layers, heads = cfg["num_hidden_layers"], cfg["num_attention_heads"]
+    hd = cfg.get("head_dim") or cfg["hidden_size"] // heads
+    block = int(engine["block_size"])
+    row_blocks = -(-int(engine["max_len"]) // block)
+    pool_el = jnp.dtype(engine.get("kv_cache_dtype") or cfg["torch_dtype"]).itemsize
+    q_el = jnp.dtype(cfg["torch_dtype"]).itemsize
+    kv_bytes = read * layers * block * cfg["num_key_value_heads"] * hd * 2 * pool_el
+    lanes = -(-read // row_blocks)
+    qo_bytes = lanes * layers * heads * hd * 2 * q_el
+    return 4 * heads * hd * read * block * layers, kv_bytes + qo_bytes

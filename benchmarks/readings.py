@@ -37,8 +37,7 @@ def tool_context(workload, rehearse, seconds):
         print("no tpu (--rehearse runs tiny sizes on the CPU)", file=sys.stderr)
         return None
     ctx = {"config": cfg, "traffic": traffic, "seconds": seconds,
-           "generator": runner._module("generators", traffic["generator"]),
-           "reference": runner._module("reference", cfg["reference"])}
+           **runner.cell_modules(cfg, traffic)}
     return cfg, runner._module("drivers", cfg["driver"]), ctx
 
 
@@ -56,11 +55,9 @@ def main(argv=None):
     cfg, driver, ctx = got
     import jax
 
-    import common
-
     seeds = [int(s) for s in args.seeds.split(",") if s]
     controls = {int(s) for s in args.control_seeds.split(",") if s}
-    model = common.construct_model(cfg)
+    model = ctx["builder"].construct(cfg)
     lower, upper = {}, {}
     for seed in seeds:
         got = driver.readings(ctx, model, seed, seed in controls)

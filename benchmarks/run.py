@@ -3,15 +3,20 @@
 
     python3 benchmarks/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-One process. The cell's configuration, traffic, driver, generator, reference,
-limits and metrics are files found BY NAME (``BENCHMARK.json`` names the cell's
-configuration and traffic; the configuration's file names its driver and its
-reference; the traffic's file names its generator; each metric's file names its
-reader), so a new cell is new files and new entries, and this file holds no
-name of any of them. Without ``--rehearse`` it exits non-zero, and prints no
-result, unless jax's platform is ``tpu`` and holds the chips the cell asks for.
-``--rehearse`` runs the same code at the tiny sizes the files give, on the CPU
-with Pallas in interpret mode, and its result never names a ``tpu``.
+One process. The cell's configuration, traffic, driver, builder, generator,
+reference, limits and metrics are files found BY NAME (``BENCHMARK.json`` names
+the cell's configuration and traffic; the configuration's file names its
+``driver``, its ``builder`` (``benchmarks/builders/<name>.py``: the program's
+model at the configuration's sizes and its weights from the seed; since PR 31,
+when ``common.construct_model`` moved to ``builders/llama.py``) and its
+``reference``; the traffic's file names its ``generator``; each metric's file
+names its ``reader``), so a new cell, of another model class too, is new files
+and new entries, and this file holds no name of any of them. Every one of
+those keys is required: a file that lacks one is an error, there is no default.
+Without ``--rehearse`` it exits non-zero, and prints no result, unless jax's
+platform is ``tpu`` and holds the chips the cell asks for. ``--rehearse`` runs
+the same code at the tiny sizes the files give, on the CPU with Pallas in
+interpret mode, and its result never names a ``tpu``.
 
 The last line of standard output is the result: ``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device`` (and ``breakdown`` when traced), then
@@ -76,6 +81,35 @@ def load_cell(workload, rehearse):
         traffic = _merge(traffic, traffic.get("rehearse", {}))
         limits = _merge(limits, limits.get("rehearse", {}))
     return bench, cell, cfg, traffic, limits
+
+
+def cell_modules(cfg, traffic):
+    """The modules that the cell's files name, as a run's context holds them.
+    Every key is required: there is no default builder, reference or
+    generator."""
+    out = {}
+    for key, kind, spec, what in (("builder", "builders", cfg, "configuration"),
+                                  ("reference", "reference", cfg, "configuration"),
+                                  ("generator", "generators", traffic, "traffic")):
+        if key not in spec:
+            raise SystemExit(f"run.py: the cell's {what} file names no {key!r}")
+        out[key] = _module(kind, spec[key])
+    return out
+
+
+def context(cfg, traffic, seed, seconds, rehearse, tracer=None, fault=None):
+    """What a driver's ``run`` takes. ``marks`` gets the window's start."""
+    import common
+
+    compiles, marks = common.Compiles(), {}
+    return {
+        "config": cfg, "traffic": traffic, "seed": seed, "seconds": seconds,
+        "phases": common.Phases(compiles), "compiles": compiles,
+        "tracer": tracer, "fault": fault, "rehearsal": rehearse,
+        **cell_modules(cfg, traffic), "marks": marks,
+        "mark_window_start":
+            lambda: marks.setdefault("window_start", time.perf_counter()),
+    }
 
 
 def metric_entries(bench, workload, traced):
@@ -145,24 +179,15 @@ def main(argv=None, fault=None):
     import compare
     import xtrace as trace_mod
 
-    compiles = common.Compiles()
-    phases = common.Phases(compiles)
     trace_dir = os.path.join(ROOT, ".bench_out", "trace", args.workload)
     tracer = None
     if args.trace:
         shutil.rmtree(trace_dir, ignore_errors=True)
         os.makedirs(trace_dir, exist_ok=True)
         tracer = common.SliceTracer(trace_dir)
-    marks = {}
-    ctx = {
-        "config": cfg, "traffic": traffic, "seed": args.seed,
-        "seconds": args.seconds, "phases": phases, "compiles": compiles,
-        "tracer": tracer, "fault": fault, "rehearsal": args.rehearse,
-        "generator": _module("generators", traffic["generator"]),
-        "reference": _module("reference", cfg["reference"]),
-        "mark_window_start":
-            lambda: marks.setdefault("window_start", time.perf_counter()),
-    }
+    ctx = context(cfg, traffic, args.seed, args.seconds, args.rehearse,
+                  tracer, fault)
+    phases, compiles, marks = ctx["phases"], ctx["compiles"], ctx["marks"]
     raw = _module("drivers", cfg["driver"]).run(ctx)
     raw["setup_s"] = marks["window_start"] - t_start
     print(json.dumps({"setup_phases": phases.seconds,

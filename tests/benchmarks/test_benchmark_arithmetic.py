@@ -226,15 +226,20 @@ def test_an_unmoved_state_reads_one_and_judge_needs_every_limit():
 
 
 # -- the trace reduction ---------------------------------------------------------
-@pytest.fixture
-def tpu_slice(tmp_path):
+def _xplane(fixture, tmp_path):
+    """A hand-written trace of ``fixtures/`` as the profiler would write it."""
     import jax
 
-    with open(os.path.join(U.FIXTURES, "tpu_slice.textproto")) as f:
+    with open(os.path.join(U.FIXTURES, fixture + ".textproto")) as f:
         raw = jax.profiler.ProfileData.text_proto_to_serialized_xspace(f.read())
-    path = tmp_path / "slice.xplane.pb"
+    path = tmp_path / (fixture + ".xplane.pb")
     path.write_bytes(raw)
     return path
+
+
+@pytest.fixture
+def tpu_slice(tmp_path):
+    return _xplane("tpu_slice", tmp_path)
 
 
 def test_trace_reduction_on_a_tpu_shaped_trace(tpu_slice):
@@ -323,3 +328,93 @@ def test_kernel_roofline_reader_on_hand_made_events():
     assert value == pytest.approx(50.0) and note["fwd"]["bound"] == "compute"
     env["device_ops"] = {0: [("fusion.7", 0.0, 1e6, {})]}
     assert reader.read({"batch": 2, "sequence_length": 4096}, params, env) is None
+
+
+# -- a served kernel's roofline share, from the counters of the traced slice ----
+def _deepseek_paged_costs():
+    """By hand, for 1,000 blocks read a layer at the DeepSeek cut: 12 layers x
+    64 positions x 32 KV heads x 128 x (K and V) x 2 bytes is 12 MiB a block
+    over all layers; the fewest lanes that read 1,000 blocks of rows 16 wide
+    are 63, each with a query and an output row of 32 x 128 bf16 a layer."""
+    kv = 1000 * 12 * 64 * 32 * 128 * 2 * 2
+    assert kv == 1000 * 12 * 2 ** 20
+    lanes = 63
+    return 4 * 32 * 128 * 1000 * 64 * 12, kv + lanes * 12 * 32 * 128 * 2 * 2
+
+
+def _paged_slice(tmp_path=None):
+    """The planted slice: what the driver hands the readers and, where a
+    place to write the trace is given, the device's operations in it."""
+    with open(os.path.join(U.FIXTURES, "paged_slice_counters.json")) as f:
+        planted = json.load(f)
+    raw = {k: planted[k] for k in ("slice_counters", "slice_seconds")}
+    if tmp_path is None:
+        return raw
+    return raw, xtrace.device_ops(xtrace.load(str(_xplane("paged_slice", tmp_path))))
+
+
+def test_paged_attention_costs_against_a_hand_count():
+    cfg, raw = _cfg("deepseek-llm-7b"), _paged_slice()
+    assert flops.paged_attention_costs(cfg, cfg["engine"],
+                                       raw["slice_counters"]) == \
+        _deepseek_paged_costs()
+    # a float32 pool moves twice the bytes of K and V, the queries' stay
+    wide = flops.paged_attention_costs(
+        cfg, dict(cfg["engine"], kv_cache_dtype="float32"), raw["slice_counters"])
+    assert wide[1] - _deepseek_paged_costs()[1] == 1000 * 12 * 2 ** 20
+    # grouped KV heads: K and V at the KV heads' width, FLOPs at the queries'
+    gqa = flops.paged_attention_costs(dict(cfg, num_key_value_heads=8),
+                                      cfg["engine"], raw["slice_counters"])
+    assert gqa[0] == _deepseek_paged_costs()[0]
+    assert gqa[1] == 1000 * 12 * 2 ** 18 + 63 * 12 * 32 * 128 * 2 * 2
+    for nothing in ({}, {flops.ATTN_BLOCKS: {"extent=skipped": 9.0}},
+                    {flops.ATTN_BLOCKS: {"extent=read": 0.0}}):
+        assert flops.paged_attention_costs(cfg, cfg["engine"], nothing) is None
+
+
+@pytest.mark.parametrize("peak_flops,bound", [(197e12, "memory"),
+                                              (0.8e12, "compute")])
+def test_kernel_roofline_counted_on_a_planted_slice(tmp_path, peak_flops, bound):
+    reader = U.load("readers", "kernel_roofline_counted")
+    with open(os.path.join(U.BENCH, "metrics",
+                           "paged_attention_roofline.sat.json")) as f:
+        spec = json.load(f)
+    assert spec["reader"] == "kernel_roofline_counted"
+    raw, dev = _paged_slice(tmp_path)
+    cfg = _cfg("deepseek-llm-7b")
+    env = {"device_ops": dev, "config": cfg, "module": U.load,
+           "peaks": {"bf16_flops_per_s": peak_flops, "hbm_bytes_per_s": 819e9}}
+    value, note = reader.read(raw, spec["params"], env)
+    # the three kernel events (8 + 8 + 4 ms), not the fusion that only names
+    # one among its operands
+    assert note["events"] == 3 and note["seconds"] == pytest.approx(0.020)
+    ops, nbytes = _deepseek_paged_costs()
+    least = max(ops / peak_flops, nbytes / 819e9)
+    assert note["bound"] == bound and (bound == "compute") == \
+        (ops / peak_flops > nbytes / 819e9)
+    assert value == pytest.approx(100.0 * least / 0.020)
+    assert 60 < value < 100
+    assert note["bytes"] == nbytes and note["flops"] == ops
+    assert note["paddle_tpu_serving_attn_blocks_total"]["extent=read"] == 1000.0
+
+
+def test_kernel_roofline_counted_returns_nothing_where_there_is_nothing_to_read(
+        tmp_path):
+    reader = U.load("readers", "kernel_roofline_counted")
+    raw, dev = _paged_slice(tmp_path)
+    cfg = _cfg("deepseek-llm-7b")
+    peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    params = {"costs": {"module": "llama", "function": "paged_attention_costs"},
+              "calls": {"k": {"name_regex": "^%paged_attention"}}}
+    env = {"device_ops": dev, "config": cfg, "module": U.load, "peaks": peaks}
+    assert reader.read(raw, params, env)[0] > 0
+    # no matching event: a program that takes the plain path
+    none = {"calls": {"k": {"name_regex": "^%no_such_kernel"}},
+            "costs": params["costs"]}
+    assert reader.read(raw, none, env) is None
+    # an untraced run, a rehearsal without a peak, a driver that reads no
+    # slice counters (the train driver), a slice in which nothing was counted
+    assert reader.read(raw, params, dict(env, device_ops=None)) is None
+    assert reader.read(raw, params, dict(env, peaks=None)) is None
+    assert reader.read({"batch": 2, "sequence_length": 4096}, params, env) is None
+    assert reader.read({"slice_counters": {}}, params, env) is None

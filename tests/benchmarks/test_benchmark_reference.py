@@ -19,6 +19,7 @@ W = U.load("", "weights")
 common = U.load("", "common")
 compare = U.load("", "compare")
 R = U.load("reference", "llama")
+builder = U.load("builders", "llama")
 
 CFG = dict(vocab_size=256, hidden_size=64, intermediate_size=128,
            num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,
@@ -28,8 +29,8 @@ SEED = 2 ** 31 + 77           # more than 32 signed bits hold
 
 
 def _build_model(cfg, seed, training):
-    model = common.construct_model(cfg)
-    n_params = common.load_weights(model, cfg, seed)
+    model = builder.construct(cfg)
+    n_params = common.load_weights(model, builder.weights(seed, cfg, "bfloat16"))
     model.train() if training else model.eval()
     return model, n_params
 

@@ -114,3 +114,69 @@ def test_an_altered_served_token_reads_not_correct():
 def test_the_unbroken_path_reads_correct_in_process():
     res, _ = U.run_cell_with_fault(_train_cell(), 99, 1, lambda handle: None)
     assert res["correct"] is True
+
+
+def test_slice_counters_are_the_increase_between_the_profilers_start_and_stop(
+        tmp_path):
+    """The serve driver in this process, traced, with the engine's steps
+    counted from outside: what it hands the readers as ``slice_counters`` is
+    what the program counted for the steps made between the profiler's start
+    and its stop, not for the window and not for the whole run."""
+    run = U.load("", "run")
+    _, _, cfg, traffic, _ = run.load_cell(_serve_cell(), True)
+    run.set_environment(True)
+    import common
+    from paddle_tpu import monitor
+
+    calls = []
+
+    def count_steps(eng):
+        real = eng.step
+
+        def step(*a, **k):
+            out = real(*a, **k)
+            calls.append(1)
+            return out
+        eng.step = step
+
+    class Tracer(common.SliceTracer):
+        def start(self):
+            self.calls_at_start = len(calls)
+            super().start()
+
+        def stop(self):
+            super().stop()
+            self.calls_at_stop = len(calls)
+
+    tracer = Tracer(str(tmp_path))
+    ctx = run.context(cfg, traffic, 2 ** 31 + 41, 4.0, True, tracer, count_steps)
+    try:
+        raw = run._module("drivers", cfg["driver"]).run(ctx)
+        whole = monitor.snapshot()["metrics"]
+    finally:
+        monitor.disable()
+        monitor.reset()
+    sliced = raw["slice_counters"]
+    steps = "paddle_tpu_serving_steps_total"
+    in_slice = tracer.calls_at_stop - tracer.calls_at_start
+    assert 0 < in_slice < len(calls)
+    assert sum(sliced[steps].values()) == in_slice
+    assert sum(whole[steps]["values"].values()) > in_slice      # window + drain
+    # the window's own counter (read at its edges) has run for longer
+    tokens = "paddle_tpu_serving_generated_tokens_total"
+    assert 0 < sliced[tokens][""] < raw["counters"][tokens]
+    assert 0 < sliced["paddle_tpu_serving_attn_blocks_total"]["extent=read"] \
+        < whole["paddle_tpu_serving_attn_blocks_total"]["values"]["extent=read"]
+    assert raw["slice_seconds"] == pytest.approx(tracer.t_stop - tracer.t_start)
+    assert raw["slice_seconds"] < raw["window_s"]
+
+
+def test_an_untraced_run_reads_no_slice_counters_and_never_turns_the_monitor_on():
+    from paddle_tpu import monitor
+
+    run = U.load("", "run")
+    _, _, cfg, traffic, _ = run.load_cell(_serve_cell(), True)
+    run.set_environment(True)
+    ctx = run.context(cfg, traffic, 77, 1.0, True)
+    raw = run._module("drivers", cfg["driver"]).run(ctx)
+    assert "slice_counters" not in raw and not monitor.enabled()
