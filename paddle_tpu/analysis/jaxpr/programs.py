@@ -89,7 +89,7 @@ def _build_mixed_step():
     T = eng.max_step_tokens
     fn = jax.jit(eng._inner.build_mixed_step(), donate_argnums=(1,))
     args = (np.zeros((2, T), np.int32), eng._pools,
-            eng._pager.block_tables, np.zeros(T, np.int32),
+            eng._tables(), np.zeros(T, np.int32),
             np.zeros(T, bool), np.zeros(T, bool), eng._inner.weights)
     return trace(fn, args, "serving.mixed_step"), fn, args
 
@@ -102,7 +102,7 @@ def _build_decode_burst():
     fn = jax.jit(eng._inner.build_decode_burst(eng.decode_burst),
                  donate_argnums=(1,))
     args = (np.zeros((2, eng.max_batch), np.int32), eng._pools,
-            eng._pager.block_tables, eng._inner.weights)
+            eng._tables(), eng._inner.weights)
     return trace(fn, args, "serving.decode_burst"), fn, args
 
 

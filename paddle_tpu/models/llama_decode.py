@@ -15,6 +15,7 @@ functional path against the model's own forward.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import numpy as np
@@ -35,6 +36,20 @@ def _rotate_half(x):
     return jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
 
 
+def _partial(rope):
+    """``rope(x, positions, theta)`` on the first ``rotary_dim`` dims of a
+    head (rotate-half within them), the rest passing through; the whole
+    head where ``rotary_dim`` is None or the head's width."""
+    def apply(x, positions, theta, rotary_dim=None):
+        if rotary_dim is None or rotary_dim == x.shape[-1]:
+            return rope(x, positions, theta)
+        return jnp.concatenate(
+            [rope(x[..., :rotary_dim], positions, theta),
+             x[..., rotary_dim:]], axis=-1)
+    return apply
+
+
+@_partial
 def _rope_at(x, positions, theta):
     """x: (B, S, H, D) rotated at absolute 1-D `positions` (S,) — the same
     rotate-half pairing as models/llama.py apply_rotary_pos_emb."""
@@ -47,6 +62,7 @@ def _rope_at(x, positions, theta):
     return x * cos + _rotate_half(x) * sin
 
 
+@_partial
 def _rope_at_rows(x, positions, theta):
     """x: (B, 1, H, D) rotated at PER-ROW absolute `positions` (B,) — the
     ragged-batch form (continuous batching decodes every slot at its own
@@ -58,6 +74,23 @@ def _rope_at_rows(x, positions, theta):
     cos = jnp.cos(emb).astype(x.dtype)[:, None, None, :]
     sin = jnp.sin(emb).astype(x.dtype)[:, None, None, :]
     return x * cos + _rotate_half(x) * sin
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheKind:
+    """One kind of attention layer, as the serving block and the cache
+    manager see it: layers of one kind share their pools' shapes, one block
+    table per sequence and one rotary base. A Llama-shaped model has one
+    kind; a model that mixes full and sliding-window layers has two."""
+
+    name: str                 # "full" | "window": the counters' label
+    num_kv: int
+    head_dim: int             # of Q and K
+    v_head_dim: int
+    theta: float
+    rotary_dim: int           # leading dims of a head that are rotated
+    window: int | None = None   # positions attended to, the current included
+    flat: bool = False        # pools stored [blocks, block_size, kv * dim]
 
 
 class _PagedCache:
@@ -72,8 +105,40 @@ class _PagedCache:
         self.pools = pools
 
 
+class _Pagers:
+    """The pagers of a model with several cache kinds, for lockstep
+    decoding: rows advance together in every kind, nothing is shared (no
+    beam fork), and a window kind keeps its whole history."""
+
+    def __init__(self, pagers):
+        self.pagers = pagers
+        self.batch = pagers[0].batch
+
+    def ensure_capacity(self, seq_lens_next):
+        for pg in self.pagers:
+            pg.ensure_capacity(seq_lens_next)
+
+    def make_tail_exclusive(self, pos, pools):
+        return pools                     # no fork, so no shared block
+
+    def fork_rows(self, parent_rows):
+        raise NotImplementedError(
+            "beam search over several cache kinds is not supported")
+
+
+def _tables(pager):
+    """The block tables a paged program takes: one a cache kind."""
+    if isinstance(pager, _Pagers):
+        return tuple(pg.block_tables for pg in pager.pagers)
+    return (pager.block_tables,)
+
+
 class LlamaDecodeEngine:
     """Greedy/temperature decoding with a per-layer KV cache."""
+
+    # a factor on V after its projection (1: none); a model that has one
+    # sets it in _extract
+    v_scale = 1.0
 
     def __init__(self, model, max_len=None, kv_cache_dtype=None,
                  kv_cache_layout=None, block_size=64):
@@ -102,10 +167,42 @@ class LlamaDecodeEngine:
         self._pager = None   # built at prefill (batch known then)
         self.max_len = int(max_len or cfg.max_position_embeddings)
         self.num_heads = cfg.num_attention_heads
-        self.num_kv = cfg.num_key_value_heads
-        self.head_dim = cfg.head_dim
+        self._extract(model)
+        # the first kind's sizes under their old names: the dense cache and
+        # the int8 pools know one kind only
+        kind = self.kinds[0]
+        self.num_kv, self.head_dim = kind.num_kv, kind.head_dim
+        self.theta = kind.theta
+        if len(self.kinds) > 1 or kind.flat or kind.window is not None \
+                or kind.v_head_dim != kind.head_dim:
+            if not self.paged or self.kv_int8:
+                raise ValueError(
+                    "a model whose attention layers differ in kind, or in "
+                    "their K and V widths, is served from the paged "
+                    "bfloat16/float32 cache only (kv_cache_layout='paged', "
+                    "no kv_cache_dtype)")
+        # every weight the compiled programs read, as ONE pytree, passed to
+        # them as an argument (the last one): an array a jitted function
+        # merely closes over is embedded in the program as a literal, which
+        # at real widths makes a multi-GB module that cannot be serialized
+        # and a second copy of the weights on the device
+        self.weights = {"layers": self.layers, "emb": self.emb,
+                        "norm_w": self.norm_w, "head_w": self.head_w}
+
+    def _extract(self, model):
+        """Read the model into what the serving block runs on: ``kinds``
+        (the cache kinds), ``layer_kind`` (each layer's index into them),
+        ``layers`` (one dict of weights a layer; its keys tell the block
+        what the layer is: ``sink`` a per-head sink logit, ``router`` an
+        expert MLP in place of ``gate`` / ``up`` / ``down``), ``emb``,
+        ``norm_w``, ``head_w``, ``eps``. A Llama-shaped decoder is the
+        special case of one kind, whole-head rotary and dense MLPs."""
+        cfg = model.config
         self.eps = cfg.rms_norm_eps
-        self.theta = cfg.rope_theta
+        self.kinds = (CacheKind(
+            "full", cfg.num_key_value_heads, cfg.head_dim, cfg.head_dim,
+            cfg.rope_theta, cfg.head_dim),)
+        self.layer_kind = (0,) * len(model.llama.layers)
 
         def _w(layer):
             """Dense weight of a Linear OR a WeightOnlyLinear (dequantized
@@ -134,13 +231,6 @@ class LlamaDecodeEngine:
         head = model.lm_head
         self.head_w = (jnp.swapaxes(self.emb, 0, 1) if head._tied
                        else head.weight.value)
-        # every weight the compiled programs read, as ONE pytree, passed to
-        # them as an argument (the last one): an array a jitted function
-        # merely closes over is embedded in the program as a literal, which
-        # at real widths makes a multi-GB module that cannot be serialized
-        # and a second copy of the weights on the device
-        self.weights = {"layers": self.layers, "emb": self.emb,
-                        "norm_w": self.norm_w, "head_w": self.head_w}
 
     # -- cache ---------------------------------------------------------------
     def init_cache(self, batch):
@@ -189,18 +279,27 @@ class LlamaDecodeEngine:
         return out
 
     # -- functional blocks ---------------------------------------------------
-    def _attend(self, q, ck, cv, pos_mask):
-        """q: (B, S, Hq, D) vs full cache (B, max_len, Hkv, D)."""
-        rep = self.num_heads // self.num_kv
+    def _attend(self, q, ck, cv, pos_mask, sink=None):
+        """q: (B, S, Hq, D) vs full cache (B, max_len, Hkv, D); V may have
+        its own width. ``sink`` (Hq,) is one more logit a head in the
+        softmax's denominator, with no value row."""
+        rep = self.num_heads // ck.shape[2]
         if rep > 1:
             ck = jnp.repeat(ck, rep, axis=2)
             cv = jnp.repeat(cv, rep, axis=2)
-        logits = jnp.einsum("bshd,bthd->bhst", q, ck) / np.sqrt(self.head_dim)
+        logits = jnp.einsum("bshd,bthd->bhst", q, ck) / np.sqrt(q.shape[-1])
         logits = jnp.where(pos_mask[:, None, :, :], logits,
                            jnp.asarray(-1e30, logits.dtype))
         # promote, don't demote: f64 parity runs must stay f64
         ct = jnp.promote_types(q.dtype, jnp.float32)
-        probs = jax.nn.softmax(logits.astype(ct), -1).astype(q.dtype)
+        logits = logits.astype(ct)
+        if sink is not None:
+            col = jnp.broadcast_to(sink.astype(ct)[None, :, None, None],
+                                   logits.shape[:3] + (1,))
+            logits = jnp.concatenate([logits, col], axis=-1)
+        probs = jax.nn.softmax(logits, -1).astype(q.dtype)
+        if sink is not None:
+            probs = probs[..., :-1]
         return jnp.einsum("bhst,bthd->bshd", probs, cv)
 
     def _block(self, p, x, cache_kv, positions, pos_mask):
@@ -223,7 +322,7 @@ class LlamaDecodeEngine:
             cv = lax.dynamic_update_slice(cv, v, (0, start, 0, 0))
             new_cache = (ck, cv)
             attn = self._attend(q, ck, cv, pos_mask)
-        return self._post_attn(p, x, attn), new_cache
+        return self._post_attn(p, x, attn)[0], new_cache
 
     def _forward(self, ids, cache, start_pos, w):
         """ids: (B, S) absolute positions start_pos..start_pos+S-1."""
@@ -241,109 +340,139 @@ class LlamaDecodeEngine:
         return x @ w["head_w"], new_cache
 
     # -- paged forward paths (models/paged_kv.py pool + tables) --------------
-    def _qkv_rope(self, p, x, positions):
-        """Shared pre-attention: rms -> q/k/v projections -> RoPE."""
+    def _qkv_rope(self, p, x, positions, kind=None, rows=False):
+        """Shared pre-attention: rms -> q/k/v projections -> RoPE on the
+        kind's rotary dims, at ``positions`` (S,) shared by the batch, or
+        with ``rows`` at one position a row (S == 1). V is scaled where the
+        layer says so (``v_scale``: a static number among its weights'
+        keys)."""
+        kind = self.kinds[0] if kind is None else kind
         B, S, _ = x.shape
         h = _rms(x, p["ln1"], self.eps)
-        q = (h @ p["wq"]).reshape(B, S, self.num_heads, self.head_dim)
-        k = (h @ p["wk"]).reshape(B, S, self.num_kv, self.head_dim)
-        v = (h @ p["wv"]).reshape(B, S, self.num_kv, self.head_dim)
-        return (_rope_at(q, positions, self.theta),
-                _rope_at(k, positions, self.theta), v)
+        q = (h @ p["wq"]).reshape(B, S, self.num_heads, kind.head_dim)
+        k = (h @ p["wk"]).reshape(B, S, kind.num_kv, kind.head_dim)
+        v = (h @ p["wv"]).reshape(B, S, kind.num_kv, kind.v_head_dim)
+        if self.v_scale != 1.0:
+            v = v * jnp.asarray(self.v_scale, v.dtype)
+        rope = _rope_at_rows if rows else _rope_at
+        return (rope(q, positions, kind.theta, kind.rotary_dim),
+                rope(k, positions, kind.theta, kind.rotary_dim), v)
 
-    def _post_attn(self, p, x, attn):
-        """Shared epilogue: output proj + residual + rms + SwiGLU MLP."""
+    def _post_attn(self, p, x, attn, valid=None):
+        """Shared epilogue: output proj + residual + rms + the layer's MLP,
+        dense SwiGLU or (``router`` among the layer's weights) the experts
+        this engine holds. Returns ``(x, pairs)``: ``pairs`` [3] int32 counts
+        the (token, expert) pairs on held experts and in all, and the held
+        experts that got one, over ``valid`` tokens; None for a dense
+        layer."""
         B, S = x.shape[0], x.shape[1]
         x = x + attn.reshape(B, S, -1) @ p["wo"]
         h2 = _rms(x, p["ln2"], self.eps)
-        mlp = (jax.nn.silu(h2 @ p["gate"]) * (h2 @ p["up"])) @ p["down"]
-        return x + mlp
+        if "router" not in p:
+            mlp = (jax.nn.silu(h2 @ p["gate"]) * (h2 @ p["up"])) @ p["down"]
+            return x + mlp, None
+        from ..incubate.distributed.models.moe.held_experts import (
+            held_experts_mlp)
 
-    def _block_paged_prefill(self, p, x, pool, tables, lens):
-        """Prompt pass: causal self-attention within the prompt (the history
-        IS the prompt), k/v written into the sequence's blocks."""
+        y, pairs = held_experts_mlp(
+            h2.reshape(B * S, -1), p["router"], p["router_bias"], p["w1"],
+            p["w3"], p["w2"], self.held_lo, self.top_k,
+            None if valid is None else valid.reshape(B * S))
+        return x + y.reshape(x.shape), pairs
+
+    def _block_paged(self, li, p, x, pool, tables, positions, valid=None,
+                     prompt=False, counted=None):
+        """THE serving block of layer ``li``, for every paged program.
+
+        One token per LANE (``x`` (T, 1, hidden)) at a per-lane position
+        against a per-lane block-table row: the continuous-batching MIXED
+        step (decode lanes, draft-verify lanes and the consecutive prompt
+        tokens of a chunk share one program; ``valid`` marks the lanes that
+        are real, padding lanes' writes are dropped), the decode BURST and
+        lockstep decoding (``valid`` None: every row writes, an inactive
+        row into the null block its zero table row points at; ``counted``
+        then marks the rows whose expert pairs count). Writes land
+        before the attention reads the pool (kernel or gather: paged_kv
+        picks), so lanes of one chunk see each other through it, causal by
+        absolute position.
+
+        With ``prompt`` the lockstep PREFILL: ``x`` (B, S, hidden), causal
+        self-attention within the prompt (the history IS the prompt),
+        ``positions`` the prompts' lengths, k/v written into the
+        sequences' blocks.
+
+        The layer's kind gives the pool's shapes, the rotary base and dims,
+        and the window; its weights' keys give the sink and the MLP."""
         from . import paged_kv as _pk
 
-        B, S, _ = x.shape
-        q, k, v = self._qkv_rope(p, x, jnp.arange(S))
-        t_idx = jnp.arange(S)
-        pos_mask = jnp.broadcast_to(
-            t_idx[None, None, :] <= t_idx[None, :, None], (B, S, S))
+        kind = self.kinds[self.layer_kind[li]]
+        sink = p.get("sink")
+        if prompt:
+            B, S, _ = x.shape
+            q, k, v = self._qkv_rope(p, x, jnp.arange(S), kind)
+            t_idx = jnp.arange(S)
+            seen = t_idx[None, None, :] <= t_idx[None, :, None]
+            if kind.window is not None:
+                seen = seen & (t_idx[None, None, :]
+                               > t_idx[None, :, None] - kind.window)
+            pos_mask = jnp.broadcast_to(seen, (B, S, S))
+            if self.kv_int8:
+                kq, kscale = self._quantize_kv(k)
+                vq, vscale = self._quantize_kv(v)
+                pool = _pk.paged_write_prefill_int8(
+                    *pool, tables, positions, kq, kscale, vq, vscale)
+                # attend the QUANTIZED prompt, exactly like the dense int8
+                # engine's prefill (_block -> _attend_int8 over the written
+                # cache) — full-precision prompt attention here would give
+                # the paged engine different logits than dense int8
+                attn = self._attend_int8(q, kq, kscale, vq, vscale, pos_mask)
+            else:
+                pool = _pk.paged_write_prefill(*pool, tables, positions, k, v)
+                attn = self._attend(q, k, v, pos_mask, sink)
+            x, _pairs = self._post_attn(p, x, attn)
+            return x, pool, None
+        q, k, v = self._qkv_rope(p, x, positions, kind, rows=True)
         if self.kv_int8:
-            kq, kscale = self._quantize_kv(k)
+            kq, kscale = self._quantize_kv(k)      # (T, 1, kv, D)
             vq, vscale = self._quantize_kv(v)
-            pool = _pk.paged_write_prefill_int8(*pool, tables, lens,
-                                                kq, kscale, vq, vscale)
-            # attend the QUANTIZED prompt, exactly like the dense int8
-            # engine's prefill (_block -> _attend_int8 over the written
-            # cache) — full-precision prompt attention here would give the
-            # paged engine different logits than dense int8
-            attn = self._attend_int8(q, kq, kscale, vq, vscale, pos_mask)
-        else:
-            pool = _pk.paged_write_prefill(*pool, tables, lens, k, v)
-            attn = self._attend(q, k, v, pos_mask)
-        return self._post_attn(p, x, attn), pool
-
-    def _block_paged_decode(self, p, x, pool, tables, lens):
-        """One decode token per row at PER-ROW position lens[b] (write and
-        RoPE both happen at that position) — the same block serves lockstep
-        decoding (lens = broadcast pos) and continuous batching (ragged)."""
-        from . import paged_kv as _pk
-
-        B = x.shape[0]
-        h = _rms(x, p["ln1"], self.eps)
-        q = (h @ p["wq"]).reshape(B, 1, self.num_heads, self.head_dim)
-        k = (h @ p["wk"]).reshape(B, 1, self.num_kv, self.head_dim)
-        v = (h @ p["wv"]).reshape(B, 1, self.num_kv, self.head_dim)
-        q = _rope_at_rows(q, lens, self.theta)
-        k = _rope_at_rows(k, lens, self.theta)
-        if self.kv_int8:
-            kq, kscale = self._quantize_kv(k)      # (B, 1, kv, D) already
-            vq, vscale = self._quantize_kv(v)
-            pool = _pk.paged_write_decode_int8(
-                *pool, tables, lens, kq[:, 0], kscale[:, 0], vq[:, 0],
-                vscale[:, 0])
+            new = (kq[:, 0], kscale[:, 0], vq[:, 0], vscale[:, 0])
+            if valid is None:
+                pool = _pk.paged_write_decode_int8(*pool, tables, positions,
+                                                   *new)
+            else:
+                pool = _pk.paged_write_mixed_int8(*pool, tables, positions,
+                                                  valid, *new)
             attn = _pk.paged_attention_decode_int8(
-                q[:, 0], *pool, tables, lens)[:, None]
+                q[:, 0], *pool, tables, positions)[:, None]
         else:
-            pool = _pk.paged_write_decode(*pool, tables, lens,
-                                          k[:, 0], v[:, 0])
-            attn = _pk.paged_attention_decode(q[:, 0], *pool, tables,
-                                              lens)[:, None]
-        return self._post_attn(p, x, attn), pool
+            if valid is None:
+                pool = _pk.paged_write_decode(*pool, tables, positions,
+                                              k[:, 0], v[:, 0])
+            else:
+                pool = _pk.paged_write_mixed(*pool, tables, positions, valid,
+                                             k[:, 0], v[:, 0])
+            attn = _pk.paged_attention_decode(
+                q[:, 0], *pool, tables, positions, window=kind.window,
+                sink=sink)[:, None]
+        x, pairs = self._post_attn(p, x, attn,
+                                   valid if counted is None else counted)
+        return x, pool, pairs
 
-    def _block_paged_mixed(self, p, x, pool, row_tables, positions, valid):
-        """One token per LANE at a per-lane position against a per-lane
-        block-table row — the transformer block of the continuous-batching
-        MIXED step, where decode lanes (one token per running request) and
-        chunked-prefill lanes (consecutive prompt tokens of an admitted
-        request) share one compiled program. Writes land before the
-        attention reads the pool (kernel or gather: paged_kv picks), so
-        prefill lanes of the same chunk see each other through it (causal
-        by absolute position)."""
-        from . import paged_kv as _pk
-
-        B = x.shape[0]
-        h = _rms(x, p["ln1"], self.eps)
-        q = (h @ p["wq"]).reshape(B, 1, self.num_heads, self.head_dim)
-        k = (h @ p["wk"]).reshape(B, 1, self.num_kv, self.head_dim)
-        v = (h @ p["wv"]).reshape(B, 1, self.num_kv, self.head_dim)
-        q = _rope_at_rows(q, positions, self.theta)
-        k = _rope_at_rows(k, positions, self.theta)
-        if self.kv_int8:
-            kq, kscale = self._quantize_kv(k)      # (B, 1, kv, D)
-            vq, vscale = self._quantize_kv(v)
-            pool = _pk.paged_write_mixed_int8(
-                *pool, row_tables, positions, valid, kq[:, 0], kscale[:, 0],
-                vq[:, 0], vscale[:, 0])
-            attn = _pk.paged_attention_decode_int8(
-                q[:, 0], *pool, row_tables, positions)[:, None]
-        else:
-            pool = _pk.paged_write_mixed(*pool, row_tables, positions, valid,
-                                         k[:, 0], v[:, 0])
-            attn = _pk.paged_attention_decode(q[:, 0], *pool, row_tables,
-                                              positions)[:, None]
-        return self._post_attn(p, x, attn), pool
+    def _layers_paged(self, w, x, pools, tables, positions, valid=None,
+                      prompt=False, counted=None):
+        """Every layer's ``_block_paged`` in turn. ``tables`` holds one
+        block table a cache kind (already the lanes' rows, for a mixed
+        step). Returns ``(x, pools, pairs)``: ``pairs`` [3] int32 summed
+        over the expert layers, None for a model without any."""
+        new_pools, total = [], None
+        for li, (p, pool) in enumerate(zip(w["layers"], pools)):
+            x, pool, pairs = self._block_paged(
+                li, p, x, pool, tables[self.layer_kind[li]], positions,
+                valid, prompt, counted)
+            new_pools.append(pool)
+            if pairs is not None:
+                total = pairs if total is None else total + pairs
+        return x, new_pools, total
 
     def build_mixed_step(self):
         """The continuous-batching mixed step as a pure function for the
@@ -353,7 +482,8 @@ class LlamaDecodeEngine:
         every lane's K/V into its slot's paged blocks, and returns the
         per-lane greedy token (read only for lanes the scheduler marked
         as emitting). Shapes are fixed by the token budget ``T``, so XLA
-        compiles this exactly once.
+        compiles this exactly once. ``tables`` is a tuple: one block table
+        a cache kind.
 
         Verify mode (self-speculative decoding) rides the SAME program:
         ``chain[i]`` marks lane ``i`` as carrying a DRAFT token that
@@ -370,7 +500,12 @@ class LlamaDecodeEngine:
         overwritten before any mask can read them). With ``chain`` all
         False (speculation off) the flags are all zero and row 0 is the
         plain mixed step — one program serves both modes, so greedy
-        outputs are bit-identical with speculation on or off."""
+        outputs are bit-identical with speculation on or off.
+
+        A model with expert layers gets a third row in the same array (no
+        second download): ``[pairs on held experts, pairs routed, held
+        experts that got a pair]`` of the valid lanes, summed over its
+        expert layers, then zeros."""
         def serving_mixed_step(pack, pools, tables, slot_ids, valid, chain,
                                w):
             # (the function's name is the compiled program's: a device
@@ -380,12 +515,9 @@ class LlamaDecodeEngine:
             # transfers; slot_ids/valid/chain are cached per composition)
             token_ids, positions = pack[0], pack[1]
             x = w["emb"][token_ids][:, None]        # (T, 1, hidden)
-            row_tables = tables[slot_ids]           # (T, max_blocks)
-            new_pools = []
-            for p, pool in zip(w["layers"], pools):
-                x, pool = self._block_paged_mixed(p, x, pool, row_tables,
-                                                  positions, valid)
-                new_pools.append(pool)
+            row_tables = tuple(t[slot_ids] for t in tables)  # (T, max_blocks)
+            x, new_pools, pairs = self._layers_paged(
+                w, x, pools, row_tables, positions, valid)
             x = _rms(x, w["norm_w"], self.eps)
             logits = (x @ w["head_w"])[:, -1]
             # argmax INSIDE the program: the scheduler transfers one
@@ -406,7 +538,10 @@ class LlamaDecodeEngine:
 
             acc, _ = lax.associative_scan(comb, (agree, start))
             accept = acc & chain
-            return jnp.stack([nt, accept.astype(jnp.int32)]), new_pools
+            rows = [nt, accept.astype(jnp.int32)]
+            if pairs is not None:
+                rows.append(jnp.zeros_like(nt).at[:3].set(pairs))
+            return jnp.stack(rows), new_pools
 
         return serving_mixed_step
 
@@ -416,40 +551,46 @@ class LlamaDecodeEngine:
         or admission work is pending: one dispatch + one host round-trip
         emits ``k`` tokens per slot instead of one. Inactive rows write
         into the reserved null block (their table rows are zero), exactly
-        like the single-step path."""
+        like the single-step path. Returns (B, k) tokens; a model with
+        expert layers appends three rows: each iteration's pairs on held
+        experts, pairs routed and held experts that got a pair, over the
+        rows whose position is not 0."""
         def serving_decode_burst(pack, pools, tables, w):
             # (jit_serving_decode_burst in a device trace)
             # pack (2, B) int32: row 0 = current tokens, row 1 = per-row
             # positions (one fused upload per burst)
             tokens, lens = pack[0][:, None], pack[1]
+            counts = self.has_experts
 
             def body(carry, _):
                 toks, pools_c, lens_c = carry
                 x = w["emb"][toks]
-                new_pools = []
-                for p, pool in zip(w["layers"], pools_c):
-                    x, pool = self._block_paged_decode(p, x, pool, tables,
-                                                       lens_c)
-                    new_pools.append(pool)
+                # every row writes (valid None), an idle one into the null
+                # block; pairs are counted over the rows that run
+                x, new_pools, pairs = self._layers_paged(
+                    w, x, pools_c, tables, lens_c,
+                    counted=lens_c > 0 if counts else None)
                 x = _rms(x, w["norm_w"], self.eps)
                 logits = (x @ w["head_w"])[:, -1]
                 nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-                return (nxt[:, None], new_pools, lens_c + 1), nxt
+                out = nxt if pairs is None else jnp.concatenate([nxt, pairs])
+                return (nxt[:, None], new_pools, lens_c + 1), out
 
             (toks, pools, lens), outs = lax.scan(
                 body, (tokens, pools, lens), None, length=k)
-            return jnp.swapaxes(outs, 0, 1), pools    # (B, k)
+            return jnp.swapaxes(outs, 0, 1), pools    # (B [+ 3], k)
 
         return serving_decode_burst
+
+    @property
+    def has_experts(self):
+        return any("router" in p for p in self.layers)
 
     @functools.cached_property
     def _prefill_paged_jit(self):
         def run(ids, pools, tables, lens, w):
-            x = w["emb"][ids]
-            new_pools = []
-            for p, pool in zip(w["layers"], pools):
-                x, pool = self._block_paged_prefill(p, x, pool, tables, lens)
-                new_pools.append(pool)
+            x, new_pools, _ = self._layers_paged(w, w["emb"][ids], pools,
+                                                 tables, lens, prompt=True)
             x = _rms(x, w["norm_w"], self.eps)
             return x @ w["head_w"], new_pools
 
@@ -461,32 +602,50 @@ class LlamaDecodeEngine:
             # lens derives from pos INSIDE the trace: the engine decodes in
             # lockstep, so no per-token host-built array is needed
             lens = jnp.full((token.shape[0],), pos, jnp.int32)
-            x = w["emb"][token]
-            new_pools = []
-            for p, pool in zip(w["layers"], pools):
-                x, pool = self._block_paged_decode(p, x, pool, tables, lens)
-                new_pools.append(pool)
+            x, new_pools, _ = self._layers_paged(w, w["emb"][token], pools,
+                                                 tables, lens)
             x = _rms(x, w["norm_w"], self.eps)
             return (x @ w["head_w"])[:, -1], new_pools
 
         return jax.jit(run, donate_argnums=(1,))
 
-    def _init_paged(self, batch):
+    def make_pagers(self, batch, num_blocks=None, window_blocks=None):
+        """One ``PagedKVCache`` a cache kind (the first is the whole-length
+        kind's, whose pool ``num_blocks`` sizes: default, the worst case of
+        ``batch`` rows + the null block; ``window_blocks`` sizes a window
+        kind's, default the same: a caller that releases blocks behind the
+        window passes less) and the per-layer pool entries, each layer's
+        from its kind's pager."""
         from .paged_kv import PagedKVCache
 
         max_blocks = -(-self.max_len // self.block_size)
-        # pool sized for the worst case + the reserved null block; blocks
-        # are still GRANTED lazily, so a short-lived batch touches few
-        pager = PagedKVCache(
-            num_layers=len(self.layers), num_blocks=batch * max_blocks + 1,
-            block_size=self.block_size, kv_heads=self.num_kv,
-            head_dim=self.head_dim, batch=batch,
-            max_blocks_per_seq=max_blocks, dtype=self.emb.dtype,
-            quantized=self.kv_int8)
-        if self.kv_int8:
-            return pager, list(zip(pager.k, pager.k_scale,
-                                   pager.v, pager.v_scale))
-        return pager, list(zip(pager.k, pager.v))
+        pagers = []
+        for ki, kind in enumerate(self.kinds):
+            n = num_blocks if kind.window is None else window_blocks
+            pagers.append(PagedKVCache(
+                num_layers=self.layer_kind.count(ki),
+                num_blocks=batch * max_blocks + 1 if n is None else n,
+                block_size=self.block_size, kv_heads=kind.num_kv,
+                head_dim=kind.head_dim, batch=batch,
+                max_blocks_per_seq=max_blocks, dtype=self.emb.dtype,
+                quantized=self.kv_int8, v_head_dim=kind.v_head_dim,
+                flat=kind.flat, window=kind.window))
+        nth = [0] * len(self.kinds)
+        pools = []
+        for ki in self.layer_kind:
+            pg, i = pagers[ki], nth[ki]
+            nth[ki] += 1
+            pools.append((pg.k[i], pg.k_scale[i], pg.v[i], pg.v_scale[i])
+                         if self.kv_int8 else (pg.k[i], pg.v[i]))
+        return pagers, pools
+
+    def _init_paged(self, batch):
+        # pools sized for the worst case + the reserved null block; blocks
+        # are still GRANTED lazily, so a short-lived batch touches few.
+        # Lockstep decoding keeps a window layer's whole history (its mask
+        # holds the window); only the continuous-batching engine releases
+        pagers, pools = self.make_pagers(batch)
+        return (pagers[0] if len(pagers) == 1 else _Pagers(pagers)), pools
 
     # -- public API ----------------------------------------------------------
     @functools.cached_property
@@ -511,7 +670,7 @@ class LlamaDecodeEngine:
             pager.ensure_capacity([S] * B)
             lens = jnp.full((B,), S, jnp.int32)
             logits, pools = self._prefill_paged_jit(
-                ids, pools, pager.block_tables, lens, self.weights)
+                ids, pools, _tables(pager), lens, self.weights)
             return logits[:, -1], _PagedCache(pager, pools), S
         cache = self.init_cache(B)
         logits, cache = self._prefill_jit(ids, cache, self.weights)
@@ -550,7 +709,7 @@ class LlamaDecodeEngine:
                 raise
             logits, pools = self._step_paged_jit(
                 jnp.asarray(token, jnp.int32), pools,
-                pager.block_tables, jnp.asarray(pos, jnp.int32),
+                _tables(pager), jnp.asarray(pos, jnp.int32),
                 self.weights)
             return logits, _PagedCache(pager, pools)
         return self._step_jit(jnp.asarray(token, jnp.int32), cache,
@@ -667,7 +826,7 @@ class LlamaDecodeEngine:
             need[::K] = S
             pager.ensure_capacity(need)
             logits, pools = self._prefill_paged_jit(
-                ids, pools, pager.block_tables[::K],
+                ids, pools, (pager.block_tables[::K],),
                 jnp.full((B,), S, jnp.int32), self.weights)
             logits = logits[:, -1]
             cache = _PagedCache(pager, pools)

@@ -8,14 +8,19 @@ don't reserve max_len each, and finished sequences return their blocks).
 The reference implements it as a CUDA serving kernel
 (fluid/operators/fused/block_multi_head_attention_op.cu); TPU-first
 redesign: the pool is a [num_blocks, block_size, kv_heads, head_dim] array
-and the block table drives jnp scatters for the writes. The decode
-attention over a bf16/f32 pool is a Pallas kernel on the TPU
-(ops/pallas/paged_attention.py: it walks each lane's table row itself and
-reads ``position // block_size + 1`` blocks, once, in the pool's dtype);
-elsewhere — CPU runs, head dims the kernel does not tile, the int8 pool —
-it is the plain path: the table drives a jnp gather of the whole row and
-XLA fuses gather -> attention -> reduce. ``paged_attention_decode`` picks
-between the two from its inputs alone.
+and the block table drives jnp scatters for the writes. V rows may have
+their own width, a pool may be stored flat ([num_blocks, block_size,
+kv_heads * dim]: the grouped-query kernel's layout), and a pager may be the
+cache of sliding-window layers, whose rows hand back the blocks behind their
+window (``release_behind``); a model with several kinds of attention layer
+keeps one ``PagedKVCache`` a kind. The decode attention over a bf16/f32 pool
+is a Pallas kernel on the TPU (ops/pallas/paged_attention.py: it walks each
+lane's table row itself, from the window's first block to the position's,
+once, in the pool's dtype; ``paged_attention`` for one query row a KV head,
+``paged_attention_gqa`` for grouped queries over flat pools); elsewhere —
+CPU runs, widths the kernels do not tile, the int8 pool — it is the plain
+path: the table drives a jnp gather of the whole row and XLA fuses gather ->
+attention -> reduce. ``paged_attention_decode`` picks from its inputs alone.
 
 Layout note: the reference kernel stores [max_blocks, kv_heads, block_size,
 head_dim]; here blocks are [block_size, kv_heads, head_dim]-major so the
@@ -81,12 +86,29 @@ class PagedKVCache:
 
     def __init__(self, num_layers, num_blocks, block_size, kv_heads, head_dim,
                  batch, max_blocks_per_seq, dtype=jnp.bfloat16,
-                 quantized=False):
+                 quantized=False, v_head_dim=None, flat=False, window=None):
+        """``v_head_dim`` (default ``head_dim``) gives V rows their own
+        width. ``flat`` stores a block as ``[block_size, kv_heads * dim]``
+        (heads merged into the minor dim: the layout of the grouped-query
+        kernel, whose head dims need not fill 128 lanes each). ``window``
+        makes this the cache of sliding-window layers: a row keeps only the
+        blocks that hold its last ``window`` positions
+        (``release_behind``), so its table is sparse at the head."""
         self.block_size = int(block_size)
         self.num_blocks = int(num_blocks)
         self.max_blocks_per_seq = int(max_blocks_per_seq)
         self.quantized = bool(quantized)
+        self.window = None if window is None else int(window)
+        v_head_dim = head_dim if v_head_dim is None else v_head_dim
+        if quantized and (flat or v_head_dim != head_dim
+                          or window is not None):
+            raise ValueError("the int8 pool is one [block, kv_heads, "
+                             "head_dim] shape for K and V, without a window")
         shape = (num_blocks, block_size, kv_heads, head_dim)
+        vshape = (num_blocks, block_size, kv_heads, v_head_dim)
+        if flat:
+            shape = shape[:2] + (kv_heads * head_dim,)
+            vshape = vshape[:2] + (kv_heads * v_head_dim,)
         if quantized:
             # int8 blocks + per-(token, head) fp32 absmax scales: the same
             # halved-KV-bandwidth lever as the dense int8 cache, paged
@@ -99,7 +121,7 @@ class PagedKVCache:
                             for _ in range(num_layers)]
         else:
             self.k = [jnp.zeros(shape, dtype) for _ in range(num_layers)]
-            self.v = [jnp.zeros(shape, dtype) for _ in range(num_layers)]
+            self.v = [jnp.zeros(vshape, dtype) for _ in range(num_layers)]
         # block 0 is the permanently-reserved NULL block: unassigned table
         # slots point at it, so gathers stay in-bounds without masking reads
         self._free = list(range(num_blocks - 1, 0, -1))
@@ -109,6 +131,20 @@ class PagedKVCache:
         # per-block reference counts: >1 after fork_rows (beam search shares
         # prompt blocks); writes go copy-on-write via make_tail_exclusive
         self._refs = np.zeros(num_blocks, np.int32)
+        # window cache only: per row, the first block index still kept
+        self._first = np.zeros(batch, np.int64)
+
+    def _note_free(self, mon):
+        """The free-blocks gauge follows the whole-length cache; a window
+        cache beside it has its own pool and does not overwrite it."""
+        if self.window is None:
+            mon[1].set(len(self._free))
+
+    @property
+    def blocks_in_use(self):
+        """Blocks granted to rows or pinned by a cache (the null block is
+        neither)."""
+        return self.num_blocks - 1 - len(self._free)
 
     # -- host-side allocator -------------------------------------------------
     def ensure_capacity(self, seq_lens_next):
@@ -130,14 +166,21 @@ class PagedKVCache:
                 "paged KV pool exhausted: no free blocks (injected fault; "
                 f"pool={self.num_blocks}, block={self.block_size})")
         tables = self._tables_np
-        owned = (tables > 0).sum(axis=1)
+        if self.window is None:
+            owned = (tables > 0).sum(axis=1)
+        else:
+            # released blocks leave holes at a row's head: what counts is
+            # how far the row has been granted
+            held = tables > 0
+            owned = np.where(held.any(axis=1), tables.shape[1]
+                             - np.argmax(held[:, ::-1], axis=1), 0)
         need_arr = np.asarray(seq_lens_next)
         needed = -(-np.maximum(need_arr.astype(np.int64), 0)
                    // self.block_size)
         mon = _mon()
         if (needed <= owned).all():
             if mon[0].on:
-                mon[1].set(len(self._free))
+                self._note_free(mon)
             return
         changed = False
         for b, need_tok in enumerate(need_arr):
@@ -162,16 +205,46 @@ class PagedKVCache:
                 owned[b] += 1
                 changed = True
         if mon[0].on:
-            mon[1].set(len(self._free))
+            self._note_free(mon)
         if changed:
             # upload a COPY: jnp.asarray of an aligned numpy array may be
             # zero-copy on CPU, and an in-flight async step could still be
             # reading the previous device view while the host mirror mutates
             self.block_tables = jnp.asarray(tables.copy())
 
+    def release_behind(self, seq_lens):
+        """Window cache: free every block that lies wholly behind the
+        window of a row's NEXT query (position ``seq_lens[b]``, which reads
+        positions ``seq_lens[b] - window + 1 ..``); later queries read
+        later positions only. Returns the number of blocks freed. The
+        freed table slots point at the null block; the attention never
+        reads below a lane's first window block."""
+        first = np.maximum(np.asarray(seq_lens, np.int64) - self.window + 1,
+                           0) // self.block_size
+        rows = np.flatnonzero(first > self._first)
+        if not len(rows):
+            return 0
+        tables, freed = self._tables_np, 0
+        for b in rows:
+            for i in range(int(self._first[b]), int(first[b])):
+                blk = int(tables[b, i])
+                if blk > 0:
+                    tables[b, i] = 0
+                    self._refs[blk] -= 1
+                    if self._refs[blk] == 0:
+                        self._free.append(blk)
+                        freed += 1
+            self._first[b] = first[b]
+        self.block_tables = jnp.asarray(tables.copy())
+        mon = _mon()
+        if mon[0].on:
+            self._note_free(mon)
+        return freed
+
     def free_sequence(self, b):
         """Drop sequence b's block references; blocks return to the pool
         when their last referencing row lets go."""
+        self._first[b] = 0
         tables = self._tables_np
         for blk in tables[b]:
             if blk > 0:
@@ -182,7 +255,7 @@ class PagedKVCache:
         self.block_tables = jnp.asarray(tables.copy())
         mon = _mon()
         if mon[0].on:
-            mon[1].set(len(self._free))
+            self._note_free(mon)
 
     # -- external references (radix/prefix cache) ----------------------------
     def retain_blocks(self, blocks):
@@ -209,7 +282,7 @@ class PagedKVCache:
                 freed += 1
         mon = _mon()
         if mon[0].on:
-            mon[1].set(len(self._free))
+            self._note_free(mon)
         return freed
 
     def adopt_blocks(self, b, blocks):
@@ -245,7 +318,7 @@ class PagedKVCache:
             self._refs[blk] = 1
         mon = _mon()
         if mon[0].on:
-            mon[1].set(len(self._free))
+            self._note_free(mon)
         return blks
 
     def place_blocks(self, b, blocks):
@@ -347,7 +420,7 @@ class PagedKVCache:
         if pairs:
             if mon[0].on:
                 mon[2].inc(len(pairs))
-                mon[1].set(len(self._free))
+                self._note_free(mon)
             pools = self._cow_apply(pools, pairs)
             self.block_tables = jnp.asarray(t.copy())
         if exhausted:
@@ -379,7 +452,7 @@ class PagedKVCache:
         self.block_tables = jnp.asarray(new.copy())
         mon = _mon()
         if mon[0].on:
-            mon[1].set(len(self._free))
+            self._note_free(mon)
 
     def _cow_copy_fn(self):
         fn = getattr(self, "_cow_jit", None)
@@ -442,7 +515,7 @@ class PagedKVCache:
         if pairs:
             if mon[0].on:
                 mon[2].inc(len(pairs))
-                mon[1].set(len(self._free))
+                self._note_free(mon)
             pools = self._cow_apply(pools, pairs)
             self.block_tables = jnp.asarray(t.copy())
         if exhausted:
@@ -483,14 +556,23 @@ def _decode_scatter_idx(block_tables, seq_lens, bs):
     return block_tables[rows, blk_idx], off
 
 
+def _rows_for(pool, new):
+    """``new`` [..., kv_heads, dim] as the pool stores a position: unchanged
+    for a [blocks, block_size, kv_heads, dim] pool, heads merged into the
+    minor dim for a flat one; in the pool's dtype."""
+    if pool.ndim == 3:
+        new = new.reshape(new.shape[:-2] + (-1,))
+    return new.astype(pool.dtype)
+
+
 def paged_write_decode(cache_k, cache_v, block_tables, seq_lens, k_new, v_new):
     """Write ONE new token per sequence into its current tail block.
 
     k_new/v_new: [B, kv_heads, head_dim]; position = seq_lens[b].
     Returns (cache_k, cache_v) with the writes applied (functional)."""
     phys, off = _decode_scatter_idx(block_tables, seq_lens, cache_k.shape[1])
-    cache_k = cache_k.at[phys, off].set(k_new.astype(cache_k.dtype))
-    cache_v = cache_v.at[phys, off].set(v_new.astype(cache_v.dtype))
+    cache_k = cache_k.at[phys, off].set(_rows_for(cache_k, k_new))
+    cache_v = cache_v.at[phys, off].set(_rows_for(cache_v, v_new))
     return cache_k, cache_v
 
 
@@ -505,9 +587,9 @@ def paged_write_mixed(cache_k, cache_v, row_tables, positions, valid,
     padding rows (any real block id would clobber its owner)."""
     phys, off = _decode_scatter_idx(row_tables, positions, cache_k.shape[1])
     phys = jnp.where(valid, phys, cache_k.shape[0])
-    cache_k = cache_k.at[phys, off].set(k_new.astype(cache_k.dtype),
+    cache_k = cache_k.at[phys, off].set(_rows_for(cache_k, k_new),
                                         mode="drop")
-    cache_v = cache_v.at[phys, off].set(v_new.astype(cache_v.dtype),
+    cache_v = cache_v.at[phys, off].set(_rows_for(cache_v, v_new),
                                         mode="drop")
     return cache_k, cache_v
 
@@ -521,9 +603,9 @@ def paged_write_prefill(cache_k, cache_v, block_tables, seq_lens,
     phys, off = _prefill_scatter_idx(cache_k, block_tables, seq_lens,
                                      k_new.shape[1])
     cache_k = cache_k.at[phys, off].set(
-        _flat_rows(k_new).astype(cache_k.dtype), mode="drop")
+        _rows_for(cache_k, _flat_rows(k_new)), mode="drop")
     cache_v = cache_v.at[phys, off].set(
-        _flat_rows(v_new).astype(cache_v.dtype), mode="drop")
+        _rows_for(cache_v, _flat_rows(v_new)), mode="drop")
     return cache_k, cache_v
 
 
@@ -619,54 +701,81 @@ def paged_attention_decode_int8(q, kq, ks, vq, vs, block_tables, seq_lens,
     return out.reshape(B, n_q, D).astype(q.dtype)
 
 
-def _kernel_applies(q, pool):
-    """Whether ``paged_attention_decode`` runs the Pallas kernel for this
-    query and pool: on a TPU, where the kernel's blocks tile (the head dim
-    fills whole 128-lane rows; block_size and kv_heads sit on dims the
-    kernel does not tile), the dtypes are ones it loads, and its four
-    block buffers (K and V, double buffered) fit half of a core's 16 MiB
-    of scoped VMEM. Everything it reads is visible in the inputs: no flag
-    picks the path. The serving engine asks it too, to count the blocks a
-    step's attention reads."""
-    return (jax.devices()[0].platform == "tpu"
-            and q.shape[-1] % 128 == 0
+def _kernel_applies(q, pool, v_pool=None):
+    """Whether ``paged_attention_decode`` runs a Pallas kernel for this
+    query and these pools: on a TPU, where the kernel's blocks tile, the
+    dtypes are ones it loads, and its four block buffers (K and V, double
+    buffered) fit half of a core's 16 MiB of scoped VMEM. A
+    ``[blocks, block_size, kv_heads, head_dim]`` pool goes to the
+    one-row-a-head kernel (``paged_attention``): the head dim has to fill
+    whole 128-lane rows; block_size and kv_heads sit on dims it does not
+    tile. A flat ``[blocks, block_size, kv_heads * dim]`` pool goes to the
+    grouped-query kernel (``paged_attention_gqa``), which wants the MERGED
+    rows of K and of V to fill whole 128-lane rows (192-wide K heads beside
+    128-wide V heads do) and a block_size its dtype's sublane tile divides.
+    Everything read here is visible in the inputs: no flag picks the path.
+    The serving engine asks it too, to count the blocks a step's attention
+    reads."""
+    v_pool = pool if v_pool is None else v_pool
+    fits = (jax.devices()[0].platform == "tpu"
             and q.dtype in (jnp.bfloat16, jnp.float32)
             and pool.dtype in (jnp.bfloat16, jnp.float32)
-            and 4 * int(np.prod(pool.shape[1:])) * pool.dtype.itemsize
+            and 2 * (int(np.prod(pool.shape[1:]))
+                     + int(np.prod(v_pool.shape[1:]))) * pool.dtype.itemsize
             <= 8 * 2 ** 20)
+    if pool.ndim == 3:
+        return (fits and pool.shape[-1] % 128 == 0
+                and v_pool.shape[-1] % 128 == 0 and pool.shape[1] % 16 == 0)
+    return fits and q.shape[-1] % 128 == 0
+
+
+def kernel_applies(q, cache_k, cache_v):
+    """``_kernel_applies`` for a layer's pool pair (a [blocks, block_size,
+    kv_heads, head_dim] pool's V has its K's shape, so K alone decides)."""
+    if cache_k.ndim == 3:
+        return _kernel_applies(q, cache_k, cache_v)
+    return _kernel_applies(q, cache_k)
 
 
 @jax.named_scope("paged_attention")
 def paged_attention_decode(q, cache_k, cache_v, block_tables, seq_lens,
-                           scale=None):
+                           scale=None, window=None, sink=None):
     """One decode step of attention against the paged cache.
 
     q: [B, q_heads, head_dim] (GQA: q_heads a multiple of kv_heads); row b
     attends to positions 0..seq_lens[b] INCLUSIVE of its block-table row
-    (the current token was just written at position seq_lens). On a TPU
-    this is the Pallas kernel (``_kernel_applies``), which reads only the
-    blocks up to that position; elsewhere ``paged_attention_decode_plain``,
-    the kernel's reference. Same arithmetic either way: float32 scores,
-    probabilities and accumulation over K and V promoted on the fly.
+    (the current token was just written at position seq_lens), or, with
+    ``window``, to the last ``window`` of them. ``sink`` [q_heads] is a
+    logit per query head that joins the softmax's denominator and adds no
+    value. The pools may be flat and V's head dim its own (see
+    ``PagedKVCache``); the result is [B, q_heads, v_head_dim]. On a TPU
+    this is a Pallas kernel (``_kernel_applies``), which reads only the
+    blocks from the window's first to the position's; elsewhere
+    ``paged_attention_decode_plain``, the kernels' reference. Same
+    arithmetic either way: float32 scores, probabilities and accumulation.
 
     The whole of it runs under ``jax.named_scope("paged_attention")``, so
     a device trace can tell the serving programs' attention from the rest
     of a layer by name."""
-    if _kernel_applies(q, cache_k):
-        from ..ops.pallas.paged_attention import paged_attention
+    plain = cache_k.ndim == 4 and (window is not None or sink is not None)
+    if not plain and kernel_applies(q, cache_k, cache_v):
+        from ..ops.pallas import paged_attention as _k
 
-        return paged_attention(q, cache_k, cache_v, block_tables, seq_lens,
-                               scale)
+        if cache_k.ndim == 3:
+            return _k.paged_attention_gqa(q, cache_k, cache_v, block_tables,
+                                          seq_lens, scale, window, sink)
+        return _k.paged_attention(q, cache_k, cache_v, block_tables,
+                                  seq_lens, scale)
     return paged_attention_decode_plain(q, cache_k, cache_v, block_tables,
-                                        seq_lens, scale)
+                                        seq_lens, scale, window, sink)
 
 
 def paged_attention_decode_plain(q, cache_k, cache_v, block_tables, seq_lens,
-                                 scale=None):
+                                 scale=None, window=None, sink=None):
     """The gather path of ``paged_attention_decode``: every row reads its
     WHOLE table. Gathers each sequence's blocks into a [B, T_max, kv, D]
     view (T_max = max_blocks_per_seq * block_size) and masks
-    t <= seq_lens[b].
+    t <= seq_lens[b] (and, with ``window``, t > seq_lens[b] - window).
 
     QK and PV are written as multiply + reduce, not as einsums: every
     (lane, kv head) pair has its OWN gathered K and V, so as a matmul each
@@ -676,12 +785,13 @@ def paged_attention_decode_plain(q, cache_k, cache_v, block_tables, seq_lens,
     outright for a v5e). The fused multiply-reduce reads the gathered
     bf16 blocks once and keeps nothing wider than the logits."""
     B, n_q, D = q.shape
-    nb, bs, n_kv, _ = cache_k.shape
+    bs = cache_k.shape[1]
+    n_kv = cache_k.shape[2] if cache_k.ndim == 4 else cache_k.shape[2] // D
     groups = n_q // n_kv
     T = block_tables.shape[1] * bs
 
     k = cache_k[block_tables].reshape(B, T, n_kv, D)
-    v = cache_v[block_tables].reshape(B, T, n_kv, D)
+    v = cache_v[block_tables].reshape(B, T, n_kv, -1)
 
     if scale is None:
         scale = 1.0 / np.sqrt(D)
@@ -691,7 +801,16 @@ def paged_attention_decode_plain(q, cache_k, cache_v, block_tables, seq_lens,
     logits = (qg * k.astype(ct)[:, :, :, None, :]).sum(-1) * scale  # B,T,h,g
     t = jnp.arange(T)[None, :, None, None]
     mask = t <= seq_lens[:, None, None, None]
+    if window is not None:
+        mask = mask & (t > seq_lens[:, None, None, None] - window)
     logits = jnp.where(mask, logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=1)
-    out = (probs[..., None] * v.astype(ct)[:, :, :, None, :]).sum(1)  # B,h,g,D
-    return out.reshape(B, n_q, D).astype(q.dtype)
+    if sink is None:
+        probs = jax.nn.softmax(logits, axis=1)
+    else:
+        # the sink is one more logit in the denominator, with no value row
+        sk = sink.astype(ct).reshape(1, 1, n_kv, groups)
+        m = jnp.maximum(logits.max(axis=1, keepdims=True), sk)
+        e = jnp.exp(logits - m)
+        probs = e / (e.sum(axis=1, keepdims=True) + jnp.exp(sk - m))
+    out = (probs[..., None] * v.astype(ct)[:, :, :, None, :]).sum(1)  # B,h,g,Dv
+    return out.reshape(B, n_q, -1).astype(q.dtype)

@@ -118,7 +118,9 @@ class _Mon:
                  "preemptions", "cancelled",
                  "spec_drafted", "spec_accepted", "spec_rate", "pool_bytes",
                  "jit_compiles", "jit_hits", "jit_sigs",
-                 "phase_ns", "steps", "token_gap", "attn_blocks")
+                 "phase_ns", "steps", "token_gap", "attn_blocks",
+                 "kind_blocks", "block_steps", "expert_pairs",
+                 "window_released")
 
 
 _MON = None
@@ -190,6 +192,15 @@ def _mon():
             buckets=m.catalog.TOKEN_GAP_NS_BUCKETS)
         o.attn_blocks = m.counter("paddle_tpu_serving_attn_blocks_total",
                                   labelnames=("extent",))
+        o.kind_blocks = m.counter(
+            "paddle_tpu_serving_attn_kind_blocks_total",
+            labelnames=("kind",))
+        o.block_steps = m.counter("paddle_tpu_kv_block_steps_total",
+                                  labelnames=("kind",))
+        o.expert_pairs = m.counter("paddle_tpu_serving_expert_pairs_total",
+                                   labelnames=("where",))
+        o.window_released = m.counter(
+            "paddle_tpu_kv_window_blocks_released_total")
         _MON = o
     return _MON
 
@@ -255,19 +266,13 @@ def _drain(dq):
             return out
 
 
-def _pool_layout(pager, kv_int8):
-    """The engine-facing per-layer pool entries plus their total device
-    bytes. Quantized pools are 4-leaf — int8 K/V values + fp32
-    per-(token, head) scales, about half the bytes per token — bf16
-    pools are 2-leaf; every pool consumer (mixed step, CoW, spill)
-    treats the entry as an opaque leaf tuple."""
-    if kv_int8:
-        pools = list(zip(pager.k, pager.k_scale, pager.v, pager.v_scale))
-    else:
-        pools = list(zip(pager.k, pager.v))
-    nbytes = int(sum(leaf.size * leaf.dtype.itemsize
-                     for entry in pools for leaf in entry))
-    return pools, nbytes
+def _pool_bytes(pools):
+    """Device bytes of the per-layer pool entries. Quantized pools are
+    4-leaf — int8 K/V values + fp32 per-(token, head) scales, about half
+    the bytes per token — bf16 pools are 2-leaf; every pool consumer
+    (mixed step, CoW, spill) treats the entry as an opaque leaf tuple."""
+    return int(sum(leaf.size * leaf.dtype.itemsize
+                   for entry in pools for leaf in entry))
 
 
 class ContinuousBatchingEngine:
@@ -333,10 +338,14 @@ class ContinuousBatchingEngine:
         and registered decode chains survive between requests instead of
         churning through LRU eviction."""
         del prefill_buckets  # legacy knob of the bucket-prefill engine
-        self._inner = LlamaDecodeEngine(model, max_len=max_len,
-                                        kv_cache_layout="paged",
-                                        block_size=block_size,
-                                        kv_cache_dtype=kv_cache_dtype)
+        # the model's class names its decode engine (the serving block's
+        # description of its layers); a Llama-shaped one needs none
+        engine_cls = getattr(type(model), "decode_engine_class",
+                             LlamaDecodeEngine)
+        self._inner = engine_cls(model, max_len=max_len,
+                                 kv_cache_layout="paged",
+                                 block_size=block_size,
+                                 kv_cache_dtype=kv_cache_dtype)
         e = self._inner
         self.max_batch = int(max_batch)
         self.max_len = e.max_len
@@ -366,17 +375,38 @@ class ContinuousBatchingEngine:
         # wants headroom so registered chains outlive their producers
         num_blocks = self.max_batch * max_blocks + 1 if pool_blocks is None \
             else max(int(pool_blocks), max_blocks + 2)
-        self._pager = _pk.PagedKVCache(
-            num_layers=len(e.layers),
-            num_blocks=num_blocks,
-            block_size=self.block_size, kv_heads=e.num_kv,
-            head_dim=e.head_dim, batch=self.max_batch,
-            max_blocks_per_seq=max_blocks, dtype=e.emb.dtype,
-            quantized=e.kv_int8)
+        # one pager a cache kind, the whole-length kind's first: ``_pager``
+        # is the one the radix cache, copy-on-write and the spill layer
+        # work on (a model with a second kind has none of the three). A
+        # window kind's pool holds what its rows can keep at once: per row
+        # the blocks its last ``window`` positions span and the one being
+        # filled, plus the blocks one step's prefill budget adds before
+        # the next release
+        self._windowed = any(k.window is not None for k in e.kinds)
+        if self._windowed and (prefix_cache or kv_spill):
+            raise ValueError(
+                "a model with sliding-window layers is served with "
+                "prefix_cache=False and kv_spill=False: a radix hit would "
+                "have to bring the window layers' last blocks too, and a "
+                "preempted request's window blocks are not spilled; "
+                "neither is implemented, and silent wrong reuse is not an "
+                "option")
+        window_blocks = None
+        if self._windowed:
+            widest = max(k.window for k in e.kinds if k.window is not None)
+            window_blocks = (self.max_batch
+                             * ((widest - 1) // self.block_size + 3)
+                             + -(-self.max_step_tokens // self.block_size)
+                             + 1)
+        self._pagers, self._pools = e.make_pagers(
+            self.max_batch, num_blocks, window_blocks)
+        self._pager = self._pagers[0]
+        # per kind: its label, how many layers keep a pool of it
+        self._kind_layers = [(k.name, e.layer_kind.count(i))
+                             for i, k in enumerate(e.kinds)]
         # the capacity lever the pool-bytes gauge documents: equal byte
         # budgets admit ~2x the requests when the pools are quantized
-        self._pools, self.kv_pool_bytes = _pool_layout(self._pager,
-                                                       e.kv_int8)
+        self.kv_pool_bytes = _pool_bytes(self._pools)
         self.kv_cache_dtype = kv_cache_dtype
         self.kv_spill = bool(kv_spill)
         self.prefix_cache = PrefixCache(
@@ -930,7 +960,7 @@ class ContinuousBatchingEngine:
             blocks = [int(b) for b in self._pager._tables_np[slot][:nblk]]
             contents = _pk.read_blocks(self._pools, blocks)
         req.spill = (n_tok, contents, bool(self._decode_ready[slot]))
-        self._pager.free_sequence(slot)
+        self._free_row(slot)
         self._slots[slot] = None
         self._active[slot] = False
         self._decode_ready[slot] = False
@@ -1151,27 +1181,70 @@ class ContinuousBatchingEngine:
             self._phase = ph
             self._phases.append(ph)
 
+    def _tables(self):
+        """The block tables the programs take: one a cache kind."""
+        return tuple(pg.block_tables for pg in self._pagers)
+
     def _count_attn_blocks(self, mon, positions, lanes):
         """How far the paged attention's ragged read engages this step:
-        of the ``lanes`` x table-width blocks its lanes' rows span, the
+        of the ``lanes`` x table-width blocks its lanes' rows span, a
         kernel reads ``position // block_size + 1`` per valid lane
-        (``positions``: theirs, every iteration's for a burst); the plain
-        gather path reads them all."""
-        total = lanes * self._pager.max_blocks_per_seq
+        (``positions``: theirs, every iteration's for a burst), from its
+        window's first block on in a window layer; the plain gather path
+        reads them all. Counted a kind (one layer of it once), and all
+        kinds together under ``extent``."""
         e = self._inner
-        if _pk._kernel_applies(
-                jax.ShapeDtypeStruct((e.num_heads, e.head_dim), e.emb.dtype),
-                self._pools[0][0]):             # (an int8 pool: never)
-            read = int((positions // self.block_size + 1).sum())
-        else:
-            read = total
+        width = self._pager.max_blocks_per_seq
+        total = read = 0
+        for ki, kind in enumerate(e.kinds):
+            q = jax.ShapeDtypeStruct((e.num_heads, kind.head_dim),
+                                     e.emb.dtype)
+            entry = self._pools[e.layer_kind.index(ki)]
+            k, v = (entry[0], entry[2]) if e.kv_int8 else entry
+            if _pk.kernel_applies(q, k, v):      # (an int8 pool: never)
+                first = 0 if kind.window is None else np.maximum(
+                    positions - kind.window + 1, 0) // self.block_size
+                n = int((positions // self.block_size + 1 - first).sum())
+            else:
+                n = lanes * width
+            mon.kind_blocks.labels(kind.name).inc(n)
+            read += n
+            total += lanes * width
         mon.attn_blocks.labels("read").inc(read)
         mon.attn_blocks.labels("skipped").inc(total - read)
 
+    def _release_window_blocks(self, mon):
+        """What the scheduler adds for sliding-window layers: before a
+        step's grants, hand back every block that lies wholly behind the
+        window of its row's next query (``lens``: queries only move on).
+        Also adds this step's block-steps: blocks in use a kind, times the
+        layers that keep a pool of it."""
+        if self._windowed:
+            t0 = mon.mod.now_ns() if mon.tstate.on else 0
+            freed = 0
+            for pg in self._pagers:
+                if pg.window is not None:
+                    freed += pg.release_behind(
+                        np.where(self._active, self.lens, 0))
+            if mon.state.on and freed:
+                mon.window_released.inc(freed)
+            if mon.tstate.on and freed:
+                mon.trace.record_span(
+                    "serving.release_window", t0, mon.mod.now_ns(),
+                    parent=self._phase.span, attrs={"blocks": freed})
+        if mon.state.on:
+            for (name, layers), pg in zip(self._kind_layers, self._pagers):
+                mon.block_steps.labels(name).inc(pg.blocks_in_use * layers)
+
     def _ensure(self, need):
-        """ensure_capacity with radix-cache relief: pool exhaustion evicts
-        exactly the LRU cache-only blocks the grant is short of, then
-        retries once (blocks mapped into live requests are never taken)."""
+        """ensure_capacity, in every cache kind, with radix-cache relief:
+        pool exhaustion evicts exactly the LRU cache-only blocks the grant
+        is short of, then retries once (blocks mapped into live requests
+        are never taken). A grant that one kind made before another ran
+        dry stays with its row: it is used when the row's turn comes, or
+        freed with the row."""
+        for pg in self._pagers[1:]:
+            pg.ensure_capacity(need)
         try:
             self._pager.ensure_capacity(need)
             return
@@ -1192,6 +1265,10 @@ class ContinuousBatchingEngine:
             mon.pc_blocks.set(len(self.prefix_cache))
         self._pager.ensure_capacity(need)
 
+    def _free_row(self, slot):
+        for pg in self._pagers:
+            pg.free_sequence(slot)
+
     def _step_impl(self, eos_token_id, max_new_tokens):
         # a hang (watchdog-recovered) almost always sits in the compiled
         # dispatch below, so the epoch captured here + the fence after
@@ -1210,6 +1287,7 @@ class ContinuousBatchingEngine:
                 self._update_gauges(mon)
             return []
         t0 = mon.mod.now_ns()
+        self._release_window_blocks(mon)
         T = self.max_step_tokens
         decode_slots = np.flatnonzero(self._decode_ready)
         prefill_slots = np.flatnonzero(self._active
@@ -1466,7 +1544,7 @@ class ContinuousBatchingEngine:
             self._count_attn_blocks(mon, positions[:n_lanes], T)
         self._next_phase("serving.dispatch", "mixed")
         out_dev, self._pools = step(
-            jnp.asarray(pack_np), self._pools, self._pager.block_tables,
+            jnp.asarray(pack_np), self._pools, self._tables(),
             slots_dev, valid_dev, chain_dev, self._inner.weights)
         self._next_phase("serving.wait")
         if _sanitizers._state.numerics:
@@ -1478,6 +1556,8 @@ class ContinuousBatchingEngine:
         out = np.asarray(out_dev)
         self._next_phase("serving.route")
         toks, acc = out[0], out[1]
+        if len(out) > 2 and mon.state.on:
+            self._count_expert_pairs(mon, *out[2][:3], 1)
         if epoch != self._epoch:
             # a hang recovery superseded this step while it sat in
             # compile/dispatch. The pools rebind above MUST stand — the
@@ -1715,7 +1795,7 @@ class ContinuousBatchingEngine:
                 self.max_batch * K)
         self._next_phase("serving.dispatch", "burst")
         toks_dev, self._pools = burst(
-            jnp.asarray(pack), self._pools, self._pager.block_tables,
+            jnp.asarray(pack), self._pools, self._tables(),
             self._inner.weights)
         self._next_phase("serving.wait")
         if _sanitizers._state.numerics:
@@ -1724,8 +1804,10 @@ class ContinuousBatchingEngine:
                 "serving.decode_burst",
                 (("tokens", toks_dev), ("kv_pools", self._pools)),
                 step=self._san_steps)
-        toks = np.asarray(toks_dev)            # (B, K)
+        toks = np.asarray(toks_dev)            # (B, K) [+ 3 rows of pairs]
         self._next_phase("serving.route")
+        if len(toks) > self.max_batch and mon.state.on:
+            self._count_expert_pairs(mon, *toks[-3:].sum(axis=1), K)
         if epoch != self._epoch:
             # superseded mid-dispatch: keep the pools rebind (buffer
             # validity + the warm radix blocks), apply no host state —
@@ -1759,6 +1841,18 @@ class ContinuousBatchingEngine:
             self._update_gauges(mon)
             mon.mod.sample()
         return finished
+
+    def _count_expert_pairs(self, mon, held, routed, hit, forwards):
+        """The (token, expert) pairs a step's program counted on the experts
+        held here and in all, the held experts that got a pair, and the
+        expert calls the pairs had to share: held experts x expert layers x
+        forward passes."""
+        e = self._inner
+        mon.expert_pairs.labels("held").inc(int(held))
+        mon.expert_pairs.labels("routed").inc(int(routed))
+        mon.expert_pairs.labels("experts_hit").inc(int(hit))
+        mon.expert_pairs.labels("expert_calls").inc(
+            forwards * e.held_experts * sum("router" in p for p in e.layers))
 
     def _note_token(self, slot, tok, eos_token_id, max_new_tokens,
                     finished, mon, t_now):
@@ -1800,7 +1894,7 @@ class ContinuousBatchingEngine:
         # loop that evicts it, so register (and pin) them before the row
         # is freed — a repeated prompt then drafts the WHOLE previous run
         self._register_decode_blocks(slot, None, mon)
-        self._pager.free_sequence(slot)
+        self._free_row(slot)
         self._slots[slot] = None
         self._active[slot] = False
         self._decode_ready[slot] = False
@@ -1987,7 +2081,7 @@ class ContinuousBatchingEngine:
                 if entry is not None:
                     mon.trace.drop(entry[1])
                     mon.trace.end_span(entry[0])
-                self._pager.free_sequence(b)
+                self._free_row(b)
                 self._slots[b] = None
                 if self._drafter is not None:
                     self._drafter.drop(req.rid)
@@ -2135,16 +2229,8 @@ class StaticBatchEngine:
         self.block_size = int(block_size)
         self._buckets = tuple(b for b in sorted(prefill_buckets)
                               if b <= e.max_len) or (e.max_len,)
-        max_blocks = -(-e.max_len // self.block_size)
-        self._pager = _pk.PagedKVCache(
-            num_layers=len(e.layers),
-            num_blocks=self.max_batch * max_blocks + 1,
-            block_size=self.block_size, kv_heads=e.num_kv,
-            head_dim=e.head_dim, batch=self.max_batch,
-            max_blocks_per_seq=max_blocks, dtype=e.emb.dtype,
-            quantized=e.kv_int8)
-        self._pools, self.kv_pool_bytes = _pool_layout(self._pager,
-                                                       e.kv_int8)
+        (self._pager,), self._pools = e.make_pagers(self.max_batch)
+        self.kv_pool_bytes = _pool_bytes(self._pools)
         self.kv_cache_dtype = kv_cache_dtype
         self.lens = np.zeros(self.max_batch, np.int64)
         self._slots = [None] * self.max_batch
@@ -2169,13 +2255,10 @@ class StaticBatchEngine:
                                  signature=key)
 
             def run(ids, pools, row_tables, length, w):
-                x = w["emb"][ids]
                 lens1 = jnp.asarray([length], jnp.int32)
-                new_pools = []
-                for p, pool in zip(w["layers"], pools):
-                    x, pool = e._block_paged_prefill(p, x, pool, row_tables,
-                                                     lens1)
-                    new_pools.append(pool)
+                x, new_pools, _ = e._layers_paged(
+                    w, w["emb"][ids], pools, (row_tables,), lens1,
+                    prompt=True)
                 x = _rms(x, w["norm_w"], e.eps)
                 logits = x @ w["head_w"]
                 tok = jnp.argmax(logits[0, length - 1], -1)
@@ -2194,11 +2277,8 @@ class StaticBatchEngine:
                                  signature="step")
 
             def run(tokens, pools, tables, lens, w):
-                x = w["emb"][tokens]
-                new_pools = []
-                for p, pool in zip(w["layers"], pools):
-                    x, pool = e._block_paged_decode(p, x, pool, tables, lens)
-                    new_pools.append(pool)
+                x, new_pools, _ = e._layers_paged(
+                    w, w["emb"][tokens], pools, (tables,), lens)
                 x = _rms(x, w["norm_w"], e.eps)
                 logits = (x @ w["head_w"])[:, -1]
                 return jnp.argmax(logits, -1).astype(jnp.int32), new_pools

@@ -106,6 +106,26 @@ METRICS = {
         "them where the plain gather path runs (it reads the whole "
         "table). extent=skipped: the rest. Counted in the schedule "
         "phase, per layer not multiplied out."),
+    "paddle_tpu_serving_attn_kind_blocks_total": (
+        "counter", ("kind",),
+        "KV blocks the paged attention of completed engine steps has to "
+        "read, by cache kind (full | window), one layer of the kind once: "
+        "per valid lane and burst iteration the blocks from the window's "
+        "first (block 0 for a full layer) to the position's, where a "
+        "Pallas kernel runs; lanes x table width where the plain gather "
+        "path runs. paddle_tpu_serving_attn_blocks_total{extent=read} is "
+        "the sum over the kinds."),
+    "paddle_tpu_serving_expert_pairs_total": (
+        "counter", ("where",),
+        "(token, expert) pairs of completed engine steps of a model with "
+        "expert layers, counted inside the step's program over its valid "
+        "lanes and summed over its expert layers: where=held on the "
+        "experts this engine holds, where=routed in all (lanes x experts "
+        "per token x expert layers), where=experts_hit the held experts that "
+        "got at least one pair (a layer and forward pass each), "
+        "where=expert_calls the held experts x expert layers x forward "
+        "passes (1 a mixed step, decode_burst a burst) those pairs were "
+        "shared among."),
     "paddle_tpu_serving_token_gap_ns": (
         "histogram", (),
         "Time between one request's consecutive output tokens, observed "
@@ -257,6 +277,16 @@ METRICS = {
     "paddle_tpu_kv_free_blocks": (
         "gauge", (),
         "Free blocks in the most recently updated paged-KV pool."),
+    "paddle_tpu_kv_block_steps_total": (
+        "counter", ("kind",),
+        "Pool blocks in use, added up once per engine step, by cache kind "
+        "(full | window) and multiplied by the layers that keep a pool of "
+        "that kind: the memory the cache manager holds over time."),
+    "paddle_tpu_kv_window_blocks_released_total": (
+        "counter", (),
+        "Blocks of a sliding-window cache kind handed back to their pool "
+        "because they lay wholly behind their row's window (one pager "
+        "block once, whatever the number of layers that share it)."),
     "paddle_tpu_kv_cow_copies_total": (
         "counter", (),
         "Blocks copied by copy-on-write before a shared-tail write."),
@@ -500,6 +530,11 @@ SPANS = {
         "One request preempted under pool pressure: its KV spilled to "
         "host RAM, its blocks freed, the request requeued (restored "
         "bit-exact on re-admission). attrs: slot, rid, tokens_in_kv."),
+    "serving.release_window": (
+        "The scheduler handing back KV blocks that lie wholly behind "
+        "their rows' sliding window, before a step's grants (child of "
+        "serving.pack_tokens; recorded only when a block was freed). "
+        "attrs: blocks."),
     "serving.spec_verify": (
         "One mixed step's speculative verification: draft tokens packed "
         "as extra ragged lanes, accepted by the device-side longest-"

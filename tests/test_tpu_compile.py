@@ -22,7 +22,8 @@ from jax.sharding import SingleDeviceSharding
 import paddle_tpu  # noqa: F401
 from paddle_tpu.models import paged_kv
 from paddle_tpu.ops.pallas.flash_attention import flash_attention_fwd
-from paddle_tpu.ops.pallas.paged_attention import paged_attention
+from paddle_tpu.ops.pallas.paged_attention import (paged_attention,
+                                                   paged_attention_gqa)
 
 
 @pytest.fixture(scope="module")
@@ -126,6 +127,36 @@ def test_paged_attention_kernel_compiles_for_v5e(one_chip, mosaic, n_q, kv):
     it: the plain path below asks for 3.8 GB of temporaries here."""
     compiled = jax.jit(paged_attention).lower(
         *_paged_shapes(one_chip, 144, 16, 64, n_q, kv, 128, 257)).compile()
+    assert compiled.as_text().count(
+        "custom_call_target=\"tpu_custom_call\"") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2 ** 20
+
+
+@pytest.mark.parametrize("kv,blocks,window,sink", [
+    pytest.param(4, 8193, None, False, id="full"),
+    pytest.param(8, 262, 128, True, id="window"),
+])
+def test_paged_attention_gqa_kernel_compiles_for_v5e(one_chip, mosaic, kv,
+                                                     blocks, window, sink):
+    """The grouped-query kernel at the MiMo-V2-Flash cell's shapes: 320 lanes
+    (max_batch 64 + chunk 256), 64 query heads of 192 against flat pools of
+    K rows kv x 192 and V rows kv x 128, block 64, a table of 128 blocks
+    (max_len 8192; 160 KB of scalars), full (4 KV heads, the whole-length
+    pool) and window (8 KV heads, window 128, a sink logit a head)."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = [sds((320, 64, 192), jnp.bfloat16),
+            sds((blocks, 64, kv * 192), jnp.bfloat16),
+            sds((blocks, 64, kv * 128), jnp.bfloat16),
+            sds((320, 128), jnp.int32), sds((320,), jnp.int32)]
+    if sink:
+        args.append(sds((64,), jnp.bfloat16))
+
+    def attend(q, k, v, tables, pos, sk=None):
+        return paged_attention_gqa(q, k, v, tables, pos, None, window, sk)
+
+    compiled = jax.jit(attend).lower(*args).compile()
     assert compiled.as_text().count(
         "custom_call_target=\"tpu_custom_call\"") == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2 ** 20
