@@ -381,7 +381,7 @@ class LlamaDecodeEngine:
         return x + y.reshape(x.shape), pairs
 
     def _block_paged(self, li, p, x, pool, tables, positions, valid=None,
-                     prompt=False, counted=None):
+                     prompt=False, counted=None, rows=None):
         """THE serving block of layer ``li``, for every paged program.
 
         One token per LANE (``x`` (T, 1, hidden)) at a per-lane position
@@ -394,7 +394,9 @@ class LlamaDecodeEngine:
         then marks the rows whose expert pairs count). Writes land
         before the attention reads the pool (kernel or gather: paged_kv
         picks), so lanes of one chunk see each other through it, causal by
-        absolute position.
+        absolute position. ``rows`` (the mixed step's slot ids) tells the
+        attention which lanes sit on one table row: a chunk's lanes then
+        form query tiles that walk the row once.
 
         With ``prompt`` the lockstep PREFILL: ``x`` (B, S, hidden), causal
         self-attention within the prompt (the history IS the prompt),
@@ -453,13 +455,13 @@ class LlamaDecodeEngine:
                                              k[:, 0], v[:, 0])
             attn = _pk.paged_attention_decode(
                 q[:, 0], *pool, tables, positions, window=kind.window,
-                sink=sink)[:, None]
+                sink=sink, rows=rows)[:, None]
         x, pairs = self._post_attn(p, x, attn,
                                    valid if counted is None else counted)
         return x, pool, pairs
 
     def _layers_paged(self, w, x, pools, tables, positions, valid=None,
-                      prompt=False, counted=None):
+                      prompt=False, counted=None, rows=None):
         """Every layer's ``_block_paged`` in turn. ``tables`` holds one
         block table a cache kind (already the lanes' rows, for a mixed
         step). Returns ``(x, pools, pairs)``: ``pairs`` [3] int32 summed
@@ -468,7 +470,7 @@ class LlamaDecodeEngine:
         for li, (p, pool) in enumerate(zip(w["layers"], pools)):
             x, pool, pairs = self._block_paged(
                 li, p, x, pool, tables[self.layer_kind[li]], positions,
-                valid, prompt, counted)
+                valid, prompt, counted, rows)
             new_pools.append(pool)
             if pairs is not None:
                 total = pairs if total is None else total + pairs
@@ -517,7 +519,7 @@ class LlamaDecodeEngine:
             x = w["emb"][token_ids][:, None]        # (T, 1, hidden)
             row_tables = tuple(t[slot_ids] for t in tables)  # (T, max_blocks)
             x, new_pools, pairs = self._layers_paged(
-                w, x, pools, row_tables, positions, valid)
+                w, x, pools, row_tables, positions, valid, rows=slot_ids)
             x = _rms(x, w["norm_w"], self.eps)
             logits = (x @ w["head_w"])[:, -1]
             # argmax INSIDE the program: the scheduler transfers one

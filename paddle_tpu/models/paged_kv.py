@@ -739,7 +739,7 @@ def kernel_applies(q, cache_k, cache_v):
 
 @jax.named_scope("paged_attention")
 def paged_attention_decode(q, cache_k, cache_v, block_tables, seq_lens,
-                           scale=None, window=None, sink=None):
+                           scale=None, window=None, sink=None, rows=None):
     """One decode step of attention against the paged cache.
 
     q: [B, q_heads, head_dim] (GQA: q_heads a multiple of kv_heads); row b
@@ -754,6 +754,12 @@ def paged_attention_decode(q, cache_k, cache_v, block_tables, seq_lens,
     ``paged_attention_decode_plain``, the kernels' reference. Same
     arithmetic either way: float32 scores, probabilities and accumulation.
 
+    ``rows`` [B] int32 says which rows of ``block_tables`` are the SAME row
+    (equal ids; a mixed step's slot ids): the kernels then serve lanes on
+    one row at consecutive positions (a prefill chunk) as query tiles that
+    walk the row once (``ops.pallas.paged_attention.plan_tiles``). It is
+    data about the step, and changes no result; the gather paths ignore it.
+
     The whole of it runs under ``jax.named_scope("paged_attention")``, so
     a device trace can tell the serving programs' attention from the rest
     of a layer by name."""
@@ -763,9 +769,9 @@ def paged_attention_decode(q, cache_k, cache_v, block_tables, seq_lens,
 
         if cache_k.ndim == 3:
             return _k.paged_attention_gqa(q, cache_k, cache_v, block_tables,
-                                          seq_lens, scale, window, sink)
+                                          seq_lens, scale, window, sink, rows)
         return _k.paged_attention(q, cache_k, cache_v, block_tables,
-                                  seq_lens, scale)
+                                  seq_lens, scale, rows)
     return paged_attention_decode_plain(q, cache_k, cache_v, block_tables,
                                         seq_lens, scale, window, sink)
 

@@ -119,7 +119,7 @@ class _Mon:
                  "spec_drafted", "spec_accepted", "spec_rate", "pool_bytes",
                  "jit_compiles", "jit_hits", "jit_sigs",
                  "phase_ns", "steps", "token_gap", "attn_blocks",
-                 "kind_blocks", "block_steps", "expert_pairs",
+                 "kind_blocks", "attn_lanes", "block_steps", "expert_pairs",
                  "window_released")
 
 
@@ -195,6 +195,8 @@ def _mon():
         o.kind_blocks = m.counter(
             "paddle_tpu_serving_attn_kind_blocks_total",
             labelnames=("kind",))
+        o.attn_lanes = m.counter("paddle_tpu_serving_attn_lanes_total",
+                                 labelnames=("path",))
         o.block_steps = m.counter("paddle_tpu_kv_block_steps_total",
                                   labelnames=("kind",))
         o.expert_pairs = m.counter("paddle_tpu_serving_expert_pairs_total",
@@ -1185,28 +1187,48 @@ class ContinuousBatchingEngine:
         """The block tables the programs take: one a cache kind."""
         return tuple(pg.block_tables for pg in self._pagers)
 
-    def _count_attn_blocks(self, mon, positions, lanes):
+    def _count_attn_blocks(self, mon, positions, lanes, rows=None,
+                           valid=None):
         """How far the paged attention's ragged read engages this step:
         of the ``lanes`` x table-width blocks its lanes' rows span, a
-        kernel reads ``position // block_size + 1`` per valid lane
-        (``positions``: theirs, every iteration's for a burst), from its
-        window's first block on in a window layer; the plain gather path
-        reads them all. Counted a kind (one layer of it once), and all
-        kinds together under ``extent``."""
+        kernel brings in what ``blocks_walked`` counts (``positions``: a
+        burst's valid lanes, every iteration's; a mixed step's whole pack
+        with its slot ids ``rows``, the first ``valid`` lanes real): a
+        lane of its own ``position // block_size + 1`` blocks, from its
+        window's first on in a window layer; a query tile (the lanes of a
+        prefill chunk) each block from its first lane's first to its last
+        lane's last ONCE; the plain gather path reads them all. Counted a
+        kind (one layer of it once), and all kinds together under
+        ``extent``; the valid lanes by the path that served them (the
+        first kind's) under ``path``."""
+        from ..ops.pallas import paged_attention as _pa
+
         e = self._inner
         width = self._pager.max_blocks_per_seq
+        n_valid = positions.size if valid is None else valid
         total = read = 0
+        plans = {}                      # one plan a tile size
         for ki, kind in enumerate(e.kinds):
             q = jax.ShapeDtypeStruct((e.num_heads, kind.head_dim),
                                      e.emb.dtype)
             entry = self._pools[e.layer_kind.index(ki)]
             k, v = (entry[0], entry[2]) if e.kv_int8 else entry
+            tiled = 0
             if _pk.kernel_applies(q, k, v):      # (an int8 pool: never)
-                first = 0 if kind.window is None else np.maximum(
-                    positions - kind.window + 1, 0) // self.block_size
-                n = int((positions // self.block_size + 1 - first).sum())
+                plan = None
+                if rows is not None and _pa.tiles_apply(k, kind.num_kv):
+                    tq = _pa.tile_lanes(e.num_heads // kind.num_kv,
+                                        k.ndim == 3)
+                    if tq not in plans:
+                        plans[tq] = _pa.plan_tiles(rows, positions, tq, np)
+                    plan = plans[tq]
+                n, tiled = _pa.blocks_walked(
+                    positions, self.block_size, valid, plan, kind.window)
             else:
                 n = lanes * width
+            if ki == 0:
+                mon.attn_lanes.labels("tiled").inc(tiled)
+                mon.attn_lanes.labels("lane").inc(n_valid - tiled)
             mon.kind_blocks.labels(kind.name).inc(n)
             read += n
             total += lanes * width
@@ -1530,18 +1552,18 @@ class ContinuousBatchingEngine:
                 lane += take
             valid_np[:n_lanes] = True
             cached = (jnp.asarray(slot_np), jnp.asarray(valid_np),
-                      jnp.asarray(chain_np))
+                      jnp.asarray(chain_np), slot_np)
             if len(self._lane_cache) > 256:
                 self._lane_cache.clear()
             self._lane_cache[key] = cached
-        slots_dev, valid_dev, chain_dev = cached
+        slots_dev, valid_dev, chain_dev, slot_np = cached
         step = self._step_jit()
         if self._phase.span is not None:
             self._phase.attrs = {
                 "n_decode": nd, "n_draft": n_dec_lanes - nd,
                 "n_prefill": n_lanes - n_dec_lanes, "budget": T}
         if mon.state.on:
-            self._count_attn_blocks(mon, positions[:n_lanes], T)
+            self._count_attn_blocks(mon, positions, T, slot_np, n_lanes)
         self._next_phase("serving.dispatch", "mixed")
         out_dev, self._pools = step(
             jnp.asarray(pack_np), self._pools, self._tables(),

@@ -101,20 +101,34 @@ METRICS = {
         "every lane of a step's program spans its table row (lanes x "
         "table width blocks a layer: the token budget for a mixed step, "
         "max_batch x decode_burst for a burst). extent=read: the blocks "
-        "the attention has to read, position // block_size + 1 per valid "
-        "lane and burst iteration, where the Pallas kernel runs; all of "
-        "them where the plain gather path runs (it reads the whole "
-        "table). extent=skipped: the rest. Counted in the schedule "
-        "phase, per layer not multiplied out."),
+        "the attention's DMAs bring in where a Pallas kernel runs: "
+        "position // block_size + 1 per valid lane and burst iteration "
+        "that walks its own row, and for a query tile (the lanes of a "
+        "prefill chunk, which share a row; ops.pallas.paged_attention."
+        "plan_tiles) each block from its first lane's first to its last "
+        "lane's last ONCE; all of them where the plain gather path runs "
+        "(it reads the whole table). extent=skipped: the rest. Counted "
+        "in the schedule phase, per layer not multiplied out."),
     "paddle_tpu_serving_attn_kind_blocks_total": (
         "counter", ("kind",),
         "KV blocks the paged attention of completed engine steps has to "
         "read, by cache kind (full | window), one layer of the kind once: "
-        "per valid lane and burst iteration the blocks from the window's "
-        "first (block 0 for a full layer) to the position's, where a "
-        "Pallas kernel runs; lanes x table width where the plain gather "
-        "path runs. paddle_tpu_serving_attn_blocks_total{extent=read} is "
-        "the sum over the kinds."),
+        "per valid lane and burst iteration that walks its own row the "
+        "blocks from the window's first (block 0 for a full layer) to "
+        "the position's, per query tile the blocks from its first lane's "
+        "first to its last lane's last once, where a Pallas kernel runs; "
+        "lanes x table width where the plain gather path runs. "
+        "paddle_tpu_serving_attn_blocks_total{extent=read} is the sum "
+        "over the kinds."),
+    "paddle_tpu_serving_attn_lanes_total": (
+        "counter", ("path",),
+        "Valid lanes of completed engine steps (a burst: every "
+        "iteration's) by the path the paged attention served them on: "
+        "path=tiled as one of a query tile's rows (lanes of a prefill "
+        "chunk: one table row, consecutive positions, 4 lanes or more), "
+        "path=lane by a walk of their own (decode lanes, bursts, short "
+        "runs, every lane where no kernel runs). Read from the first "
+        "cache kind's plan; tiled + lane = the valid lanes."),
     "paddle_tpu_serving_expert_pairs_total": (
         "counter", ("where",),
         "(token, expert) pairs of completed engine steps of a model with "

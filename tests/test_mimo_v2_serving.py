@@ -321,6 +321,160 @@ def test_gqa_kernel_against_the_plain_path(monkeypatch, dtype, window, sink):
                                   np.asarray(got, np.float32))
 
 
+def test_engine_tiles_a_chunk_in_both_cache_kinds_and_counts_its_blocks_once(
+        monkeypatch):
+    """(ISSUE 33) one 50-token prompt through the engine with the kernels on
+    (interpret mode): the same greedy tokens as through the gather path, and
+    ``attn_kind_blocks_total`` a kind counts a chunk's blocks once: a full
+    layer from block 0, a window layer (20 positions, block 8) from the
+    FIRST lane's window's first block."""
+    from paddle_tpu import monitor
+
+    prompt = [(_prompts()[0][0], 4)]
+    want, _ = _serve(_engine(), prompt)
+    monkeypatch.setattr(paged_kv, "_kernel_applies", lambda *a: True)
+    name = "paddle_tpu_serving_attn_kind_blocks_total"
+    lanes = "paddle_tpu_serving_attn_lanes_total"
+    monitor.enable()
+    seen = []
+
+    def read():
+        m = monitor.snapshot()["metrics"]
+        seen.append({**m[name]["values"], **m[lanes]["values"]})
+
+    read()
+    try:
+        got, _ = _serve(_engine(), prompt, between_steps=lambda eng: read())
+    finally:
+        monitor.disable()
+    np.testing.assert_array_equal(got[0], want[0])
+    moved = [{k: after[k] - before.get(k, 0.0) for k in after}
+             for before, after in zip(seen, seen[1:])]
+    # chunks at 0..15, 16..31 and 32..47 are tiles; 48, 49 walk alone
+    assert moved[:4] == [
+        {"kind=full": 2.0, "kind=window": 2.0, "path=tiled": 16.0,
+         "path=lane": 0.0},
+        {"kind=full": 4.0, "kind=window": 4.0, "path=tiled": 16.0,
+         "path=lane": 0.0},
+        {"kind=full": 6.0, "kind=window": 5.0, "path=tiled": 16.0,
+         "path=lane": 0.0},
+        {"kind=full": 14.0, "kind=window": 8.0, "path=tiled": 0.0,
+         "path=lane": 2.0}]
+
+
+def _gqa_pack(lanes, total, width):
+    """``lanes``: (table row, position) a lane, padded to ``total`` lanes
+    with slot 0 at position 0, as the engine pads; one table of 4 rows."""
+    rng = np.random.default_rng(2)
+    row_tables = rng.permutation(np.arange(1, 4 * width + 1)).reshape(
+        4, width).astype(np.int32)
+    pad = total - len(lanes)
+    rows = np.array([r for r, _ in lanes] + [0] * pad, np.int32)
+    pos = np.array([p for _, p in lanes] + [0] * pad, np.int32)
+    return row_tables[rows], pos, rows
+
+
+def _run(row, start, n):
+    return [(row, start + i) for i in range(n)]
+
+
+GQA_TILES = {
+    # (a) one run that starts mid-block (block 8) and crosses two boundaries
+    "midblock-run": dict(lanes=_run(2, 5, 14), window=None, sink=False),
+    # (b) decode lanes, two runs of different rows, padding lanes behind
+    "two-runs": dict(lanes=[(0, 30), (1, 9)] + _run(2, 3, 12) + _run(3, 20, 7),
+                     window=9, sink=True),
+    # (c) runs shorter than a tile (32 lanes), and one too short to be one
+    "short-runs": dict(lanes=[(0, 44)] + _run(2, 14, 5) + _run(3, 30, 3),
+                       window=None, sink=True),
+    # a run of two tiles whose second is not full
+    "two-tiles": dict(lanes=[(1, 9)] + _run(2, 1, 40), window=16, sink=False),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", sorted(GQA_TILES))
+def test_gqa_tiles_against_the_plain_path(case, dtype):
+    """(ISSUE 33) lanes of one table row at consecutive positions walk the
+    row once as query tiles; the lanes no tile serves keep the per-lane
+    kernel's bits. K rows wider than V rows, 4 query heads a KV head."""
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
+    spec = GQA_TILES[case]
+    T, n_q, kv, dk, dv, bs, width = 48, 8, 2, 24, 16, 8, 6
+    tables, pos, rows = _gqa_pack(spec["lanes"], T, width)
+    n = len(spec["lanes"])
+    rng = np.random.default_rng(3)
+    q = jnp.asarray(rng.normal(size=(T, n_q, dk)), dtype)
+    k = jnp.asarray(rng.normal(size=(4 * width + 1, bs, kv * dk)), dtype)
+    v = jnp.asarray(rng.normal(size=(4 * width + 1, bs, kv * dv)), dtype)
+    sk = jnp.asarray(rng.normal(size=(n_q,)), jnp.float32) \
+        if spec["sink"] else None
+    args = (q, k, v, jnp.asarray(tables), jnp.asarray(pos), None,
+            spec["window"], sk)
+    plan = pa.plan_tiles(rows, pos, pa.tile_lanes(n_q // kv, True), np)
+    assert plan["tiles"] == (1 if case in ("midblock-run", "short-runs")
+                             else 2)
+    got = pa.paged_attention_gqa(*args, rows=jnp.asarray(rows))
+    want = paged_kv.paged_attention_decode_plain(*args)
+    tol = 2e-6 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32)[:n],
+                               np.asarray(want, np.float32)[:n], atol=tol)
+    alone = pa.paged_attention_gqa(*args)
+    left = ~plan["tiled"][:n]
+    np.testing.assert_array_equal(np.asarray(got, np.float32)[:n][left],
+                                  np.asarray(alone, np.float32)[:n][left])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_gqa_tiles_with_a_run_longer_than_the_window(monkeypatch, dtype):
+    """(d) a window of 128 with a sink and a run of 150 lanes, at the
+    cell's head widths (K heads of 192 beside V heads of 128: each head's
+    product runs over the 128-aligned stretch of the merged row that holds
+    it), through the dispatcher as the mixed step calls it."""
+    T, n_q, kv, dk, dv, bs, width = 160, 8, 2, 192, 128, 16, 16
+    lanes = [(0, 200), (1, 17)] + _run(2, 61, 150)
+    tables, pos, rows = _gqa_pack(lanes, T, width)
+    rng = np.random.default_rng(4)
+    q = jnp.asarray(rng.normal(size=(T, n_q, dk)), dtype)
+    k = jnp.asarray(rng.normal(size=(4 * width + 1, bs, kv * dk)), dtype)
+    v = jnp.asarray(rng.normal(size=(4 * width + 1, bs, kv * dv)), dtype)
+    sk = jnp.asarray(rng.normal(size=(n_q,)), jnp.float32)
+    monkeypatch.setattr(paged_kv, "_kernel_applies", lambda *a: True)
+    got = paged_kv.paged_attention_decode(
+        q, k, v, jnp.asarray(tables), jnp.asarray(pos), window=128, sink=sk,
+        rows=jnp.asarray(rows))
+    want = paged_kv.paged_attention_decode_plain(
+        q, k, v, jnp.asarray(tables), jnp.asarray(pos), None, 128, sk)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32)[:len(lanes)],
+                               np.asarray(want, np.float32)[:len(lanes)],
+                               atol=tol)
+
+
+def test_gqa_with_no_shared_row_is_the_per_lane_kernel_bit_for_bit():
+    """(e) told that no two neighbouring lanes share a row, the tiled entry
+    runs the per-lane kernel over every lane: the same bits."""
+    from paddle_tpu.ops.pallas.paged_attention import paged_attention_gqa
+
+    rng = np.random.default_rng(1)
+    T, n_q, kv, dk, dv, bs, width, nb = 6, 8, 2, 24, 16, 8, 6, 40
+    q = jnp.asarray(rng.normal(size=(T, n_q, dk)), jnp.bfloat16)
+    k = jnp.asarray(rng.normal(size=(nb, bs, kv * dk)), jnp.bfloat16)
+    v = jnp.asarray(rng.normal(size=(nb, bs, kv * dv)), jnp.bfloat16)
+    tables = jnp.asarray(rng.permutation(np.arange(1, nb))[:T * width]
+                         .reshape(T, width), jnp.int32)
+    pos = jnp.asarray([0, 7, 8, 23, 40, 47], jnp.int32)
+    sk = jnp.asarray(rng.normal(size=(n_q,)), jnp.float32)
+    want = paged_attention_gqa(q, k, v, tables, pos, None, 9, sk)
+    got = paged_attention_gqa(q, k, v, tables, pos, None, 9, sk,
+                              rows=jnp.arange(T, dtype=jnp.int32))
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+
+
 def test_kernel_applies_reads_flat_pools_from_their_shapes(monkeypatch):
     class Dev:
         platform = "tpu"

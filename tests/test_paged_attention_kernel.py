@@ -196,9 +196,164 @@ def test_engine_gives_the_same_greedy_tokens_through_the_kernel(monkeypatch):
     predicate (as the ``mosaic`` fixture of test_tpu_compile.py turns
     ``_interpret``): head dim 128, GQA 2:1, chunked prefill of prompts
     longer than a chunk, bursts of 3, 5 requests through 3 slots."""
+    from paddle_tpu import monitor
+
     want, kinds = _serve(_engine())
     assert kinds >= {"mixed", "burst"}
     monkeypatch.setattr(paged_kv, "_kernel_applies", lambda q, pool: True)
-    got, kinds = _serve(_engine())
-    assert kinds >= {"mixed", "burst"}
+    lanes = "paddle_tpu_serving_attn_lanes_total"
+
+    def served():
+        monitor.enable()
+        before = dict(monitor.snapshot()["metrics"][lanes]["values"])
+        try:
+            got, kinds = _serve(_engine())
+        finally:
+            monitor.disable()
+        after = monitor.snapshot()["metrics"][lanes]["values"]
+        assert kinds >= {"mixed", "burst"}
+        return got, {k: after[k] - before.get(k, 0.0) for k in after}
+
+    # most of the 64 prompt tokens ride query tiles (chunks of 4 lanes or
+    # more); the 3-token prompt and the chunks the budget cuts shorter stay
+    # per lane, as every decode lane does
+    got, moved = served()
     assert got == want
+    assert 48 <= moved["path=tiled"] < 64 - 3
+    # (ISSUE 33) the same requests with every lane on a walk of its own: no
+    # run is long enough to be a tile
+    monkeypatch.setattr(pa_mod, "MIN_RUN", 10 ** 6)
+    per_lane, moved_alone = served()
+    assert per_lane == got
+    assert moved_alone["path=tiled"] == 0
+    assert moved_alone["path=lane"] == moved["path=lane"] + moved["path=tiled"]
+
+
+# -- query tiles (ISSUE 33): lanes that share a table row walk it once ---------
+from paddle_tpu.ops.pallas import paged_attention as pa_mod  # noqa: E402
+
+TILE_LANES = 48
+
+
+def _run(row, start, n):
+    return [(row, start + i) for i in range(n)]
+
+
+# (table row, position) a lane; rows 0 and 1 are decode lanes' own
+TILE_CASES = {
+    # (a) one run that starts mid-block and crosses two block boundaries
+    "midblock-run": _run(2, 10, 30),
+    # (b) decode lanes, two runs of different rows, padding lanes behind
+    # (slot 0 at position 0, as the engine pads)
+    "two-runs": [(0, 37), (1, 5)] + _run(2, 3, 12) + _run(3, 20, 10)
+                + [(0, 0)] * 4,
+    # (c) a run shorter than a tile, and one too short to be a tile at all
+    "short-runs": [(0, 50)] + _run(2, 14, 5) + _run(3, 30, 3),
+    # more lanes than one tile of the GQA case holds (32): two tiles
+    "two-tiles": [(1, 9)] + _run(2, 17, 40),
+    # a run up to the table's last position
+    "to-the-end": _run(3, FULL - 20, 21) + [(0, 3)],
+}
+
+
+def _tile_inputs(case, n_q, n_kv, dtype, seed=5):
+    rng = np.random.default_rng(seed)
+    k, v = _pools(rng, n_kv, dtype, nb=20)
+    row_tables = _rows(rng, 4, nb=20)
+    lanes = TILE_CASES[case]
+    pad = TILE_LANES - len(lanes)
+    rows = np.array([r for r, _ in lanes] + [0] * pad, np.int32)
+    pos = np.array([p for _, p in lanes] + [0] * pad, np.int32)
+    q = jnp.asarray(rng.standard_normal((TILE_LANES, n_q, D)), dtype)
+    return (q, k, v, jnp.asarray(row_tables[rows]), jnp.asarray(pos),
+            jnp.asarray(rows), len(lanes))
+
+
+_TILED = jax.jit(lambda q, k, v, t, p, r: paged_attention(q, k, v, t, p,
+                                                          rows=r))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("heads", sorted(HEADS))
+@pytest.mark.parametrize("case", sorted(TILE_CASES))
+def test_tiles_match_the_plain_path(case, heads, dtype):
+    n_q, n_kv = HEADS[heads]
+    q, k, v, tables, pos, rows, n = _tile_inputs(case, n_q, n_kv, dtype)
+    plan = pa_mod.plan_tiles(np.asarray(rows), np.asarray(pos),
+                             pa_mod.tile_lanes(n_q // n_kv, False), np)
+    assert plan["tiled"].sum() >= 5 and plan["tiles"] >= 1
+    assert plan["tiles"] == (2 if (case, heads) == ("two-tiles", "gqa4to1")
+                             or case == "two-runs" else 1)
+    got = _TILED(q, k, v, tables, pos, rows)
+    want = paged_kv.paged_attention_decode_plain(q, k, v, tables, pos)
+    np.testing.assert_allclose(np.asarray(got, np.float32)[:n],
+                               np.asarray(want, np.float32)[:n],
+                               atol=TOL[dtype], rtol=TOL[dtype])
+    # the lanes no tile serves: the per-lane walk's result, bit for bit
+    alone = _KERNEL(q, k, v, tables, pos)
+    left = ~plan["tiled"][:n]
+    np.testing.assert_array_equal(np.asarray(got, np.float32)[:n][left],
+                                  np.asarray(alone, np.float32)[:n][left])
+
+
+@pytest.mark.parametrize("heads", sorted(HEADS))
+def test_a_step_with_no_shared_row_is_the_per_lane_kernel_bit_for_bit(heads):
+    """(e) told that no two lanes share a row (a burst, lockstep decode,
+    decode lanes alone, two lanes of one row that are not neighbours in
+    position), the tiled entry gives the per-lane kernel's bits."""
+    n_q, n_kv = HEADS[heads]
+    rng = np.random.default_rng(9)
+    k, v = _pools(rng, n_kv, jnp.bfloat16)
+    tables, pos = _lengths(rng)
+    tables = np.concatenate([tables, tables[:2]])       # rows 0, 1 again
+    pos = np.concatenate([pos, [pos[0] + 2, pos[1] + 5]]).astype(np.int32)
+    rows = np.concatenate([np.arange(7), [0, 1]]).astype(np.int32)
+    q = jnp.asarray(rng.standard_normal((len(pos), n_q, D)), jnp.bfloat16)
+    plan = pa_mod.plan_tiles(rows, pos, pa_mod.tile_lanes(n_q // n_kv, False),
+                             np)
+    assert not plan["tiled"].any() and plan["tiles"] == 0
+    got = paged_attention(q, k, v, jnp.asarray(tables), jnp.asarray(pos),
+                          rows=jnp.asarray(rows))
+    want = paged_attention(q, k, v, jnp.asarray(tables), jnp.asarray(pos))
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+
+
+def test_a_tile_reads_no_block_past_its_last_lane():
+    """Blocks past the run's last position hold NaN, and so does every
+    block of the rows no lane of the step sits on."""
+    q, k, v, tables, pos, rows, n = _tile_inputs("midblock-run", 4, 4,
+                                                 jnp.float32)
+    keep = set(np.asarray(tables)[0, :39 // BS + 1].tolist()) | {0}
+    for blk in range(k.shape[0]):
+        if blk not in keep:
+            k = k.at[blk].set(jnp.nan)
+            v = v.at[blk].set(jnp.nan)
+    out = _TILED(q, k, v, tables, pos, rows)
+    assert np.isfinite(np.asarray(out)[:n]).all()
+
+
+def test_the_host_counts_what_the_device_plans():
+    """``plan_tiles`` is one piece of code for the device (jnp) and the
+    host's count (numpy): the same plan from both, and a tile's blocks
+    counted once."""
+    for case, lanes in TILE_CASES.items():
+        rows = np.array([r for r, _ in lanes], np.int32)
+        pos = np.array([p for _, p in lanes], np.int32)
+        for tq in (32, 128):
+            host = pa_mod.plan_tiles(rows, pos, tq, np)
+            dev = pa_mod.plan_tiles(jnp.asarray(rows), jnp.asarray(pos), tq)
+            for key, value in host.items():
+                np.testing.assert_array_equal(value, np.asarray(dev[key]),
+                                              err_msg=f"{case} {key}")
+    rows, pos = (np.array(a, np.int32)
+                 for a in zip(*TILE_CASES["midblock-run"]))
+    plan = pa_mod.plan_tiles(rows, pos, 128, np)
+    # positions 10..39 of one row: blocks 0, 1, 2 once, not 30 walks
+    assert pa_mod.blocks_walked(pos, BS, plan=plan) == (3, 30)
+    assert pa_mod.blocks_walked(pos, BS) == (int((pos // BS + 1).sum()), 0)
+    # with a window of 8 the tile starts at its FIRST lane's first block
+    assert pa_mod.blocks_walked(pos, BS, plan=plan, window=8) == (3, 30)
+    assert pa_mod.blocks_walked(pos + 16, BS, plan=pa_mod.plan_tiles(
+        rows, pos + 16, 128, np), window=8) == (3, 30)   # blocks 1..3

@@ -308,8 +308,9 @@ class TestAttnBlocks:
         assert catalog.spec(self.NAME)[:2] == ("counter", ("extent",))
 
     @pytest.mark.parametrize("kind,lanes,read", [
-        # the 5-token prompt's chunk at positions 0..4: one block each
-        ("mixed", 10, 5),
+        # the 5-token prompt's chunk at positions 0..4 is ONE query tile: it
+        # walks block 0 once (ISSUE 33; each lane on its own: 5)
+        ("mixed", 10, 1),
         # one decode lane at positions 5, 6, 7 (block 0) and 8 (block 1)
         ("burst", 2 * 4, 3 * 1 + 2),
     ])
@@ -330,6 +331,114 @@ class TestAttnBlocks:
         eng.step()
         eng.step()
         assert _counter(self.NAME) == {}
+        assert _counter(self.LANES) == {}
+
+    # -- the lanes by the path that served them (ISSUE 33) -------------------
+    LANES = "paddle_tpu_serving_attn_lanes_total"
+
+    def test_the_lanes_counter_is_cataloged(self):
+        assert catalog.spec(self.LANES)[:2] == ("counter", ("path",))
+        assert catalog.spec(
+            "paddle_tpu_serving_attn_kind_blocks_total")[:2] == (
+                "counter", ("kind",))
+
+    @pytest.mark.parametrize("kind,ragged,moved", [
+        # the chunk's 5 lanes are a tile's; a burst's lanes walk alone
+        ("mixed", True, {"path=tiled": 5.0}),
+        ("burst", True, {"path=lane": 4.0}),
+        # where no kernel runs there is no tile: the gather path reads all
+        ("mixed", False, {"path=lane": 5.0}),
+    ])
+    def test_tiled_and_lane_add_up_to_the_valid_lanes(self, monkeypatch, kind,
+                                                      ragged, moved):
+        self.NAME, name = self.LANES, self.NAME
+        try:
+            assert self._step(kind, monkeypatch, ragged) == moved
+        finally:
+            self.NAME = name
+
+    def test_a_longer_prompt_is_tiled_a_chunk_and_counted_a_block_once(
+            self, monkeypatch):
+        """A 13-token prompt behind a decoding request: its chunk of 8 at
+        positions 0..7 is one tile over block 0; the next step carries the
+        other 5 (positions 8..12, block 1: the tile walks blocks 0 and 1)
+        beside the first request's decode lane."""
+        from paddle_tpu.models import paged_kv
+
+        eng = _one_step_of("burst")           # slot 0 decodes at position 5
+        eng.submit(np.arange(1, 14, dtype=np.int32), max_new_tokens=4)
+        monkeypatch.setattr(paged_kv, "_kernel_applies", lambda q, pool: True)
+        monitor.enable()
+        seen = []
+        for _ in range(2):
+            blocks, lanes = _counter(self.NAME), _counter(self.LANES)
+            eng.step()
+            seen.append((_moved(blocks, _counter(self.NAME)),
+                         _moved(lanes, _counter(self.LANES))))
+        monitor.disable()
+        assert seen[0] == ({"extent=read": 1.0 + 1.0,
+                            "extent=skipped": 10 * 6 - 2.0},
+                           {"path=tiled": 8.0, "path=lane": 1.0})
+        assert seen[1] == ({"extent=read": 1.0 + 2.0,
+                            "extent=skipped": 10 * 6 - 3.0},
+                           {"path=tiled": 5.0, "path=lane": 1.0})
+
+    def test_the_benchmarks_metric_file_reads_the_lanes_counter(
+            self, monkeypatch):
+        """``attn_tiled_lane_share.sat`` is data: the accepted reader
+        ``counter_share`` over this PR's counter. A mixed step with a tile
+        of 5 lanes, then a burst of 4 lane-iterations: 5 of 9."""
+        import importlib.util
+        import json
+        import os
+
+        from paddle_tpu.models import paged_kv
+
+        bench = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks")
+        with open(os.path.join(bench, "metrics",
+                               "attn_tiled_lane_share.sat.json")) as f:
+            spec = json.load(f)
+        assert spec["reader"] == "counter_share"
+        mod = importlib.util.spec_from_file_location(
+            "bench_counter_share",
+            os.path.join(bench, "readers", "counter_share.py"))
+        reader = importlib.util.module_from_spec(mod)
+        mod.loader.exec_module(reader)
+        monitor.reset()
+        # (the parent of this PR has no such counter: nothing, and no raise)
+        assert reader.read({}, spec["params"], {}) is None
+        eng = _one_step_of("mixed")
+        monkeypatch.setattr(paged_kv, "_kernel_applies",
+                            lambda q, pool: True)
+        monitor.enable()
+        eng.step()
+        eng.step()
+        monitor.disable()
+        assert eng._step_kind == "burst"
+        value, note = reader.read({}, spec["params"], {})
+        assert value == pytest.approx(100.0 * 5 / 9)
+        assert note[self.LANES] == {"path=tiled": 5.0, "path=lane": 4.0}
+
+    def test_kind_blocks_under_a_window_kind(self):
+        """``attn_kind_blocks_total`` of a window layer: a lane of its own
+        from its window's first block, a tile from its FIRST lane's window's
+        first block to its last lane's last; counted by the code that plans
+        the tiles on the device."""
+        from paddle_tpu.ops.pallas import paged_attention as pa
+
+        rows = np.array([0] + [1] * 12, np.int32)
+        pos = np.array([40] + list(range(20, 32)), np.int32)
+        plan = pa.plan_tiles(rows, pos, 32, np)
+        assert list(plan["n"][:2]) == [12, 0] and plan["tiles"] == 1
+        # block 8, window 16: the lane at 40 reads blocks 3..5; the tile's
+        # first lane (20) sees from 5 = block 0, its last (31) ends block 3
+        assert pa.blocks_walked(pos, 8, plan=plan, window=16) == (3 + 4, 12)
+        assert pa.blocks_walked(pos, 8, plan=plan) == (6 + 4, 12)
+        # per lane, as before the tiles: 3 blocks each (a window of 16
+        # straddles three blocks of 8 unless it ends one)
+        assert pa.blocks_walked(pos, 8, window=16) == (
+            int(sum(p // 8 - max(p - 15, 0) // 8 + 1 for p in pos)), 0)
 
 
 # --------------------------------------------------------------------------- #

@@ -162,6 +162,56 @@ def test_paged_attention_gqa_kernel_compiles_for_v5e(one_chip, mosaic, kv,
     assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2 ** 20
 
 
+@pytest.mark.parametrize("n_q,kv", [
+    pytest.param(32, 32, id="mha"),            # deepseek-7b-serve-offline
+    pytest.param(32, 8, id="gqa32:8"),         # Mistral-7B's heads
+])
+def test_paged_attention_tiles_compile_for_v5e(one_chip, mosaic, n_q, kv):
+    """The mixed step's call (ISSUE 33): told which lanes share a table row,
+    the per-lane kernel over an ORDER of lanes (a grid as long as the order)
+    and the query-tile kernel beside it (strided per-head loads from a
+    bfloat16 block's 32-bit words, float32 products, a tile of 128 queries
+    a head), and nothing gathered."""
+    shapes = _paged_shapes(one_chip, 144, 16, 64, n_q, kv, 128, 257)
+    rows = jax.ShapeDtypeStruct((144,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda q, k, v, t, p, r: paged_attention(q, k, v, t, p, rows=r)
+    ).lower(*shapes, rows).compile()
+    assert compiled.as_text().count(
+        "custom_call_target=\"tpu_custom_call\"") == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2 ** 20
+
+
+@pytest.mark.parametrize("kv,blocks,window,sink", [
+    pytest.param(4, 8193, None, False, id="full"),
+    pytest.param(8, 262, 128, True, id="window"),
+])
+def test_paged_attention_gqa_tiles_compile_for_v5e(one_chip, mosaic, kv,
+                                                   blocks, window, sink):
+    """The grouped-query kernels of a mixed step at the MiMo-V2-Flash cell's
+    shapes: tiles of 32 lanes x 64 query heads, each KV head's product over
+    the 128-aligned stretch of the merged K row that holds its 192 lanes."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = [sds((320, 64, 192), jnp.bfloat16),
+            sds((blocks, 64, kv * 192), jnp.bfloat16),
+            sds((blocks, 64, kv * 128), jnp.bfloat16),
+            sds((320, 128), jnp.int32), sds((320,), jnp.int32),
+            sds((320,), jnp.int32)]
+    if sink:
+        args.append(sds((64,), jnp.bfloat16))
+
+    def attend(q, k, v, tables, pos, rows, sk=None):
+        return paged_attention_gqa(q, k, v, tables, pos, None, window, sk,
+                                   rows=rows)
+
+    compiled = jax.jit(attend).lower(*args).compile()
+    assert compiled.as_text().count(
+        "custom_call_target=\"tpu_custom_call\"") == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2 ** 20
+
+
 def test_paged_decode_attention_fits_the_mixed_step(one_chip):
     """The PLAIN path (the kernel's reference, and what runs where the kernel
     does not apply) at chip_smoke's shape: 136 lanes (max_batch 8 + chunk
