@@ -357,8 +357,7 @@ def measure_compiled(fn, args):
     read the executable's own memory analysis. ``peak_bytes`` is
     arguments + temporaries + outputs − aliased (donated outputs reuse
     argument buffers) — the measured twin the estimator is held to
-    within tolerance by the tier-1 test and the bench's
-    ``detail.hbm_estimate`` row. Caveat: backends may embed large
+    within tolerance by the tier-1 test. Caveat: backends may embed large
     closure constants in the executable image instead of the buffer
     tables, so const-heavy programs can measure BELOW their true
     device residency — the estimator counts them."""

@@ -5,8 +5,8 @@ graftir's passes NAME what the traced programs waste ("Operator Fusion
 in XLA", arXiv 2301.13062: the fusion classes XLA's heuristics leave on
 the table); this module REWRITES the jaxpr so the waste is gone before
 XLA ever sees it. Every rewrite is semantics-preserving by construction
-— the bench and tier-1 tests pin optimized-vs-unoptimized outputs
-BIT-exact — and the rewritten program re-analyzes clean under
+— the tier-1 tests pin optimized-vs-unoptimized outputs BIT-exact —
+and the rewritten program re-analyzes clean under
 GI001–GI004 (the ``check_opt_parity`` CI row):
 
 - ``convert-roundtrip`` — a value cast to a WIDER type and straight
@@ -40,7 +40,7 @@ cond / while / remat bodies) without ever changing a sub-jaxpr's
 interface, so pjit sharding/donation params stay valid. The engine is
 trace-level only — no compile, no dispatch; :func:`optimize_jitted`
 rebuilds a runnable (re-jitted, donation-preserving) callable from the
-rewritten jaxpr for the bench and the serving/mesh drills.
+rewritten jaxpr for the serving/mesh drills.
 
 Importing this module costs stdlib only; jax loads on first use.
 """
@@ -494,7 +494,7 @@ def count_regions(jaxpr):
     """Fusible-region accounting: like :func:`count_eqns` but an
     outlined ``closed_call`` closure counts as ONE region (its body is
     the single fused computation XLA receives) — the dispatch-count
-    number the fusion bench gates on."""
+    number tests/test_ir_opt.py gates on."""
     n = len(jaxpr.eqns)
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "closed_call":

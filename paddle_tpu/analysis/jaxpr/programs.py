@@ -148,8 +148,8 @@ _BUILDERS = {
 def build_program(name, with_callable=False):
     """One flagship :class:`~.ir.ProgramIR` by name. With
     ``with_callable=True`` also returns ``(program, jitted, args)`` so
-    callers can compile-and-measure (the bench's hbm stamp / the
-    estimate-vs-measured tolerance test)."""
+    callers can compile-and-measure (the estimate-vs-measured
+    tolerance test)."""
     builder = _BUILDERS.get(name)
     if builder is None:
         raise AnalysisError(

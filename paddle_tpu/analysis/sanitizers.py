@@ -46,8 +46,8 @@ Five sanitizers, enabled via
 
 Discipline matches monitor/trace: **disabled by default**, every guard is
 one slot load on a preallocated ``_state`` object, nothing is wrapped or
-hooked until enabled — bench.py stamps ``detail.sanitizer_overhead`` and
-the tier-1 dispatch budget holds with sanitizers off.
+hooked until enabled — the tier-1 dispatch budget holds with sanitizers
+off (tests/test_sanitizers.py).
 
 Every trip also (best-effort) bumps
 ``paddle_tpu_monitor_sanitizer_trips_total``, records a

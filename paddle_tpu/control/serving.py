@@ -131,7 +131,7 @@ def build_serving_controller(fleet, *, rules=None, interval_s=0.25,
     ``replan`` (optional) is the HBM guard's budget-remat hook
     (``analysis.jaxpr.planner.make_replan_hook``). The controller is
     returned STOPPED — call ``.start()`` to run the loop, or drive
-    ``.tick()`` yourself (the bench does).
+    ``.tick()`` yourself (the tests do).
     """
     eng = fleet.replicas[0].engine
     hedge0 = fleet.hedge_after_s if fleet.hedge_after_s is not None \

@@ -32,7 +32,7 @@ class CostModel:
         CostEstimate (step time, FLOPs, bytes, collective volume) for the
         given model/parallel/hardware description."""
         if model is None:
-            # the flagship bench shape as the default subject (bench.py)
+            # a 542M-parameter decoder at seq 2048 as the default subject
             model = ModelDesc(n_params=542_148_608, hidden=2048, layers=8,
                               seq=2048)
         parallel = parallel or ParallelConfig()

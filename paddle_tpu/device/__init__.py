@@ -21,8 +21,8 @@ def enable_compile_cache():
     sets nothing in code. Otherwise the cache lives at ``<checkout>/.jax_cache``,
     a fixed path derived from the package's location: the path is part of the
     cache key, so a directory that moves between runs never hits. Call it
-    before the first compile; the entry-point scripts (chip_smoke.py, bench.py,
-    bench_suite.py) are the only callers."""
+    before the first compile; the entry-point script chip_smoke.py is the only
+    caller (benchmarks/run.py places its own cache)."""
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

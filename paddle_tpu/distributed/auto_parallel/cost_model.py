@@ -5,8 +5,8 @@ op-level comp/comm cost tables and estimator that power Engine.cost() and the
 planner. TPU-first redesign: transformer training cost has a closed form on
 this hardware — MXU FLOPs, HBM traffic, and collective volume over ICI/DCN —
 so the estimator is a roofline calculation over (model, parallel config,
-hardware profile) instead of per-op cost tables. The FLOPs accounting matches
-bench.py (PaLM appendix-B: 6N + 12*L*h*s per token); the collective terms use
+hardware profile) instead of per-op cost tables. The FLOPs accounting is
+PaLM appendix B's (6N + 12*L*h*s per token); the collective terms use
 ring costs (2(n-1)/n for allreduce, (n-1)/n for reduce-scatter/allgather).
 
 Powers Engine.cost() and the AutoTuner's pre-trial pruning/ordering

@@ -15,9 +15,10 @@ ONE ``shard_map``-wrapped, donated, jitted step over the
   ``with_sharding_constraint`` annotations keep riding GSPMD inside the
   body, so dp x mp composes without a second code path.
 
-The live Layer/Optimizer objects are threaded functionally exactly like
-``bench_common.build_step`` — the tape runs inside the shard_map trace, so
-eager model code IS the distributed program.
+The live Layer/Optimizer objects are threaded functionally: parameter,
+accumulator and master values enter the step as arguments, are bound to the
+live objects for the trace and restored after it — the tape runs inside the
+shard_map trace, so eager model code IS the distributed program.
 """
 from __future__ import annotations
 
@@ -530,9 +531,7 @@ class MeshParallel:
     def step_jaxpr(self, *batch):
         """The traced (closed) jaxpr of this step program, cached after
         the first trace — the input of the jaxpr-walking consumers: the
-        byte census, graftir passes, and graftscope's modeled
-        comm-overlap timeline
-        (``monitor.timeline.modeled_overlap_report``)."""
+        byte census and the graftir passes."""
         if self._closed_jaxpr is None:
             self._closed_jaxpr = jax.make_jaxpr(self._jitted)(
                 *self._step_args(batch))
@@ -550,7 +549,7 @@ class MeshParallel:
         compiler text :meth:`collective_counts` already parsed, via
         ``byte_census_hlo`` (entries carry ``priced_by: "hlo"``).
         Cached after the first trace; surfaced as ``<collective>_bytes``
-        attrs on ``comm.mesh_step`` spans and in the mesh_bench rows."""
+        attrs on ``comm.mesh_step`` spans."""
         if self._collective_bytes is None:
             closed = self.step_jaxpr(*batch)
             census = _collectives.byte_census_jaxpr(closed.jaxpr)

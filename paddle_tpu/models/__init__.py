@@ -29,5 +29,4 @@ from .gpt import (  # noqa: F401
 )
 from .llama_decode import LlamaDecodeEngine  # noqa: F401
 from .radix_cache import PrefixCache  # noqa: F401
-from .serving import (AdmissionTimeout, ContinuousBatchingEngine,  # noqa: F401
-                      StaticBatchEngine)
+from .serving import AdmissionTimeout, ContinuousBatchingEngine  # noqa: F401

@@ -31,11 +31,6 @@ XLA program):
    ``submit()`` blocks on a bounded admission queue and raises a typed
    :class:`AdmissionTimeout` instead of waiting unboundedly.
 
-:class:`StaticBatchEngine` keeps the OLD architecture — batch-synchronous
-waves, one bucket-padded compiled prefill per admission, lockstep decode —
-as the measured baseline the bench compares against (``bench.py`` serving
-block), at equal batch capacity.
-
 Instrumentation: the paddle_tpu.monitor serving metrics (queue depth,
 occupancy, pack fill, prefix-cache hits/misses/blocks-shared,
 chunked-prefill depth, TTFT — docs/observability.md) plus, with span
@@ -59,11 +54,11 @@ import jax.numpy as jnp
 from . import paged_kv as _pk
 from ..analysis import faultinject as _fi
 from ..analysis import sanitizers as _sanitizers
-from .llama_decode import LlamaDecodeEngine, _rms
+from .llama_decode import LlamaDecodeEngine
 from .radix_cache import PrefixCache
 
-__all__ = ["ContinuousBatchingEngine", "StaticBatchEngine",
-           "AdmissionTimeout", "RequestShed", "RequestAborted"]
+__all__ = ["ContinuousBatchingEngine", "AdmissionTimeout", "RequestShed",
+           "RequestAborted"]
 
 _ENGINE_SEQ = itertools.count()
 
@@ -290,7 +285,7 @@ class ContinuousBatchingEngine:
     def __init__(self, model, max_batch=8, max_len=None, block_size=64,
                  chunk_size=32, max_step_tokens=None, policy="fcfs",
                  decode_priority=0.0, decode_burst=4, max_queue=None,
-                 prefix_cache=True, prefill_buckets=None, kv_spill=False,
+                 prefix_cache=True, kv_spill=False,
                  spill_capacity_blocks=None, strict_priority=False,
                  kv_cache_dtype=None, spec_lookahead=0, spec_ngram=3,
                  pool_blocks=None):
@@ -306,9 +301,7 @@ class ContinuousBatchingEngine:
         per-dispatch overhead amortizes over burst tokens; admissions wait
         at most one burst, and 1 disables it). ``max_queue`` bounds the
         submit() admission queue (backpressure; None = unbounded).
-        ``prefill_buckets`` is accepted for backward compatibility and
-        ignored — chunked prefill replaced bucket-padded admission
-        prefills. ``kv_spill`` enables the host-RAM resilience layer:
+        ``kv_spill`` enables the host-RAM resilience layer:
         radix-cache evictions spill their KV bits to host (restorable on
         a later prefix match) and, under pool pressure, the lowest-
         priority active request is PREEMPTED — KV spilled, blocks freed,
@@ -339,7 +332,6 @@ class ContinuousBatchingEngine:
         serving sizes the pool PAST the live batch so shared prefixes
         and registered decode chains survive between requests instead of
         churning through LRU eviction."""
-        del prefill_buckets  # legacy knob of the bucket-prefill engine
         # the model's class names its decode engine (the serving block's
         # description of its layers); a Llama-shaped one needs none
         engine_cls = getattr(type(model), "decode_engine_class",
@@ -424,8 +416,8 @@ class ContinuousBatchingEngine:
                 prefix_cache=self.prefix_cache)
         else:
             self._drafter = None
-        # host counters behind the spec metrics (the bench reads these
-        # directly so accept rates report with the monitor off too)
+        # host counters behind the spec metrics (read directly, so accept
+        # rates report with the monitor off too)
         self.spec_drafted = 0
         self.spec_accepted = 0
         # per-slot radix-registration cursors (see _register_decode_blocks);
@@ -2226,212 +2218,3 @@ class ContinuousBatchingEngine:
                     self._spawn_driver()
                 return
 
-
-class StaticBatchEngine:
-    """The batch-synchronous BASELINE the continuous engine is measured
-    against (bench.py serving block), at equal batch capacity: admit a
-    full wave of requests, prefill each prompt as its own bucket-padded
-    compiled call, decode every wave slot in lockstep until the LAST
-    request of the wave finishes, then evict all and admit the next wave.
-    This is the pre-chunked-prefill architecture — a request arriving
-    mid-wave waits for the whole wave to drain, early finishers burn
-    decode lanes until the wave's longest request completes, and every
-    prompt pays bucket padding."""
-
-    def __init__(self, model, max_batch=8, max_len=None, block_size=64,
-                 prefill_buckets=(32, 64, 128, 256, 512, 1024, 2048),
-                 kv_cache_dtype=None):
-        self._inner = LlamaDecodeEngine(model, max_len=max_len,
-                                        kv_cache_layout="paged",
-                                        block_size=block_size,
-                                        kv_cache_dtype=kv_cache_dtype)
-        e = self._inner
-        self.max_batch = int(max_batch)
-        self.max_len = e.max_len
-        self.block_size = int(block_size)
-        self._buckets = tuple(b for b in sorted(prefill_buckets)
-                              if b <= e.max_len) or (e.max_len,)
-        (self._pager,), self._pools = e.make_pagers(self.max_batch)
-        self.kv_pool_bytes = _pool_bytes(self._pools)
-        self.kv_cache_dtype = kv_cache_dtype
-        self.lens = np.zeros(self.max_batch, np.int64)
-        self._slots = [None] * self.max_batch
-        self._done = np.zeros(self.max_batch, bool)
-        self._pending = collections.deque()
-        self._next_rid = 0
-        self._jit_cache = {}
-        self._san_tag = f"e{next(_ENGINE_SEQ)}"
-        self._stats = collections.OrderedDict()
-
-    # -- compiled paths (the legacy shapes: per-bucket prefill + lockstep
-    #    ragged decode) -------------------------------------------------------
-    def _prefill_slot_jit(self, bucket):
-        e = self._inner
-        key = ("prefill", bucket)
-        cache = self._jit_cache
-        if key not in cache:
-            san = _sanitizers
-            if san._state.recompile:
-                # bounded by the bucket list BY DESIGN
-                san.note_compile(f"serving.prefill[{self._san_tag}]",
-                                 signature=key)
-
-            def run(ids, pools, row_tables, length, w):
-                lens1 = jnp.asarray([length], jnp.int32)
-                x, new_pools, _ = e._layers_paged(
-                    w, w["emb"][ids], pools, (row_tables,), lens1,
-                    prompt=True)
-                x = _rms(x, w["norm_w"], e.eps)
-                logits = x @ w["head_w"]
-                tok = jnp.argmax(logits[0, length - 1], -1)
-                return tok.astype(jnp.int32), new_pools
-
-            cache[key] = jax.jit(run, donate_argnums=(1,))
-        return cache[key]
-
-    def _step_all_jit(self):
-        e = self._inner
-        cache = self._jit_cache
-        if "step" not in cache:
-            san = _sanitizers
-            if san._state.recompile:
-                san.note_compile(f"serving.decode_step[{self._san_tag}]",
-                                 signature="step")
-
-            def run(tokens, pools, tables, lens, w):
-                x, new_pools, _ = e._layers_paged(
-                    w, w["emb"][tokens], pools, (tables,), lens)
-                x = _rms(x, w["norm_w"], e.eps)
-                logits = (x @ w["head_w"])[:, -1]
-                return jnp.argmax(logits, -1).astype(jnp.int32), new_pools
-
-            cache["step"] = jax.jit(run, donate_argnums=(1,))
-        return cache["step"]
-
-    # -- API (mirrors the continuous engine's driving surface) ---------------
-    def submit(self, prompt_ids, max_new_tokens=None):
-        prompt = np.asarray(getattr(prompt_ids, "value", prompt_ids),
-                            np.int32).reshape(-1)
-        L = len(prompt)
-        if L == 0 or L >= self.max_len:
-            raise ValueError(f"prompt length {L} out of range (1.."
-                             f"{self.max_len - 1})")
-        rid = self._next_rid
-        self._next_rid += 1
-        req = _Request(rid, prompt, max_new_tokens,
-                       time.perf_counter_ns())
-        self._pending.append(req)
-        self._stats[rid] = {"rid": rid, "prompt_len": L,
-                            "submit_ns": req.t_submit}
-        if len(self._stats) > 4096:
-            self._stats.popitem(last=False)
-        return rid
-
-    def pop_stats(self, rid):
-        return self._stats.pop(rid, None)
-
-    def _admit_wave(self):
-        for b in range(self.max_batch):
-            if not self._pending:
-                break
-            req = self._pending.popleft()
-            L = len(req.prompt)
-            bucket = next((k for k in self._buckets if k >= L),
-                          self.max_len)
-            padded = np.zeros((1, bucket), np.int32)
-            padded[0, :L] = req.prompt
-            need = np.where([s is not None for s in self._slots],
-                            self.lens + 1, 0)
-            need[b] = L + 1
-            self._pager.ensure_capacity(need)
-            row_tables = self._pager.block_tables[b:b + 1]
-            tok_dev, self._pools = self._prefill_slot_jit(bucket)(
-                jnp.asarray(padded), self._pools, row_tables,
-                jnp.asarray(L, jnp.int32), self._inner.weights)
-            tok = int(tok_dev)
-            req.prefill_pos = L
-            req.last_token = tok
-            req.outputs = [tok]
-            req.t_first = time.perf_counter_ns()
-            self._slots[b] = req
-            self.lens[b] = L
-            self._done[b] = False
-            st = self._stats.get(req.rid)
-            if st is not None:
-                st["ttft_ns"] = req.t_first - req.t_submit
-                st["tokens"] = 1
-
-    def step(self, eos_token_id=None, max_new_tokens=None):
-        """One wave-synchronous step. With no wave in flight, admits (and
-        prefills) the next wave; otherwise decodes EVERY wave slot in
-        lockstep — finished rows keep burning their lane until the whole
-        wave completes (the static-batching cost being measured)."""
-        finished = []
-        active = [b for b in range(self.max_batch)
-                  if self._slots[b] is not None]
-        if not active:
-            if not self._pending:
-                return []
-            self._admit_wave()
-            active = [b for b in range(self.max_batch)
-                      if self._slots[b] is not None]
-            # first tokens may already complete single-token requests
-            for b in active:
-                self._check_done(b, eos_token_id, max_new_tokens)
-            return self._maybe_drain_wave(active, finished)
-        tokens = np.zeros((self.max_batch, 1), np.int32)
-        for b in active:
-            tokens[b, 0] = self._slots[b].last_token
-        need = np.where([s is not None for s in self._slots],
-                        self.lens + 1, 0)
-        self._pager.ensure_capacity(need)
-        step = self._step_all_jit()
-        toks_dev, self._pools = step(
-            jnp.asarray(tokens), self._pools, self._pager.block_tables,
-            jnp.asarray(self.lens, jnp.int32), self._inner.weights)
-        toks = np.asarray(toks_dev)
-        for b in active:
-            req = self._slots[b]
-            if self._done[b]:
-                # a finished row burns its decode lane until the wave
-                # drains (the static-batching waste being measured), but
-                # its position is FROZEN: it re-writes garbage over its
-                # last slot instead of growing past its block table
-                continue
-            self.lens[b] += 1
-            tok = int(toks[b])
-            req.outputs.append(tok)
-            req.last_token = tok
-            st = self._stats.get(req.rid)
-            if st is not None:
-                st["tokens"] = len(req.outputs)
-            self._check_done(b, eos_token_id, max_new_tokens)
-        return self._maybe_drain_wave(active, finished)
-
-    def _check_done(self, b, eos_token_id, max_new_tokens):
-        req = self._slots[b]
-        limit = req.max_new if req.max_new is not None else max_new_tokens
-        tok = req.outputs[-1]
-        if (eos_token_id is not None and tok == eos_token_id) \
-                or (limit is not None and len(req.outputs) >= limit) \
-                or self.lens[b] + 1 >= self.max_len:
-            self._done[b] = True
-
-    def _maybe_drain_wave(self, active, finished):
-        if active and all(self._done[b] for b in active):
-            for b in active:
-                req = self._slots[b]
-                finished.append((req.rid, list(req.outputs)))
-                self._pager.free_sequence(b)
-                self._slots[b] = None
-                self.lens[b] = 0
-                self._done[b] = False
-        return finished
-
-    @property
-    def num_active(self):
-        return sum(1 for s in self._slots if s is not None)
-
-    @property
-    def num_pending(self):
-        return len(self._pending)

@@ -68,8 +68,7 @@ def provenance():
     """The provenance block attached to every snapshot. git_rev is OMITTED
     (not sentinel-filled) outside a git checkout: an absent rev means
     "unversioned deployment" and still validates, while a PRESENT
-    placeholder marks forgery — the same policy bench.py's replay cache
-    applies."""
+    placeholder marks forgery."""
     prov = {
         "hostname": socket.gethostname(),
         "pid": os.getpid(),

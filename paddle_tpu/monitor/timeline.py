@@ -18,9 +18,7 @@ perf questions ROADMAP items 1/2 keep asking of that record:
   ``serving.request`` root per request with ``serving.queue_wait`` /
   ``serving.prefill`` children, so TTFT splits into queue wait +
   chunked prefill + the (small) scheduling gap, components summing to
-  the measured TTFT by construction;
-- **MFU** — tokens x flops-per-token vs wall against a peak-FLOP/s
-  denominator (the bench.py formula, importable instead of copied).
+  the measured TTFT by construction.
 
 Everything here is pure computation over span DICTS (``Span.to_dict()``
 shape, or ``span_dump()`` output) — no jax, no framework import, no
@@ -28,26 +26,6 @@ clock reads, so analytics over a flight dump work offline in any
 process. :func:`perf_report` assembles every section the live ring can
 support and backs the debug server's ``/perfz`` endpoint
 (``monitor/server.py``; docs/introspection.md has the exact formulas).
-
-The **modeled schedule** half (:func:`modeled_step_timeline`) bridges
-the one place wall-clock spans cannot see: a single fused XLA program
-dispatches as ONE host span, so the comm/compute overlap INSIDE the
-mesh train step is invisible to the ring. The model walks the traced
-jaxpr (duck-typed eqns, same discipline as
-``analysis/jaxpr/collectives.py``) under a two-stream schedule —
-compute eqns execute sequentially in program order on the compute
-stream; collective eqns execute in program order on ONE in-order comm
-stream, each starting as soon as its operands are ready (start = max of
-data-ready and the comm stream becoming free — collective-start hoisted
-up to the data dependence) and stalling compute only at the first
-consumer. That is what makes the PR 13 bucketed build measurable: the
-legacy exchange iterates params in FORWARD order, so its first
-collective waits on the LAST-completing gradient and convoys every
-later one behind it on the in-order stream, while completion-ordered
-buckets drain as the backward produces them and overlap the remaining
-backward compute. The synthetic spans it returns (``compute`` busy
-intervals + ``comm.<collective>`` intervals) feed the SAME
-:func:`comm_overlap` formula as real spans.
 """
 from __future__ import annotations
 
@@ -55,15 +33,14 @@ import statistics
 
 __all__ = [
     "comm_overlap", "step_phases", "bubble_fraction",
-    "ttft_decomposition", "mfu", "transformer_flops_per_token",
-    "perf_report", "modeled_step_timeline", "modeled_overlap_report",
+    "ttft_decomposition", "perf_report",
     "COMPUTE_SPAN_NAMES", "TRAIN_STAGES",
 ]
 
 # wall-clock span names that count as device/compute work for the
-# overlap formula (the modeled schedule adds its own "compute" spans)
+# overlap formula
 COMPUTE_SPAN_NAMES = frozenset({
-    "train.forward", "train.backward", "train.optimizer", "compute",
+    "train.forward", "train.backward", "train.optimizer",
 })
 
 TRAIN_STAGES = ("dataload", "forward", "backward", "optimizer")
@@ -296,25 +273,6 @@ def ttft_decomposition(spans):
     return {"requests": len(rows), "rows": rows, "p50_ms": p50}
 
 
-# -- MFU ---------------------------------------------------------------------
-
-def transformer_flops_per_token(n_params, num_layers=0, hidden=0, seq=0):
-    """The decoder-transformer train-step FLOPs/token formula bench.py
-    stamps MFU with: ``6 * n_params`` (fwd+bwd matmuls) plus the
-    attention term ``12 * L * H * seq``."""
-    return 6 * int(n_params) + 12 * int(num_layers) * int(hidden) \
-        * int(seq)
-
-
-def mfu(tokens, wall_s, flops_per_token, peak_flops):
-    """Model-FLOPs utilization: ``tokens * flops_per_token / (wall_s *
-    peak_flops)`` — the fraction of the chip's peak matmul throughput
-    the measured pass sustained."""
-    if wall_s <= 0 or peak_flops <= 0:
-        return 0.0
-    return tokens * flops_per_token / (wall_s * peak_flops)
-
-
 # -- the assembled report (/perfz) -------------------------------------------
 
 def perf_report(spans=None):
@@ -348,203 +306,3 @@ def perf_report(spans=None):
         doc["serving"] = {"ttft": ttft_decomposition(closed)}
     return doc
 
-
-# -- the modeled two-stream schedule over a traced program -------------------
-
-# jaxpr-level collective spellings (analysis/jaxpr/collectives.py is the
-# one home; imported lazily so this module stays framework-free at
-# import time for offline dump analysis)
-def _collectives_mod():
-    from ..analysis.jaxpr import collectives as c
-
-    return c
-
-
-def _aval_elems(aval):
-    shape = getattr(aval, "shape", None)
-    if shape is None:
-        return 0
-    n = 1
-    for d in shape:
-        n *= int(d)
-    return n
-
-
-# pure layout/metadata primitives: XLA fuses these into their consumers
-# (or elides them entirely), so the model treats them as FREE
-# pass-throughs — zero compute time, output ready = input ready. This is
-# what lets a collective's readiness reflect its GRADIENT's completion
-# time instead of the position of its reshape/pad wrapper in the traced
-# program (the whole exchange section is traced after the backward).
-_FREE_PRIMITIVES = frozenset({
-    "reshape", "transpose", "squeeze", "expand_dims", "broadcast_in_dim",
-    "pad", "concatenate", "slice", "dynamic_slice", "rev",
-    "convert_element_type", "bitcast_convert_type", "copy",
-    "stop_gradient", "sharding_constraint",
-})
-
-
-def _eqn_flops(eqn):
-    """Modeled compute cost of one non-collective eqn: dot_general pays
-    ``2 * out_elems * contracted_size``; everything else one flop per
-    output element (a relative cost model — only the schedule's shape
-    matters, not absolute time)."""
-    out_elems = sum(_aval_elems(getattr(v, "aval", None))
-                    for v in eqn.outvars)
-    if eqn.primitive.name == "dot_general":
-        try:
-            (lhs_c, _rhs_c), _batch = eqn.params["dimension_numbers"]
-            lhs_shape = eqn.invars[0].aval.shape
-            k = 1
-            for d in lhs_c:
-                k *= int(lhs_shape[d])
-            first_out = _aval_elems(eqn.outvars[0].aval)
-            return 2 * first_out * max(k, 1)
-        except Exception:  # noqa: BLE001 - fall through to the default
-            pass
-    return max(out_elems, 1)
-
-
-class _Sched:
-    __slots__ = ("compute_t", "comm_free", "busy", "comm_spans",
-                 "stall_ns", "flop_ns", "byte_ns")
-
-    def __init__(self, flops_per_s, bytes_per_s):
-        self.compute_t = 0.0
-        self.comm_free = 0.0
-        self.busy = []          # compute (t0, t1) intervals
-        self.comm_spans = []    # (canonical collective, t0, t1, bytes)
-        self.stall_ns = 0.0
-        self.flop_ns = 1e9 / float(flops_per_s)
-        self.byte_ns = 1e9 / float(bytes_per_s)
-
-
-def _is_literal(v):
-    return hasattr(v, "val") and not hasattr(v, "count")
-
-
-def _ready(env, v):
-    if _is_literal(v):
-        return 0.0
-    return env.get(v, 0.0)
-
-
-def _walk_schedule(jaxpr, env, st):
-    coll = _collectives_mod()
-    for eqn in jaxpr.eqns:
-        canon = coll.COLLECTIVE_PRIMITIVES.get(eqn.primitive.name)
-        t_ready = max([_ready(env, v) for v in eqn.invars], default=0.0)
-        if canon is not None:
-            # async collective on ONE in-order comm stream: collectives
-            # execute in program order, but each may START as soon as
-            # its operands are ready (collective-start hoisted up to the
-            # data dependence) — so a program whose FIRST exchange waits
-            # on the LAST-completing gradient convoys every later one
-            # behind it, while completion-ordered buckets drain as the
-            # backward produces them. Compute stalls only at consumers.
-            nbytes = max(
-                sum(coll._aval_bytes(getattr(v, "aval", None))
-                    for v in eqn.invars),
-                sum(coll._aval_bytes(getattr(v, "aval", None))
-                    for v in eqn.outvars))
-            issue = max(t_ready, st.comm_free)
-            done = issue + nbytes * st.byte_ns
-            st.comm_free = done
-            st.comm_spans.append((canon, issue, done, nbytes))
-            for v in eqn.outvars:
-                env[v] = done
-            continue
-        subs = list(coll.iter_subjaxprs(eqn))
-        if subs:
-            # inline every sub-jaxpr (cond branches both count —
-            # conservative; scan/while bodies count once per trace, the
-            # same caveat as the byte census). Bind invars/outvars
-            # tail-aligned so cond's leading predicate drops out.
-            for _slot, sub in subs:
-                for cv in getattr(sub, "constvars", ()):
-                    env.setdefault(cv, 0.0)
-                n = min(len(eqn.invars), len(sub.invars))
-                if n:
-                    for outer, inner in zip(eqn.invars[-n:],
-                                            sub.invars[-n:]):
-                        env[inner] = _ready(env, outer)
-                _walk_schedule(sub, env, st)
-                m = min(len(eqn.outvars), len(sub.outvars))
-                if m:
-                    for outer, inner in zip(eqn.outvars[-m:],
-                                            sub.outvars[-m:]):
-                        env[outer] = _ready(env, inner)
-            for v in eqn.outvars:
-                env.setdefault(v, st.compute_t)
-            continue
-        if eqn.primitive.name in _FREE_PRIMITIVES:
-            # fused-away layout op: free, and a pure dependence
-            # pass-through (does not occupy or wait for the compute
-            # stream)
-            for v in eqn.outvars:
-                env[v] = t_ready
-            continue
-        start = max(st.compute_t, t_ready)
-        if start > st.compute_t:
-            st.stall_ns += start - st.compute_t
-        end = start + _eqn_flops(eqn) * st.flop_ns
-        if end > start:
-            st.busy.append((start, end))
-        st.compute_t = end
-        for v in eqn.outvars:
-            env[v] = end
-
-
-def modeled_step_timeline(jaxpr, *, flops_per_s=1e12, bytes_per_s=1e11):
-    """Synthetic span set for one traced program under the two-stream
-    schedule (module docstring): ``compute`` spans for the merged
-    compute-busy intervals and one ``comm.<collective>`` span per
-    collective eqn. Deterministic in the program alone; feed the result
-    to :func:`comm_overlap` / :func:`modeled_overlap_report`."""
-    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)    # ClosedJaxpr -> Jaxpr
-    st = _Sched(flops_per_s, bytes_per_s)
-    env = {}
-    for v in list(getattr(jaxpr, "constvars", ())) \
-            + list(getattr(jaxpr, "invars", ())):
-        env[v] = 0.0
-    _walk_schedule(jaxpr, env, st)
-    spans = []
-    sid = 1
-    for t0, t1 in _union(st.busy):
-        spans.append({"name": "compute", "span_id": sid, "trace_id": 0,
-                      "parent_id": None, "t0_ns": int(round(t0)),
-                      "t1_ns": int(round(t1))})
-        sid += 1
-    for canon, t0, t1, nbytes in st.comm_spans:
-        spans.append({"name": f"comm.{canon}", "span_id": sid,
-                      "trace_id": 0, "parent_id": None,
-                      "t0_ns": int(round(t0)), "t1_ns": int(round(t1)),
-                      "attrs": {"bytes": int(nbytes)}})
-        sid += 1
-    spans.sort(key=lambda d: d["t0_ns"])
-    return spans, {"stall_ns": int(round(st.stall_ns)),
-                   "makespan_ns": int(round(max(st.compute_t,
-                                                st.comm_free)))}
-
-
-def modeled_overlap_report(jaxpr, *, flops_per_s=1e12, bytes_per_s=1e11):
-    """The modeled comm-overlap report of one traced step program:
-    :func:`comm_overlap` over the modeled span set, plus the compute
-    stall (time the compute stream waited on a collective's result) and
-    the modeled makespan. The one number ROADMAP item 2 left
-    unmeasured: the PR 13 bucketed-overlap build reports a strictly
-    higher ``overlap_fraction`` than the legacy tape-end exchange of
-    the same model (mesh_bench's ``timeline`` rows)."""
-    spans, extra = modeled_step_timeline(
-        jaxpr, flops_per_s=flops_per_s, bytes_per_s=bytes_per_s)
-    rep = comm_overlap(spans, compute_names=frozenset({"compute"}))
-    makespan = max((d["t1_ns"] for d in spans), default=0)
-    rep.update({
-        "collectives": sum(1 for d in spans
-                           if d["name"].startswith("comm.")),
-        "comm_stall_ns": extra["stall_ns"],
-        "makespan_ns": makespan,
-        "comm_stall_fraction": (extra["stall_ns"] / makespan)
-        if makespan else 0.0,
-    })
-    return rep

@@ -258,8 +258,7 @@ def _trace_ticket(trace):
     span (N = trace.dispatch_sample_every()). The ticket is drawn BEFORE
     any timing so the 63-in-64 unsampled dispatches pay one atomic count
     bump + a modulo, not two clock reads — the enabled-mode span tax
-    stays a fraction of the per-op cost (bench.py detail.trace_overhead
-    tracks it)."""
+    stays a fraction of the per-op cost."""
     return next(trace._dispatch_tick) % trace._DISPATCH_SAMPLE_EVERY == 0
 
 

@@ -67,8 +67,7 @@ drill the router (analysis/faultinject.py); fleet metrics and spans are
 cataloged in monitor/catalog.py (docs/observability.md, docs/tracing.md);
 the chaos drill — kill 1 of 3 replicas under the Poisson mixed workload,
 all requests complete bit-identically, plus the zero-loss drain drill —
-is ``bench_common.fleet_bench`` via ``bench_suite.py --smoke fleet``,
-gated in tier-1.
+is tests/test_serving_fleet.py (TestFailoverDrill, TestDrainAndResume).
 """
 from __future__ import annotations
 
@@ -303,7 +302,7 @@ class FleetRouter:
         # re-route work that found NO admissible replica (total outage):
         # retried by the health monitor as soon as one heals
         self._stranded = collections.deque()
-        # host-side counters (the bench reads these with the monitor off)
+        # host-side counters (readable with the monitor off)
         self.requests_total = 0
         self.failovers = 0
         self.hedges = 0

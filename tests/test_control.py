@@ -29,6 +29,7 @@ The acceptance bars:
 """
 import json
 import os
+import time
 
 import numpy as np
 import pytest
@@ -574,6 +575,57 @@ class TestServingControllerWiring:
                 assert rep.engine._pending_knobs == {"chunk_size": 32}
         finally:
             ctl.close()
+
+    def test_autoscale_resumes_a_drained_replica_and_moves_no_token(self):
+        """The closed loop on a live fleet, no clock in it: a backlog
+        queued on the one active replica makes the first tick resume the
+        drained one; the record replays to the identical decisions
+        inside the declared bounds; and the tokens equal those of the
+        same fleet with no controller and with a controller that is
+        built and never ticked (knobs move latency, never tokens)."""
+        model = _model()
+        r = np.random.RandomState(5)
+        prompts = [r.randint(0, 96, (10,)).astype("int32")
+                   for _ in range(6)]
+
+        def serve(controller):
+            fl = _fleet(model, replicas=2, start=False)
+            ctl = None
+            try:
+                fl.drain(1)                      # the overnight shape
+                if controller:
+                    ctl = build_serving_controller(
+                        fl, rules=[AutoscaleRule()], register=False)
+                frids = [fl.submit(p, max_new_tokens=6) for p in prompts]
+                if controller == "ticked":
+                    ctl.tick(now=0.0)
+                fl.start()
+                got, t0 = {}, time.time()
+                while len(got) < len(frids) and time.time() - t0 < 60:
+                    got.update((f, list(t)) for f, t in fl.pop_results())
+                    time.sleep(0.001)
+                return [got.get(f) for f in frids], fl.active_replicas(), \
+                    ctl and ctl.recorder.export(), ctl and ctl.degraded
+            finally:
+                if ctl is not None:
+                    ctl.close()
+                fl.stop()
+
+        static, n_static, _, _ = serve(None)
+        off, n_off, _, _ = serve("built")
+        on, n_on, record, degraded = serve("ticked")
+        assert all(t is not None for t in static)
+        assert off == static and on == static
+        assert (n_static, n_off, n_on) == (1, 1, 2)
+        sets = [d for t in record["ticks"] for d in t["decisions"]
+                if d["action"] == "set"]
+        assert [(d["knob"], d["old"], d["new"]) for d in sets] \
+            == [("fleet.replicas", 1, 2)]
+        spec = KNOB_BOUNDS["fleet.replicas"]
+        assert spec["min"] <= 2 <= spec["max"] and 1 <= spec["slew"]
+        assert decision_sequence(replay(record, [AutoscaleRule()])) \
+            == decision_sequence(record) != []
+        assert degraded is False
 
     def test_fleet_telemetry_snapshot_is_jsonable(self):
         fl = _fleet(_model(), replicas=2, start=False)

@@ -26,13 +26,14 @@ def _v5e():
 
 
 def _model():
-    # the bench.py flagship: ~542M params, hidden 2048, 8 layers, seq 2048
+    # the estimator's default subject: ~542M params, hidden 2048, 8 layers,
+    # seq 2048
     return ModelDesc(542_000_000, hidden=2048, layers=8, seq=2048)
 
 
 class TestEstimatorProperties:
     def test_flagship_matches_measured_band(self):
-        """The estimate for the old bench.py shape on a v5e must land in a
+        """The estimate for the default subject on a v5e must land in a
         loose plausibility band (a sanity bound on the roofline arithmetic:
         no measured figure exists to match, PERF.md)."""
         est = estimate_cost(_model(), ParallelConfig(

@@ -22,6 +22,8 @@ from paddle_tpu import monitor
 from paddle_tpu.distributed import api as dist_api
 from paddle_tpu.distributed.placement import Partial, Replicate, Shard
 from paddle_tpu.distributed.process_mesh import ProcessMesh
+from paddle_tpu.framework import random as rng
+from paddle_tpu.framework.core import Tensor
 from paddle_tpu.monitor import trace
 
 
@@ -35,15 +37,83 @@ def _mse(m, x, y):
     return ((m(x) - y) ** 2).mean()
 
 
+def _build_step(model, optimizer, loss_fn):
+    """The single-device reference: one donated, jitted train step (fwd + bwd
+    + optimizer) with functional state threading over the live Layer and
+    Optimizer objects, no mesh. The plain eager loop is NOT the reference
+    here: under jit `optimizer.step()` is traced once, so Adam's step counter
+    stays at 1 in this step as in `mesh.parallelize`'s (PERF.md section 7,
+    question 1), and the eager loop's third loss already differs by 4%.
+
+    Returns (jitted_step, state_fn):
+      jitted_step(param_values, acc_values, master_values, *batch)
+        -> (loss_value, new_params, new_accs, new_masters)
+      state_fn() -> the current (params, accs, masters) value lists
+
+    ``loss_fn(model, *batch_tensors)`` returns the scalar loss Tensor.
+    """
+    params = [p for _, p in model.named_parameters()]
+    for p in params:
+        if id(p) not in optimizer._accumulators:
+            optimizer._accumulators[id(p)] = optimizer._init_state(p)
+        if (optimizer._use_master_weights
+                and id(p) not in optimizer._master_weights):
+            optimizer._master_weights[id(p)] = p.value.astype(jnp.float32)
+    acc_keys = [sorted(optimizer._accumulators[id(p)].keys()) for p in params]
+    use_masters = optimizer._use_master_weights
+
+    def train_step(param_values, acc_values, master_values, *batch):
+        with rng.trace_key(jax.random.PRNGKey(0)):
+            saved_p = [(p, p._value) for p in params]
+            saved_a = {id(p): dict(optimizer._accumulators[id(p)])
+                       for p in params}
+            saved_m = dict(optimizer._master_weights)
+            try:
+                for p, v in zip(params, param_values):
+                    p._replace_value(v)
+                for p, ks, vs in zip(params, acc_keys, acc_values):
+                    for k, v in zip(ks, vs):
+                        optimizer._accumulators[id(p)][k] = v
+                if use_masters:
+                    for p, mv in zip(params, master_values):
+                        optimizer._master_weights[id(p)] = mv
+                loss = loss_fn(model, *[Tensor(b) for b in batch])
+                loss.backward()
+                optimizer.step()
+                optimizer.clear_grad()
+                new_p = [p._value for p in params]
+                new_a = [[optimizer._accumulators[id(p)][k] for k in ks]
+                         for p, ks in zip(params, acc_keys)]
+                new_m = ([optimizer._master_weights[id(p)] for p in params]
+                         if use_masters else master_values)
+                return loss.value, new_p, new_a, new_m
+            finally:
+                for p, v in saved_p:
+                    p._replace_value(v)
+                for p in params:
+                    optimizer._accumulators[id(p)] = saved_a[id(p)]
+                optimizer._master_weights = saved_m
+
+    jitted = jax.jit(train_step, donate_argnums=(0, 1, 2))
+
+    def state_fn():
+        pv = [p.value for p in params]
+        av = [[optimizer._accumulators[id(p)][k] for k in ks]
+              for p, ks in zip(params, acc_keys)]
+        mv = ([optimizer._master_weights[id(p)] for p in params]
+              if use_masters else [])
+        return pv, av, mv
+
+    return jitted, state_fn
+
+
 def _single_device_losses(factory, loss_fn, batch, steps, lr=1e-2,
                           opt_cls=None):
-    from bench_common import build_step
-
     paddle.seed(0)
     model = factory()
     opt_cls = opt_cls or paddle.optimizer.Adam
     opt = opt_cls(learning_rate=lr, parameters=model.parameters())
-    step, state, _ = build_step(model, opt, loss_fn)
+    step, state = _build_step(model, opt, loss_fn)
     pv, av, mv = state()
     losses = []
     for _ in range(steps):

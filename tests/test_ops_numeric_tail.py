@@ -943,9 +943,9 @@ WAIVERS = {
     "qr": "sign-ambiguous factors; reconstruction-tested",
     "svd": "sign-ambiguous factors; reconstruction-tested",
     "householder_product": "composition of reflectors; covered via qr tests",
-    # attention kernels: dedicated correctness suites (incl. on-device Pallas
-    # checks in bench.py and tests/test_pallas.py)
-    "flash_attention": "vs math-path oracle in test_pallas + bench on-device",
+    # attention kernels: dedicated correctness suites (tests/test_pallas.py;
+    # on the chip, chip_smoke.py's flash phase)
+    "flash_attention": "vs math-path oracle in test_pallas + chip_smoke on-device",
     "flash_attn_varlen": "vs dense-attention oracle in test_nn varlen tests",
     # recurrent/scan kernels: sequence-level tests in test_nn rnn suites
     "rnn_scan": "lstm/gru sequence parity tests in test_nn",

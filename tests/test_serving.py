@@ -26,8 +26,7 @@ from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.analysis import faultinject as fi
 from paddle_tpu.models.serving import (AdmissionTimeout,
                                        ContinuousBatchingEngine,
-                                       RequestShed,
-                                       StaticBatchEngine)
+                                       RequestShed)
 
 
 def _model(vocab=96, layers=2):
@@ -300,64 +299,6 @@ class TestSanitizedSteadyState:
             san.reset()
 
 
-class TestStaticBatchEngine:
-    def test_wave_synchronous_barrier(self):
-        """The baseline's defining cost: a request submitted after the
-        wave started waits for the WHOLE wave to drain before admission,
-        and all wave members evict together."""
-        model = _model()
-        rng = np.random.RandomState(5)
-        eng = StaticBatchEngine(model, max_batch=2, max_len=64,
-                                block_size=8, prefill_buckets=(16,))
-        r1 = eng.submit(rng.randint(0, 96, (6,)).astype("int32"),
-                        max_new_tokens=2)
-        r2 = eng.submit(rng.randint(0, 96, (4,)).astype("int32"),
-                        max_new_tokens=8)
-        eng.step()                      # admits + prefills the wave
-        r3 = eng.submit(rng.randint(0, 96, (5,)).astype("int32"),
-                        max_new_tokens=2)
-        assert eng.num_active == 2 and eng.num_pending == 1
-        finished = []
-        for _ in range(12):
-            finished += eng.step()
-            if finished:
-                break
-        # r1 finished at 2 tokens but was held until r2's 8 drained
-        assert sorted(r for r, _ in finished) == [r1, r2]
-        assert dict(finished)[r1].__len__() == 2
-        assert eng.num_pending == 1
-        eng.step()                      # next wave admits r3
-        assert eng.num_active == 1 and eng.num_pending == 0
-        done = {r: t for r, t in _run_all(eng).items()}
-        assert len(done[r3]) == 2
-
-    def test_early_finisher_never_overruns_its_block_table(self):
-        """A row finishing early keeps burning its lane until the wave
-        drains, but its position must FREEZE — a long-prompt early
-        finisher next to a long-running short-prompt peer would otherwise
-        grow past max_blocks_per_seq and crash the allocator."""
-        model = _model()
-        rng = np.random.RandomState(6)
-        eng = StaticBatchEngine(model, max_batch=2, max_len=32,
-                                block_size=8, prefill_buckets=(32,))
-        ra = eng.submit(rng.randint(0, 96, (20,)).astype("int32"),
-                        max_new_tokens=2)       # done at lens 21
-        rb = eng.submit(rng.randint(0, 96, (4,)).astype("int32"),
-                        max_new_tokens=26)      # decodes ~25 more steps
-        done = _run_all(eng, max_steps=40)
-        assert len(done[ra]) == 2 and len(done[rb]) == 26
-        assert eng.lens.max() == 0              # wave fully evicted
-
-    def test_static_stats_carry_ttft(self):
-        model = _model()
-        eng = StaticBatchEngine(model, max_batch=1, max_len=32,
-                                block_size=8, prefill_buckets=(16,))
-        rid = eng.submit(np.arange(6, dtype="int32"), max_new_tokens=2)
-        _run_all(eng)
-        st = eng.pop_stats(rid)
-        assert st["ttft_ns"] > 0 and st["tokens"] == 2
-
-
 def test_prompt_length_validation():
     eng = ContinuousBatchingEngine(_model(), max_batch=2, max_len=16)
     with pytest.raises(ValueError, match="out of range"):
@@ -525,6 +466,109 @@ class TestTenants:
         assert shed > 0
         for old_rid, new_rid in zip(rids, gold_rids):
             assert list(done[new_rid]) == iso[old_rid]
+
+    @staticmethod
+    def _strict(model, **kw):
+        eng = ContinuousBatchingEngine(model, max_batch=2, max_len=64,
+                                       block_size=8, chunk_size=16,
+                                       decode_burst=1,
+                                       strict_priority=True, **kw)
+        eng.set_tenant("gold", weight=2.0, priority=1)
+        eng.set_tenant("bronze", weight=1.0, priority=0)
+        return eng
+
+    def test_strict_priority_defers_lower_work_while_higher_is_active(self):
+        """One gold request holds one of two slots: queued bronze stays
+        queued though a slot is free, and is admitted by the first step
+        after gold's eviction. The same engine without the option hands
+        bronze the free slot at once."""
+        model = _model()
+        r = np.random.RandomState(21)
+        gold = r.randint(0, 96, (9,)).astype("int32")
+        bronze = r.randint(0, 96, (9,)).astype("int32")
+        for strict in (True, False):
+            eng = self._strict(model)
+            eng.strict_priority = strict
+            g = eng.submit(gold, max_new_tokens=6, tenant="gold")
+            eng.step()
+            b = eng.submit(bronze, max_new_tokens=3, tenant="bronze")
+            done = dict(eng.step())
+            active = {s.rid for s in eng._slots if s is not None}
+            assert active == ({g} if strict else {g, b})
+            while strict and g not in done:
+                assert eng.num_pending == 1
+                assert [s.rid for s in eng._slots if s is not None] == [g]
+                done.update(eng.step())
+            if strict:                   # gold evicted: the next step admits
+                done.update(eng.step())
+                assert [s.rid for s in eng._slots if s is not None] == [b]
+            done.update(_run_all(eng))
+            assert len(done[g]) == 6 and len(done[b]) == 3
+
+    def test_strict_priority_keeps_high_tenant_tokens_bit_identical(self):
+        """Gold's tokens with a bronze flood beside it equal its
+        isolated run's, bit for bit, and no bronze request shares a
+        step with gold: none holds a slot while a gold one is active
+        or queued."""
+        model = _model()
+        r = np.random.RandomState(22)
+        gold_prompts = [r.randint(0, 96, (n,)).astype("int32")
+                        for n in (9, 12, 7)]
+        eng = self._strict(model)
+        rids = [eng.submit(p, max_new_tokens=8, tenant="gold")
+                for p in gold_prompts]
+        iso = _run_all(eng, max_steps=200)
+        gold_rids = [eng.submit(p, max_new_tokens=8, tenant="gold")
+                     for p in gold_prompts]
+        bronze_rids = [eng.submit(r.randint(0, 96, (9,)).astype("int32"),
+                                  max_new_tokens=4, tenant="bronze")
+                       for _ in range(5)]
+        done, gold_left = {}, set(gold_rids)
+        for _ in range(400):
+            if gold_left:
+                # gold active or queued: no bronze request holds a slot
+                assert all(s is None or s.tenant == "gold"
+                           for s in eng._slots)
+            for rid, toks in eng.step():
+                done[rid] = np.asarray(toks)
+                gold_left.discard(rid)
+            if not (eng.num_active or eng.num_pending):
+                break
+        for old, new in zip(rids, gold_rids):
+            np.testing.assert_array_equal(done[new], iso[old])
+        assert all(len(done[b]) == 4 for b in bronze_rids)
+
+    def test_strict_priority_sheds_the_flood_and_nothing_of_the_high(self):
+        """Under ``max_queue`` the deferred flood fills the queue once
+        and every later bronze arrival is shed typed; gold submits
+        displace queued bronze; no gold request is ever shed."""
+        model = _model()
+        r = np.random.RandomState(23)
+        eng = self._strict(model, max_queue=3)
+        g0 = eng.submit(r.randint(0, 96, (9,)).astype("int32"),
+                        max_new_tokens=20, tenant="gold")
+        eng.step()                       # gold active: bronze is deferred
+        shed_on_submit = 0
+        for _ in range(8):
+            try:
+                eng.submit(r.randint(0, 96, (9,)).astype("int32"),
+                           max_new_tokens=4, tenant="bronze")
+            except RequestShed as e:
+                assert e.tenant == "bronze"
+                shed_on_submit += 1
+            eng.step()                   # a free slot, and still deferred
+        assert shed_on_submit == 8 - 3   # the queue holds 3, once
+        gold_rids = [g0] + [
+            eng.submit(r.randint(0, 96, (9,)).astype("int32"),
+                       max_new_tokens=6, tenant="gold") for _ in range(2)]
+        displaced = eng.pop_shed()
+        assert len(displaced) == 2
+        assert all(isinstance(e, RequestShed) and e.tenant == "bronze"
+                   for e in displaced)
+        done = _run_all(eng, max_steps=400)
+        assert [len(done[g]) for g in gold_rids] == [20, 6, 6]
+        assert eng.pop_shed() == []
+        assert len(done) == 3 + 1        # gold's three and bronze's last
 
 
 # --------------------------------------------------------------------------- #
@@ -815,6 +859,52 @@ class TestQuantizedKV:
             agree = (np.asarray(full[:n]) == np.asarray(q[:n])).mean()
             assert agree >= 0.75, (full, q)
             np.testing.assert_array_equal(full[:4], q[:4])
+
+    def test_int8_pools_hold_1_8x_the_requests_in_the_bf16_pool_bytes(self):
+        """ISSUE 7 acceptance: at the byte budget of the bfloat16 engine's
+        pool, int8 pools hold >= 1.8 x the concurrent requests, each to
+        its full length (head_dim 64: 256 B a token and KV head in
+        bfloat16, 128 + 8 B of scales in int8). Counted in blocks and
+        slots and from the pool-bytes gauge, never timed."""
+        paddle.seed(0)
+        cfg = LlamaConfig(vocab_size=96, hidden_size=128,
+                          intermediate_size=176, num_hidden_layers=2,
+                          num_attention_heads=2, num_key_value_heads=1,
+                          max_position_embeddings=128, dtype="bfloat16")
+        model = LlamaForCausalLM(cfg)
+        model.to(dtype="bfloat16")
+        kw = dict(max_len=32, block_size=8, chunk_size=16,
+                  prefix_cache=False)
+        ref = ContinuousBatchingEngine(model, max_batch=5, **kw)
+        blocks_a_request = -(-ref.max_len // ref.block_size)
+        block_bytes = ContinuousBatchingEngine(
+            model, max_batch=1, kv_cache_dtype="int8",
+            **kw).kv_pool_bytes // (blocks_a_request + 1)
+        pool_blocks = ref.kv_pool_bytes // block_bytes
+        n = (pool_blocks - 1) // blocks_a_request   # whole-length requests
+        assert n >= 1.8 * 5, (n, pool_blocks)
+        monitor.reset()
+        monitor.enable()
+        try:
+            eng = ContinuousBatchingEngine(
+                model, max_batch=n, kv_cache_dtype="int8",
+                pool_blocks=pool_blocks, **kw)
+            rng = np.random.RandomState(19)
+            rids = [eng.submit(rng.randint(0, 96, (4,)).astype("int32"))
+                    for _ in range(n)]
+            eng.step()                   # admission drains: every slot fills
+            assert eng.num_active == n and eng.num_pending == 0
+            gauge = monitor.snapshot()["metrics"][
+                "paddle_tpu_serving_kv_pool_bytes"]["values"][""]
+            assert gauge == eng.kv_pool_bytes <= ref.kv_pool_bytes
+            # every request runs to the engine's max_len side by side
+            # (no spill layer: a pool too small would raise): the pool
+            # really holds n whole-length requests
+            done = _run_all(eng, max_steps=400)
+            assert all(len(done[r]) == eng.max_len - 4 for r in rids)
+        finally:
+            monitor.disable()
+            monitor.reset()
 
     def test_int8_spec_bit_identical_to_int8_plain(self):
         """Speculation exactness is dtype-independent: drafts verified

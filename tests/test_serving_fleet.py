@@ -247,6 +247,46 @@ class TestFailoverDrill:
         finally:
             fl.stop()
 
+    def test_failover_leaves_survivors_warm_and_dumps_the_dead_replica(
+            self, monkeypatch, tmp_path):
+        """The warm half of the kill drill: with the recompile sentinel
+        at threshold 1 after warm-up (any compile would raise at its
+        site), killing 1 of 3 replicas adds no program to any replica
+        and trips nothing; the recovery's flight dump is the dead
+        replica's own file."""
+        monkeypatch.setenv("PADDLE_TPU_FLIGHT_DIR", str(tmp_path))
+        monitor.enable()
+        trace.enable()          # the flight dump needs the recorder on
+        model = _model()
+        r = np.random.RandomState(0)
+        prompts = [r.randint(0, 96, (12,)).astype("int32")
+                   for _ in range(9)]
+        fl = _fleet(model, replicas=3, max_new_tokens=8)
+        thr0 = san.recompile_threshold()
+        try:
+            fl.warmup(prompts[0][:6])
+            programs0 = [len(rep.engine._jit_cache) for rep in fl.replicas]
+            san.reset()
+            san.set_recompile_threshold(1)
+            san.enable("recompile")
+            fi.arm("fleet.replica_step", action="raise", nth=6)
+            frids = [fl.submit(p, max_new_tokens=8) for p in prompts]
+            out = _collect(fl, frids)
+            san.disable("recompile")
+            assert all(t is not None for t in out)
+            assert fl.failovers >= 1
+            assert san.trips() == []
+            assert sum(san.compile_counts().values()) == 0
+            assert [len(rep.engine._jit_cache)
+                    for rep in fl.replicas] == programs0
+            (dead,) = [rep for rep in fl.replicas
+                       if rep.engine.recovery_stats]
+            dump = dead.engine.recovery_stats[0]["dump"]
+            assert dump and dead.tag in dump
+        finally:
+            san.set_recompile_threshold(thr0)
+            fl.stop()
+
     def test_fleet_counters_and_metrics_export(self):
         monitor.enable()
         model = _model()
@@ -379,11 +419,14 @@ class TestDrainAndResume:
         fl = _fleet(model, replicas=2, max_new_tokens=10)
         try:
             fl.warmup(prompts[0][:6])
+            ref = _collect(fl, [fl.submit(p, max_new_tokens=10)
+                                for p in prompts])
             frids = [fl.submit(p, max_new_tokens=10) for p in prompts]
             res = fl.drain(1, timeout=30.0)
             assert res["parked"] is True
             out = _collect(fl, frids)
             assert all(t is not None for t in out)          # zero lost
+            assert out == ref       # a drain moves work, never tokens
             assert fl.states()[fl.replicas[1].tag] == PARKED
             assert fl.drains == 1
         finally:

@@ -1,10 +1,8 @@
 """graftscope analytics (ISSUE 15): span-timeline math on CONSTRUCTED
 span sets with hand-computed answers — overlap/bubble/TTFT must match
-exactly, not approximately — plus the modeled two-stream schedule on a
-hand-built program, and the SLO burn-rate window math + alert drill on
-an injected clock.
+exactly, not approximately — plus the SLO burn-rate window math + alert
+drill on an injected clock.
 """
-import numpy as np
 import pytest
 
 import paddle_tpu  # noqa: F401 - initializes the package (monitor deps)
@@ -167,15 +165,6 @@ class TestTTFTDecomposition:
         assert tl.ttft_decomposition(spans)["requests"] == 0
 
 
-class TestMFU:
-    def test_formulas(self):
-        assert tl.transformer_flops_per_token(1000) == 6000
-        assert tl.transformer_flops_per_token(
-            1000, num_layers=2, hidden=8, seq=10) == 6000 + 12 * 2 * 8 * 10
-        assert tl.mfu(100, 1.0, 5e9, 1e12) == 0.5
-        assert tl.mfu(100, 0.0, 5e9, 1e12) == 0.0
-
-
 class TestPerfReport:
     def test_assembles_from_live_ring(self):
         trace.enable()
@@ -190,144 +179,6 @@ class TestPerfReport:
         assert 0.0 <= rep["train"]["bubble"]["bubble_fraction"] <= 1.0
         assert "serving" not in rep
         assert "provenance" in rep
-
-
-# -- the modeled two-stream schedule on a hand-built program ----------------
-
-class _Aval:
-    def __init__(self, shape, dtype="float32"):
-        self.shape = tuple(shape)
-        self.dtype = np.dtype(dtype)
-
-
-class _Var:
-    def __init__(self, shape, dtype="float32"):
-        self.aval = _Aval(shape, dtype)
-        self.count = 0              # marks "not a literal" for _is_literal
-
-
-class _Prim:
-    def __init__(self, name):
-        self.name = name
-
-
-class _Eqn:
-    def __init__(self, prim, invars, outvars, params=None):
-        self.primitive = _Prim(prim)
-        self.invars = list(invars)
-        self.outvars = list(outvars)
-        self.params = params or {}
-
-
-class _Jaxpr:
-    def __init__(self, eqns, invars, outvars, constvars=()):
-        self.eqns = list(eqns)
-        self.invars = list(invars)
-        self.outvars = list(outvars)
-        self.constvars = list(constvars)
-
-
-def _hand_program():
-    """mul(100) -> psum(400B) overlapping an independent mul(300) ->
-    consumer add stalls 100ns. Hand schedule at 1 flop/ns, 1 byte/ns:
-    compute [0,100)+[100,400)+[500,600), comm [100,500), overlap 300."""
-    x = _Var((100,))
-    a = _Var((100,))
-    ar = _Var((100,))
-    y = _Var((300,))
-    b = _Var((300,))
-    c = _Var((100,))
-    eqns = [
-        _Eqn("mul", [x, x], [a]),
-        _Eqn("psum", [a], [ar], {"axes": ("dp",)}),
-        _Eqn("mul", [y, y], [b]),
-        _Eqn("add", [ar, b], [c]),
-    ]
-    return _Jaxpr(eqns, [x, y], [c])
-
-
-class TestModeledSchedule:
-    KW = dict(flops_per_s=1e9, bytes_per_s=1e9)   # 1 ns/flop, 1 ns/byte
-
-    def test_hand_computed_schedule(self):
-        spans, extra = tl.modeled_step_timeline(_hand_program(),
-                                                **self.KW)
-        comm = [d for d in spans if d["name"].startswith("comm.")]
-        compute = [(d["t0_ns"], d["t1_ns"]) for d in spans
-                   if d["name"] == "compute"]
-        assert comm == [{"name": "comm.all_reduce",
-                         "span_id": comm[0]["span_id"], "trace_id": 0,
-                         "parent_id": None, "t0_ns": 100, "t1_ns": 500,
-                         "attrs": {"bytes": 400}}]
-        assert compute == [(0, 400), (500, 600)]
-        assert extra["stall_ns"] == 100
-        assert extra["makespan_ns"] == 600
-
-    def test_overlap_report_hand_computed(self):
-        rep = tl.modeled_overlap_report(_hand_program(), **self.KW)
-        assert rep["comm_ns"] == 400
-        assert rep["overlapped_ns"] == 300
-        assert rep["overlap_fraction"] == 0.75
-        assert rep["collectives"] == 1
-        assert rep["comm_stall_ns"] == 100
-        assert rep["makespan_ns"] == 600
-
-    def test_free_layout_ops_pass_dependence_through(self):
-        """A reshape between the grad and its collective is free AND
-        transparent: the collective still issues at the grad's ready
-        time, not at the reshape's program position."""
-        x = _Var((100,))
-        a = _Var((100,))
-        r = _Var((10, 10))
-        ar = _Var((10, 10))
-        big = _Var((300,))
-        bb = _Var((300,))
-        eqns = [
-            _Eqn("mul", [x, x], [a]),                       # [0, 100)
-            _Eqn("mul", [big, big], [bb]),                  # [100, 400)
-            _Eqn("reshape", [a], [r]),                      # free
-            _Eqn("psum", [r], [ar], {"axes": ("dp",)}),     # issue @100
-        ]
-        spans, _ = tl.modeled_step_timeline(
-            _Jaxpr(eqns, [x, big], [ar, bb]), **self.KW)
-        comm = [d for d in spans if d["name"].startswith("comm.")]
-        assert comm[0]["t0_ns"] == 100 and comm[0]["t1_ns"] == 500
-
-    def test_in_order_comm_stream_convoys(self):
-        """Two collectives in program order: the first ready LATE
-        convoys the second behind it even though the second's data was
-        ready early — the legacy forward-order exchange's failure mode."""
-        early = _Var((100,))
-        late = _Var((100,))
-        ge = _Var((100,))
-        gl = _Var((100,))
-        re_ = _Var((100,))
-        rl = _Var((100,))
-        eqns = [
-            _Eqn("mul", [early, early], [ge]),              # ready @100
-            _Eqn("mul", [late, late], [gl]),                # ready @200
-            _Eqn("psum", [gl], [rl], {"axes": ("dp",)}),    # [200, 600)
-            _Eqn("psum", [ge], [re_], {"axes": ("dp",)}),   # [600, 1000)
-        ]
-        spans, _ = tl.modeled_step_timeline(
-            _Jaxpr(eqns, [early, late], [re_, rl]), **self.KW)
-        comm = sorted(((d["t0_ns"], d["t1_ns"]) for d in spans
-                       if d["name"].startswith("comm.")))
-        assert comm == [(200, 600), (600, 1000)]
-
-    def test_sub_jaxpr_inlined(self):
-        """A pjit-like wrapper eqn is walked through: same schedule as
-        the flat program."""
-        inner = _hand_program()
-        ox = _Var((100,))
-        oy = _Var((300,))
-        oc = _Var((100,))
-        outer = _Jaxpr(
-            [_Eqn("pjit", [ox, oy], [oc], {"jaxpr": inner})],
-            [ox, oy], [oc])
-        rep = tl.modeled_overlap_report(outer, **self.KW)
-        assert rep["overlap_fraction"] == 0.75
-        assert rep["makespan_ns"] == 600
 
 
 # -- SLO burn-rate window math + alert drill --------------------------------
