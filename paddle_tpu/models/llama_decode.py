@@ -361,10 +361,10 @@ class LlamaDecodeEngine:
     def _post_attn(self, p, x, attn, valid=None):
         """Shared epilogue: output proj + residual + rms + the layer's MLP,
         dense SwiGLU or (``router`` among the layer's weights) the experts
-        this engine holds. Returns ``(x, pairs)``: ``pairs`` [3] int32 counts
+        this engine holds. Returns ``(x, pairs)``: ``pairs`` [4] int32 counts
         the (token, expert) pairs on held experts and in all, and the held
-        experts that got one, over ``valid`` tokens; None for a dense
-        layer."""
+        experts that got one, over ``valid`` tokens, and the rows of the
+        grouped product's row tiles; None for a dense layer."""
         B, S = x.shape[0], x.shape[1]
         x = x + attn.reshape(B, S, -1) @ p["wo"]
         h2 = _rms(x, p["ln2"], self.eps)
@@ -464,7 +464,7 @@ class LlamaDecodeEngine:
                       prompt=False, counted=None, rows=None):
         """Every layer's ``_block_paged`` in turn. ``tables`` holds one
         block table a cache kind (already the lanes' rows, for a mixed
-        step). Returns ``(x, pools, pairs)``: ``pairs`` [3] int32 summed
+        step). Returns ``(x, pools, pairs)``: ``pairs`` [4] int32 summed
         over the expert layers, None for a model without any."""
         new_pools, total = [], None
         for li, (p, pool) in enumerate(zip(w["layers"], pools)):
@@ -506,8 +506,9 @@ class LlamaDecodeEngine:
 
         A model with expert layers gets a third row in the same array (no
         second download): ``[pairs on held experts, pairs routed, held
-        experts that got a pair]`` of the valid lanes, summed over its
-        expert layers, then zeros."""
+        experts that got a pair]`` of the valid lanes and the rows of the
+        grouped product's row tiles, summed over its expert layers, then
+        zeros."""
         def serving_mixed_step(pack, pools, tables, slot_ids, valid, chain,
                                w):
             # (the function's name is the compiled program's: a device
@@ -542,7 +543,7 @@ class LlamaDecodeEngine:
             accept = acc & chain
             rows = [nt, accept.astype(jnp.int32)]
             if pairs is not None:
-                rows.append(jnp.zeros_like(nt).at[:3].set(pairs))
+                rows.append(jnp.zeros_like(nt).at[:pairs.shape[0]].set(pairs))
             return jnp.stack(rows), new_pools
 
         return serving_mixed_step
@@ -554,9 +555,10 @@ class LlamaDecodeEngine:
         emits ``k`` tokens per slot instead of one. Inactive rows write
         into the reserved null block (their table rows are zero), exactly
         like the single-step path. Returns (B, k) tokens; a model with
-        expert layers appends three rows: each iteration's pairs on held
+        expert layers appends four rows: each iteration's pairs on held
         experts, pairs routed and held experts that got a pair, over the
-        rows whose position is not 0."""
+        rows whose position is not 0, and the rows of the grouped product's
+        row tiles."""
         def serving_decode_burst(pack, pools, tables, w):
             # (jit_serving_decode_burst in a device trace)
             # pack (2, B) int32: row 0 = current tokens, row 1 = per-row
@@ -580,7 +582,7 @@ class LlamaDecodeEngine:
 
             (toks, pools, lens), outs = lax.scan(
                 body, (tokens, pools, lens), None, length=k)
-            return jnp.swapaxes(outs, 0, 1), pools    # (B [+ 3], k)
+            return jnp.swapaxes(outs, 0, 1), pools    # (B [+ 4], k)
 
         return serving_decode_burst
 

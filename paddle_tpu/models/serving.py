@@ -1571,7 +1571,7 @@ class ContinuousBatchingEngine:
         self._next_phase("serving.route")
         toks, acc = out[0], out[1]
         if len(out) > 2 and mon.state.on:
-            self._count_expert_pairs(mon, *out[2][:3], 1)
+            self._count_expert_pairs(mon, out[2][:4], 1)
         if epoch != self._epoch:
             # a hang recovery superseded this step while it sat in
             # compile/dispatch. The pools rebind above MUST stand — the
@@ -1818,10 +1818,10 @@ class ContinuousBatchingEngine:
                 "serving.decode_burst",
                 (("tokens", toks_dev), ("kv_pools", self._pools)),
                 step=self._san_steps)
-        toks = np.asarray(toks_dev)            # (B, K) [+ 3 rows of pairs]
+        toks = np.asarray(toks_dev)            # (B, K) [+ 4 rows of pairs]
         self._next_phase("serving.route")
         if len(toks) > self.max_batch and mon.state.on:
-            self._count_expert_pairs(mon, *toks[-3:].sum(axis=1), K)
+            self._count_expert_pairs(mon, toks[-4:].sum(axis=1), K)
         if epoch != self._epoch:
             # superseded mid-dispatch: keep the pools rebind (buffer
             # validity + the warm radix blocks), apply no host state —
@@ -1856,15 +1856,18 @@ class ContinuousBatchingEngine:
             mon.mod.sample()
         return finished
 
-    def _count_expert_pairs(self, mon, held, routed, hit, forwards):
-        """The (token, expert) pairs a step's program counted on the experts
-        held here and in all, the held experts that got a pair, and the
-        expert calls the pairs had to share: held experts x expert layers x
-        forward passes."""
+    def _count_expert_pairs(self, mon, pairs, forwards):
+        """What a step's program counted (``pairs`` [4]): the (token, expert)
+        pairs on the experts held here and in all, the held experts that got
+        a pair, and the rows of the row tiles the grouped product visited
+        (every lane's pairs, each group padded to whole tiles of the Pallas
+        kernels' sublane rows, or of ``ragged_dot``'s one row: held over it
+        is the tiles' fill); and the expert calls the pairs had to share:
+        held experts x expert layers x forward passes."""
         e = self._inner
-        mon.expert_pairs.labels("held").inc(int(held))
-        mon.expert_pairs.labels("routed").inc(int(routed))
-        mon.expert_pairs.labels("experts_hit").inc(int(hit))
+        for where, n in zip(("held", "routed", "experts_hit", "kernel_rows"),
+                            pairs):
+            mon.expert_pairs.labels(where).inc(int(n))
         mon.expert_pairs.labels("expert_calls").inc(
             forwards * e.held_experts * sum("router" in p for p in e.layers))
 

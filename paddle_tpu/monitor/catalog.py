@@ -139,7 +139,11 @@ METRICS = {
         "got at least one pair (a layer and forward pass each), "
         "where=expert_calls the held experts x expert layers x forward "
         "passes (1 a mixed step, decode_burst a burst) those pairs were "
-        "shared among."),
+        "shared among, where=kernel_rows the rows of all row tiles the "
+        "grouped product visited (every lane's pairs, each held expert's "
+        "group padded to whole row tiles: the dtype's sublane rows for "
+        "the Pallas kernels, one row for jax.lax.ragged_dot): held over "
+        "it is the tiles' fill."),
     "paddle_tpu_serving_token_gap_ns": (
         "histogram", (),
         "Time between one request's consecutive output tokens, observed "

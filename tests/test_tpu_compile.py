@@ -20,7 +20,9 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 import paddle_tpu  # noqa: F401
+from paddle_tpu.incubate.distributed.models.moe import held_experts
 from paddle_tpu.models import paged_kv
+from paddle_tpu.ops.pallas import grouped_matmul
 from paddle_tpu.ops.pallas.flash_attention import flash_attention_fwd
 from paddle_tpu.ops.pallas.paged_attention import (paged_attention,
                                                    paged_attention_gqa)
@@ -57,7 +59,7 @@ def mosaic(monkeypatch):
     """The kernels ask jax.devices() whether to interpret, and see the CPU
     here: steer them to the real lowering (through sys.modules — the package
     re-exports a function under the module's name)."""
-    for name in ("flash_attention", "paged_attention"):
+    for name in ("flash_attention", "paged_attention", "grouped_matmul"):
         mod = sys.modules["paddle_tpu.ops.pallas." + name]
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
@@ -210,6 +212,93 @@ def test_paged_attention_gqa_tiles_compile_for_v5e(one_chip, mosaic, kv,
     assert compiled.as_text().count(
         "custom_call_target=\"tpu_custom_call\"") == 2
     assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2 ** 20
+
+
+@pytest.mark.parametrize("rows", [
+    pytest.param(2560, id="mixed-step-320-lanes"),
+    pytest.param(512, id="burst-64-lanes"),
+])
+def test_grouped_matmul_kernels_compile_for_v5e(one_chip, mosaic, rows):
+    """The held experts' grouped products (ISSUE 35) at the MiMo-V2-Flash
+    cell's shapes: ``rows`` pairs sorted over 16 held experts of hidden 4096
+    x width 2048 in bfloat16, each group padded to row tiles of 16 (2,800 /
+    752 rows), the tiles in use a dynamic grid bound. TWO kernels (gate and up
+    fused with the SwiGLU, then down), their weight blocks inside the VMEM
+    they ask for, and nothing of the experts' size beside them."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    tm = grouped_matmul.row_tile(jnp.bfloat16)
+    padded = grouped_matmul.padded_rows(rows, 16, tm)
+
+    def products(xp, w1, w3, w2, sizes):
+        plan = grouped_matmul.plan_row_tiles(sizes, tm, rows)
+        return grouped_matmul.gmm_down(
+            grouped_matmul.gmm_up(xp, w1, w3, plan), w2, plan)
+
+    compiled = jax.jit(products).lower(
+        sds((padded, 4096), jnp.bfloat16), sds((16, 4096, 2048), jnp.bfloat16),
+        sds((16, 4096, 2048), jnp.bfloat16),
+        sds((16, 2048, 4096), jnp.bfloat16), sds((16,), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 2
+    assert "held_experts_gmm_up" in hlo and "held_experts_gmm_down" in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
+def test_mixed_step_of_an_expert_model_holds_the_grouped_kernels(
+        one_chip, mosaic, monkeypatch):
+    """The compiled mixed step of a tiny lane-aligned expert model (hidden
+    128, expert width 128, float32), the rule answering as on a TPU: two
+    ``held_experts_gmm`` custom calls an expert layer and no ``ragged-dot``
+    left; with the rule answering as elsewhere, no kernel (at these widths
+    the compiler unrolls ``ragged_dot`` into plain products: the
+    ``%ragged-dot-none`` custom call is the real widths')."""
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    for path in (os.path.dirname(bench), bench):
+        if path not in sys.path:
+            sys.path.insert(0, path)
+    import common
+    from builders import mimo_v2_flash as B
+
+    from paddle_tpu.models.serving import ContinuousBatchingEngine
+    from test_grouped_matmul import CFG
+
+    model = B.construct(CFG)
+    common.load_weights(model, B.weights(11, CFG, "float32"))
+    model.eval()
+    eng = ContinuousBatchingEngine(model, **CFG["engine"])
+    lanes = eng.max_step_tokens
+
+    def shapes(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (sds((2, lanes), jnp.int32), shapes(eng._pools),
+            shapes(eng._tables()), sds((lanes,), jnp.int32),
+            sds((lanes,), jnp.bool_), sds((lanes,), jnp.bool_),
+            shapes(eng._inner.weights))
+
+    def hlo():
+        # a new function each time: jit's cache knows nothing of the rule
+        return jax.jit(eng._inner.build_mixed_step()).lower(
+            *args).compile().as_text()
+
+    plain = hlo()
+    assert "held_experts_gmm" not in plain and "tpu_custom_call" not in plain
+    monkeypatch.setattr(held_experts, "_kernel_applies", lambda *a: True)
+    kernels = hlo()
+    layers = sum("router" in p for p in eng._inner.layers)
+    assert layers == 2
+    assert kernels.count("custom_call_target=\"tpu_custom_call\"") == \
+        2 * layers
+    assert kernels.count("held_experts_gmm_up") >= layers
+    assert kernels.count("held_experts_gmm_down") >= layers
+    assert "ragged-dot" not in kernels and "ragged_dot" not in kernels
 
 
 def test_paged_decode_attention_fits_the_mixed_step(one_chip):
