@@ -92,6 +92,62 @@ class CacheKind:
     window: int | None = None   # positions attended to, the current included
     flat: bool = False        # pools stored [blocks, block_size, kv * dim]
 
+    # what the cache manager may do with a sequence's cache of this kind:
+    # position-addressed blocks can be mapped into another sequence (a radix
+    # hit), spilled and brought back, and a rejected draft's write is
+    # overwritten before it is read; behind a window the blocks are released,
+    # so neither a hit nor a spill finds them
+    paged = True
+    rollback = True
+    why_not = {
+        "reuse": "a radix hit would have to bring the sliding-window "
+                 "layers' last blocks too",
+        "spill": "a preempted request's sliding-window blocks are not "
+                 "spilled"}
+
+    @property
+    def reuse(self):
+        return self.window is None
+
+    @property
+    def spill(self):
+        return self.window is None
+
+
+@dataclasses.dataclass(frozen=True)
+class StateKind:
+    """One kind of RECURRENT layer (a gated delta-rule linear-attention
+    layer), as the serving block and the cache manager see it: a sequence's
+    cache of this kind is not addressed by position. It is one float32 state
+    ``[num_heads, key_dim, value_dim]`` and the last ``conv_kernel - 1``
+    inputs of the layer's convolutions, in the SLOT of the sequence
+    (``paged_kv.StateSlots``). The state at an earlier position is gone, so
+    nothing of it can be reused by another sequence, spilled in part or
+    rolled back."""
+
+    name: str                 # "linear": the counters' label
+    num_heads: int
+    key_dim: int
+    value_dim: int
+    conv_kernel: int
+    neg_eigval: bool = True   # beta in (0, 2)
+
+    paged = False
+    reuse = False
+    spill = False
+    rollback = False
+    window = None
+    why_not = {
+        "reuse": "a radix hit would need a recurrent layer's state AT the "
+                 "hit's last position, and a slot keeps only the newest",
+        "spill": "a preempted request's recurrent state is not spilled",
+        "rollback": "a rejected draft cannot be rolled back out of a "
+                    "recurrent state"}
+
+    @property
+    def conv_width(self):
+        return self.num_heads * (2 * self.key_dim + self.value_dim)
+
 
 class _PagedCache:
     """Cache value of the paged engine: the block pools (device) plus THEIR
@@ -171,13 +227,17 @@ class LlamaDecodeEngine:
         # the first kind's sizes under their old names: the dense cache and
         # the int8 pools know one kind only
         kind = self.kinds[0]
+        if not kind.paged:
+            raise ValueError("a model's first cache kind is a paged one (its "
+                             "pager is the one the block tables, the radix "
+                             "cache and the spill layer work on)")
         self.num_kv, self.head_dim = kind.num_kv, kind.head_dim
         self.theta = kind.theta
         if len(self.kinds) > 1 or kind.flat or kind.window is not None \
                 or kind.v_head_dim != kind.head_dim:
             if not self.paged or self.kv_int8:
                 raise ValueError(
-                    "a model whose attention layers differ in kind, or in "
+                    "a model whose layers differ in kind, or in "
                     "their K and V widths, is served from the paged "
                     "bfloat16/float32 cache only (kv_cache_layout='paged', "
                     "no kv_cache_dtype)")
@@ -348,12 +408,23 @@ class LlamaDecodeEngine:
         keys)."""
         kind = self.kinds[0] if kind is None else kind
         B, S, _ = x.shape
-        h = _rms(x, p["ln1"], self.eps)
-        q = (h @ p["wq"]).reshape(B, S, self.num_heads, kind.head_dim)
-        k = (h @ p["wk"]).reshape(B, S, kind.num_kv, kind.head_dim)
+        # the layer's weights' keys say what it has: a norm before the
+        # projections (``ln1``), norms over the whole q and k projections
+        # (``q_norm``, ``k_norm``)
+        h = _rms(x, p["ln1"], self.eps) if "ln1" in p else x
+        q = h @ p["wq"]
+        if "q_norm" in p:
+            q = _rms(q, p["q_norm"], self.eps)
+        q = q.reshape(B, S, self.num_heads, kind.head_dim)
+        k = h @ p["wk"]
+        if "k_norm" in p:
+            k = _rms(k, p["k_norm"], self.eps)
+        k = k.reshape(B, S, kind.num_kv, kind.head_dim)
         v = (h @ p["wv"]).reshape(B, S, kind.num_kv, kind.v_head_dim)
         if self.v_scale != 1.0:
             v = v * jnp.asarray(self.v_scale, v.dtype)
+        if not kind.rotary_dim:         # no rotary embedding on this kind
+            return q, k, v
         rope = _rope_at_rows if rows else _rope_at
         return (rope(q, positions, kind.theta, kind.rotary_dim),
                 rope(k, positions, kind.theta, kind.rotary_dim), v)
@@ -366,10 +437,15 @@ class LlamaDecodeEngine:
         experts that got one, over ``valid`` tokens, and the rows of the
         grouped product's row tiles; None for a dense layer."""
         B, S = x.shape[0], x.shape[1]
-        x = x + attn.reshape(B, S, -1) @ p["wo"]
-        h2 = _rms(x, p["ln2"], self.eps)
+        out = attn.reshape(B, S, -1) @ p["wo"]
+        if "post_attn_norm" in p:       # the norm on the sublayer's OUTPUT
+            out = _rms(out, p["post_attn_norm"], self.eps)
+        x = x + out
+        h2 = _rms(x, p["ln2"], self.eps) if "ln2" in p else x
         if "router" not in p:
             mlp = (jax.nn.silu(h2 @ p["gate"]) * (h2 @ p["up"])) @ p["down"]
+            if "post_ff_norm" in p:
+                mlp = _rms(mlp, p["post_ff_norm"], self.eps)
             return x + mlp, None
         from ..incubate.distributed.models.moe.held_experts import (
             held_experts_mlp)
@@ -381,7 +457,7 @@ class LlamaDecodeEngine:
         return x + y.reshape(x.shape), pairs
 
     def _block_paged(self, li, p, x, pool, tables, positions, valid=None,
-                     prompt=False, counted=None, rows=None):
+                     prompt=False, counted=None, rows=None, runs=None):
         """THE serving block of layer ``li``, for every paged program.
 
         One token per LANE (``x`` (T, 1, hidden)) at a per-lane position
@@ -404,10 +480,27 @@ class LlamaDecodeEngine:
         sequences' blocks.
 
         The layer's kind gives the pool's shapes, the rotary base and dims,
-        and the window; its weights' keys give the sink and the MLP."""
+        and the window; its weights' keys give the sink and the MLP.
+
+        A RECURRENT kind (``StateKind``) has no blocks: its pool entry is
+        the slots' ``(state, conv)`` and ``runs`` the plan of the step's
+        lanes (``_plan_runs``; None where lane ``i`` is slot ``i``'s next
+        token: the burst, lockstep decoding): a chunk's lanes go through the
+        recurrence in order from their slot's state, padding lanes through
+        the null slot."""
         from . import paged_kv as _pk
 
         kind = self.kinds[self.layer_kind[li]]
+        if not kind.paged:
+            from .linear_attention import mixer
+
+            B, S, _ = x.shape
+            mix, pool = mixer(kind, p, x.reshape(B * S, -1), pool,
+                              positions, runs, self.eps)
+            x, pairs = self._post_attn(
+                p, x, mix.reshape(B, S, -1),
+                valid if counted is None else counted)
+            return x, pool, pairs
         sink = p.get("sink")
         if prompt:
             B, S, _ = x.shape
@@ -467,14 +560,35 @@ class LlamaDecodeEngine:
         step). Returns ``(x, pools, pairs)``: ``pairs`` [4] int32 summed
         over the expert layers, None for a model without any."""
         new_pools, total = [], None
+        runs = self._plan_runs(x, pools, positions, valid, prompt, rows)
         for li, (p, pool) in enumerate(zip(w["layers"], pools)):
             x, pool, pairs = self._block_paged(
                 li, p, x, pool, tables[self.layer_kind[li]], positions,
-                valid, prompt, counted, rows)
+                valid, prompt, counted, rows, runs)
             new_pools.append(pool)
             if pairs is not None:
                 total = pairs if total is None else total + pairs
         return x, new_pools, total
+
+    def _plan_runs(self, x, pools, positions, valid, prompt, rows):
+        """The runs of a step's lanes, planned ONCE for all recurrent layers
+        (None for a model without any, and where lane ``i`` is slot ``i``'s
+        next token). A mixed step's lanes are its pack (``rows`` the slot
+        ids); a lockstep prefill's are its ``B`` prompts one behind the
+        other, row ``b``'s at positions 0 .. S - 1."""
+        li = next((i for i, ki in enumerate(self.layer_kind)
+                   if not self.kinds[ki].paged), None)
+        if li is None or (rows is None and not prompt):
+            return None
+        from ..ops.pallas.gated_delta_rule import plan_runs
+
+        slots = pools[li][0].shape[0]
+        if prompt:
+            B, S, _ = x.shape
+            rows = jnp.repeat(jnp.arange(B, dtype=jnp.int32), S)
+            positions = jnp.tile(jnp.arange(S, dtype=jnp.int32), B)
+            valid = jnp.ones((B * S,), bool)
+        return plan_runs(rows, positions, valid, slots)
 
     def build_mixed_step(self):
         """The continuous-batching mixed step as a pure function for the
@@ -518,7 +632,9 @@ class LlamaDecodeEngine:
             # transfers; slot_ids/valid/chain are cached per composition)
             token_ids, positions = pack[0], pack[1]
             x = w["emb"][token_ids][:, None]        # (T, 1, hidden)
-            row_tables = tuple(t[slot_ids] for t in tables)  # (T, max_blocks)
+            # (T, max_blocks) a paged kind; a recurrent kind has no table
+            row_tables = tuple(None if t is None else t[slot_ids]
+                               for t in tables)
             x, new_pools, pairs = self._layers_paged(
                 w, x, pools, row_tables, positions, valid, rows=slot_ids)
             x = _rms(x, w["norm_w"], self.eps)
@@ -614,17 +730,22 @@ class LlamaDecodeEngine:
         return jax.jit(run, donate_argnums=(1,))
 
     def make_pagers(self, batch, num_blocks=None, window_blocks=None):
-        """One ``PagedKVCache`` a cache kind (the first is the whole-length
+        """One ``PagedKVCache`` a paged cache kind, one ``StateSlots`` a
+        recurrent kind (the first is the whole-length
         kind's, whose pool ``num_blocks`` sizes: default, the worst case of
         ``batch`` rows + the null block; ``window_blocks`` sizes a window
         kind's, default the same: a caller that releases blocks behind the
         window passes less) and the per-layer pool entries, each layer's
         from its kind's pager."""
-        from .paged_kv import PagedKVCache
+        from .paged_kv import PagedKVCache, StateSlots
 
         max_blocks = -(-self.max_len // self.block_size)
         pagers = []
         for ki, kind in enumerate(self.kinds):
+            if not kind.paged:          # a slot a row, and the null slot
+                pagers.append(StateSlots(self.layer_kind.count(ki), batch,
+                                         kind, self.emb.dtype))
+                continue
             n = num_blocks if kind.window is None else window_blocks
             pagers.append(PagedKVCache(
                 num_layers=self.layer_kind.count(ki),
@@ -639,6 +760,9 @@ class LlamaDecodeEngine:
         for ki in self.layer_kind:
             pg, i = pagers[ki], nth[ki]
             nth[ki] += 1
+            if not self.kinds[ki].paged:
+                pools.append((pg.state[i], pg.conv[i]))
+                continue
             pools.append((pg.k[i], pg.k_scale[i], pg.v[i], pg.v_scale[i])
                          if self.kv_int8 else (pg.k[i], pg.v[i]))
         return pagers, pools

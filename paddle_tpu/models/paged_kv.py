@@ -76,6 +76,46 @@ def _mon():
     return _MON
 
 
+class StateSlots:
+    """The cache manager's pool for ONE set of recurrent layers (a
+    ``StateKind``): a sequence's cache of this kind is not blocks addressed
+    by position but ONE slot, row ``b``'s is slot ``b``, and the last slot
+    (``batch``) is the NULL slot, where padding lanes go as unassigned table
+    entries go to block 0. Per layer ``state`` ``[batch + 1, heads / pack,
+    key_dim, pack * value_dim]`` float32 (``pack`` heads side by side along a
+    row: ``ops/pallas/gated_delta_rule.py``) and ``conv`` ``[batch + 1,
+    conv_kernel - 1, conv_width]`` in the activations' dtype.
+
+    Nothing is granted step by step, so the allocator's calls are no-ops
+    here, and a freed slot is not cleared on the host: a sequence's first
+    run starts at position 0, which the program takes from zeros whatever
+    the slot holds. ``slot_bytes`` is what one sequence holds in one layer."""
+
+    window = None
+    block_tables = None                 # no table: the slot is the row
+
+    def __init__(self, num_layers, batch, kind, dtype=jnp.bfloat16):
+        from ..ops.pallas.gated_delta_rule import pack_of
+
+        self.batch = int(batch)
+        self.slots = self.batch + 1
+        pack = pack_of(kind.num_heads)
+        shape = (self.slots, kind.num_heads // pack, kind.key_dim,
+                 pack * kind.value_dim)
+        cshape = (self.slots, kind.conv_kernel - 1, kind.conv_width)
+        self.state = [jnp.zeros(shape, jnp.float32)
+                      for _ in range(num_layers)]
+        self.conv = [jnp.zeros(cshape, dtype) for _ in range(num_layers)]
+        self.slot_bytes = int(np.prod(shape[1:])) * 4 \
+            + int(np.prod(cshape[1:])) * jnp.dtype(dtype).itemsize
+
+    def ensure_capacity(self, seq_lens_next):
+        """A slot holds any length."""
+
+    def free_sequence(self, b):
+        """The slot's next sequence resets it in the program."""
+
+
 class PagedKVCache:
     """Host-side block allocator + the device block pools for ONE layer set.
 
@@ -708,8 +748,10 @@ def _kernel_applies(q, pool, v_pool=None):
     buffered) fit half of a core's 16 MiB of scoped VMEM. A
     ``[blocks, block_size, kv_heads, head_dim]`` pool goes to the
     one-row-a-head kernel (``paged_attention``): the head dim has to fill
-    whole 128-lane rows; block_size and kv_heads sit on dims it does not
-    tile. A flat ``[blocks, block_size, kv_heads * dim]`` pool goes to the
+    whole 128-lane rows and the KV heads whole sublane tiles of 8, or one
+    small tile of 2 or 4 (30 heads are refused by the compiler: a model
+    with such a count keeps flat pools); block_size sits on a dim it does
+    not tile. A flat ``[blocks, block_size, kv_heads * dim]`` pool goes to the
     grouped-query kernel (``paged_attention_gqa``), which wants the MERGED
     rows of K and of V to fill whole 128-lane rows (192-wide K heads beside
     128-wide V heads do) and a block_size its dtype's sublane tile divides.
@@ -726,7 +768,12 @@ def _kernel_applies(q, pool, v_pool=None):
     if pool.ndim == 3:
         return (fits and pool.shape[-1] % 128 == 0
                 and v_pool.shape[-1] % 128 == 0 and pool.shape[1] % 16 == 0)
-    return fits and q.shape[-1] % 128 == 0
+    # (the kernel slices a block's [kv_heads, head_dim] rows out of HBM: the
+    # heads sit on sublanes, in whole tiles of 8 or one small tile of 2 or 4;
+    # the compiler refuses 1, 3, 6, 12, 20, 30)
+    heads = pool.shape[2]
+    return fits and q.shape[-1] % 128 == 0 \
+        and (heads % 8 == 0 or heads in (2, 4))
 
 
 def kernel_applies(q, cache_k, cache_v):

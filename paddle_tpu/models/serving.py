@@ -115,7 +115,8 @@ class _Mon:
                  "jit_compiles", "jit_hits", "jit_sigs",
                  "phase_ns", "steps", "token_gap", "attn_blocks",
                  "kind_blocks", "attn_lanes", "block_steps", "expert_pairs",
-                 "window_released")
+                 "window_released", "linear_tokens", "linear_runs",
+                 "byte_steps", "state_bytes", "state_resets")
 
 
 _MON = None
@@ -198,6 +199,14 @@ def _mon():
                                    labelnames=("where",))
         o.window_released = m.counter(
             "paddle_tpu_kv_window_blocks_released_total")
+        o.linear_tokens = m.counter("paddle_tpu_serving_linear_tokens_total",
+                                    labelnames=("path",))
+        o.linear_runs = m.counter("paddle_tpu_serving_linear_runs_total",
+                                  labelnames=("path",))
+        o.byte_steps = m.counter("paddle_tpu_cache_byte_steps_total",
+                                 labelnames=("kind",))
+        o.state_bytes = m.gauge("paddle_tpu_state_pool_bytes")
+        o.state_resets = m.counter("paddle_tpu_state_slots_reset_total")
         _MON = o
     return _MON
 
@@ -369,35 +378,64 @@ class ContinuousBatchingEngine:
         # wants headroom so registered chains outlive their producers
         num_blocks = self.max_batch * max_blocks + 1 if pool_blocks is None \
             else max(int(pool_blocks), max_blocks + 2)
-        # one pager a cache kind, the whole-length kind's first: ``_pager``
-        # is the one the radix cache, copy-on-write and the spill layer
-        # work on (a model with a second kind has none of the three). A
-        # window kind's pool holds what its rows can keep at once: per row
+        # what the model's cache kinds permit decides what the engine may
+        # be asked for: each kind's description says whether a sequence's
+        # cache of it can be reused by another sequence (a radix hit),
+        # spilled with a preempted request, and rolled back behind a
+        # rejected draft, and why not
+        asked = {"reuse": ("prefix_cache", prefix_cache, False),
+                 "spill": ("kv_spill", kv_spill, False),
+                 "rollback": ("spec_lookahead", spec_lookahead, 0)}
+        refused = [(field, k) for field, (_, value, _) in asked.items()
+                   if value for k in e.kinds if not getattr(k, field)]
+        if refused:
+            fields = {f for f, _ in refused}
+            raise ValueError(
+                "a model with "
+                + " and ".join(sorted({k.name for _, k in refused}))
+                + " layers is served with "
+                + " and ".join(f"{option}={off!r}"
+                               for f, (option, _, off) in asked.items()
+                               if f in fields)
+                + ": " + "; ".join(dict.fromkeys(k.why_not[f]
+                                                 for f, k in refused))
+                + "; none of it is implemented, and silent wrong reuse is "
+                  "not an option")
+        # one pager a PAGED cache kind, the whole-length kind's first:
+        # ``_pager`` is the one the radix cache, copy-on-write and the spill
+        # layer work on (a model with a second kind has none of the three).
+        # A window kind's pool holds what its rows can keep at once: per row
         # the blocks its last ``window`` positions span and the one being
         # filled, plus the blocks one step's prefill budget adds before
-        # the next release
-        self._windowed = any(k.window is not None for k in e.kinds)
-        if self._windowed and (prefix_cache or kv_spill):
-            raise ValueError(
-                "a model with sliding-window layers is served with "
-                "prefix_cache=False and kv_spill=False: a radix hit would "
-                "have to bring the window layers' last blocks too, and a "
-                "preempted request's window blocks are not spilled; "
-                "neither is implemented, and silent wrong reuse is not an "
-                "option")
+        # the next release. A recurrent kind has a slot a row and the null
+        # slot (``_states``)
+        windows = [k.window for k in e.kinds if k.window is not None]
         window_blocks = None
-        if self._windowed:
-            widest = max(k.window for k in e.kinds if k.window is not None)
+        if windows:
             window_blocks = (self.max_batch
-                             * ((widest - 1) // self.block_size + 3)
+                             * ((max(windows) - 1) // self.block_size + 3)
                              + -(-self.max_step_tokens // self.block_size)
                              + 1)
-        self._pagers, self._pools = e.make_pagers(
+        self._caches, self._pools = e.make_pagers(
             self.max_batch, num_blocks, window_blocks)
+        self._pagers = [c for c, k in zip(self._caches, e.kinds) if k.paged]
         self._pager = self._pagers[0]
-        # per kind: its label, how many layers keep a pool of it
-        self._kind_layers = [(k.name, e.layer_kind.count(i))
-                             for i, k in enumerate(e.kinds)]
+        # per paged kind: its label, how many layers keep a pool of it, the
+        # bytes of one block in one layer; per recurrent kind: its label,
+        # its layers, its slots
+        self._kind_layers = [
+            (k.name, e.layer_kind.count(i),
+             _pool_bytes([self._pools[e.layer_kind.index(i)]])
+             // c.num_blocks)
+            for i, (c, k) in enumerate(zip(self._caches, e.kinds))
+            if k.paged]
+        self._states = [(k.name, e.layer_kind.count(i), c)
+                        for i, (c, k) in enumerate(zip(self._caches,
+                                                       e.kinds))
+                        if not k.paged]
+        self.state_pool_bytes = sum(layers * c.slots * c.slot_bytes
+                                    for _, layers, c in self._states)
+        self._slot_resets = 0           # admissions since the last step
         # the capacity lever the pool-bytes gauge documents: equal byte
         # budgets admit ~2x the requests when the pools are quantized
         self.kv_pool_bytes = _pool_bytes(self._pools)
@@ -845,6 +883,7 @@ class ContinuousBatchingEngine:
         self._slots[slot] = req
         self._active[slot] = True
         self._decode_ready[slot] = False
+        self._slot_resets += 1
         self._chain_cursors.pop(slot, None)
         if self._drafter is not None:
             self._drafter.admit(req.rid, req.prompt)
@@ -899,6 +938,7 @@ class ContinuousBatchingEngine:
                 "total_blocks": total,
                 "headroom": round(free / max(total, 1), 4),
                 "pool_bytes": int(self.kv_pool_bytes),
+                "state_pool_bytes": int(self.state_pool_bytes),
                 "dtype": self.kv_cache_dtype or "full",
             },
             "compiled_programs": len(self._jit_cache),
@@ -1176,8 +1216,9 @@ class ContinuousBatchingEngine:
             self._phases.append(ph)
 
     def _tables(self):
-        """The block tables the programs take: one a cache kind."""
-        return tuple(pg.block_tables for pg in self._pagers)
+        """The block tables the programs take: one a cache kind (None for
+        a recurrent kind, whose slot is the row)."""
+        return tuple(c.block_tables for c in self._caches)
 
     def _count_attn_blocks(self, mon, positions, lanes, rows=None,
                            valid=None):
@@ -1200,7 +1241,10 @@ class ContinuousBatchingEngine:
         n_valid = positions.size if valid is None else valid
         total = read = 0
         plans = {}                      # one plan a tile size
+        first = True
         for ki, kind in enumerate(e.kinds):
+            if not kind.paged:          # no blocks: _note_state_slots counts
+                continue
             q = jax.ShapeDtypeStruct((e.num_heads, kind.head_dim),
                                      e.emb.dtype)
             entry = self._pools[e.layer_kind.index(ki)]
@@ -1218,7 +1262,8 @@ class ContinuousBatchingEngine:
                     positions, self.block_size, valid, plan, kind.window)
             else:
                 n = lanes * width
-            if ki == 0:
+            if first:
+                first = False
                 mon.attn_lanes.labels("tiled").inc(tiled)
                 mon.attn_lanes.labels("lane").inc(n_valid - tiled)
             mon.kind_blocks.labels(kind.name).inc(n)
@@ -1233,7 +1278,7 @@ class ContinuousBatchingEngine:
         window of its row's next query (``lens``: queries only move on).
         Also adds this step's block-steps: blocks in use a kind, times the
         layers that keep a pool of it."""
-        if self._windowed:
+        if any(pg.window is not None for pg in self._pagers):
             t0 = mon.mod.now_ns() if mon.tstate.on else 0
             freed = 0
             for pg in self._pagers:
@@ -1247,8 +1292,45 @@ class ContinuousBatchingEngine:
                     "serving.release_window", t0, mon.mod.now_ns(),
                     parent=self._phase.span, attrs={"blocks": freed})
         if mon.state.on:
-            for (name, layers), pg in zip(self._kind_layers, self._pagers):
-                mon.block_steps.labels(name).inc(pg.blocks_in_use * layers)
+            for (name, layers, nbytes), pg in zip(self._kind_layers,
+                                                  self._pagers):
+                held = pg.blocks_in_use * layers
+                mon.block_steps.labels(name).inc(held)
+                mon.byte_steps.labels(name).inc(held * nbytes)
+
+    def _note_state_slots(self, mon, steps, chunks=()):
+        """What the scheduler adds for recurrent layers, in the schedule
+        phase: the slots reset since the last step (a request admitted to
+        a slot starts at position 0, which the program takes from zeros),
+        the tokens and runs the step sends through the recurrence (a layer
+        once) by the path that serves them, ``steps`` runs of one (decode
+        lanes; a burst's every iteration) and the prefill ``chunks``, a
+        chunk of one token a run of one too, and the bytes the slots in use
+        hold times the kind's layers."""
+        if not self._states:
+            return
+        t0 = mon.mod.now_ns() if mon.tstate.on else 0
+        resets, self._slot_resets = self._slot_resets, 0
+        takes = [take for _b, _s, take in chunks]
+        ones = steps + sum(1 for n in takes if n == 1)
+        runs = sum(1 for n in takes if n > 1)
+        tokens = sum(n for n in takes if n > 1)
+        if mon.state.on:
+            mon.state_resets.inc(resets)
+            mon.linear_tokens.labels("step").inc(ones)
+            mon.linear_runs.labels("step").inc(ones)
+            mon.linear_tokens.labels("chunk").inc(tokens)
+            mon.linear_runs.labels("chunk").inc(runs)
+            in_use = int(self._active.sum())
+            for name, layers, c in self._states:
+                mon.byte_steps.labels(name).inc(
+                    in_use * layers * c.slot_bytes)
+        if mon.tstate.on:
+            mon.trace.record_span(
+                "serving.state_slots", t0, mon.mod.now_ns(),
+                parent=self._phase.span,
+                attrs={"reset": resets, "step_runs": ones,
+                       "chunk_runs": runs, "chunk_tokens": tokens})
 
     def _ensure(self, need):
         """ensure_capacity, in every cache kind, with radix-cache relief:
@@ -1556,6 +1638,7 @@ class ContinuousBatchingEngine:
                 "n_prefill": n_lanes - n_dec_lanes, "budget": T}
         if mon.state.on:
             self._count_attn_blocks(mon, positions, T, slot_np, n_lanes)
+        self._note_state_slots(mon, nd, chunks)
         self._next_phase("serving.dispatch", "mixed")
         out_dev, self._pools = step(
             jnp.asarray(pack_np), self._pools, self._tables(),
@@ -1807,6 +1890,7 @@ class ContinuousBatchingEngine:
             self._count_attn_blocks(
                 mon, np.add.outer(self.lens[decode_slots], np.arange(K)),
                 self.max_batch * K)
+        self._note_state_slots(mon, K * len(decode_slots))
         self._next_phase("serving.dispatch", "burst")
         toks_dev, self._pools = burst(
             jnp.asarray(pack), self._pools, self._tables(),
@@ -1941,6 +2025,7 @@ class ContinuousBatchingEngine:
         mon.queue_depth.set(depth)
         mon.occupancy.set(float(self._active.sum()) / self.max_batch)
         mon.pool_bytes.set(self.kv_pool_bytes)
+        mon.state_bytes.set(self.state_pool_bytes)
 
     @property
     def num_active(self):

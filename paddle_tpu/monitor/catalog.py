@@ -23,7 +23,10 @@ framework.
 
 # Subsystems a metric may belong to (the <subsystem> token of the name).
 SUBSYSTEMS = ("dispatch", "jit", "serving", "kv", "dataloader", "monitor",
-              "mesh", "comm", "ckpt", "train", "fleet", "control")
+              "mesh", "comm", "ckpt", "train", "fleet", "control",
+              # a model's caches whatever their kind (paged keys and values,
+              # recurrent state), and the recurrent layers' slots
+              "cache", "state")
 
 NAME_PATTERN = (
     r"^paddle_tpu_(" + "|".join(SUBSYSTEMS) + r")_[a-z][a-z0-9_]*$"
@@ -129,6 +132,20 @@ METRICS = {
         "path=lane by a walk of their own (decode lanes, bursts, short "
         "runs, every lane where no kernel runs). Read from the first "
         "cache kind's plan; tiled + lane = the valid lanes."),
+    "paddle_tpu_serving_linear_tokens_total": (
+        "counter", ("path",),
+        "Tokens of completed engine steps that went through a recurrent "
+        "(gated delta-rule) layer's recurrence, one layer once, by the "
+        "path that served them: path=chunk the lanes of prefill runs of two "
+        "tokens or more (the chunked form, gated_delta_chunk), path=step "
+        "runs of one (decode lanes, every iteration of a burst, a one-token "
+        "chunk: gated_delta_step)."),
+    "paddle_tpu_serving_linear_runs_total": (
+        "counter", ("path",),
+        "Runs (stretches of one slot's consecutive tokens in one step) that "
+        "went through a recurrent layer's recurrence, one layer once, by "
+        "path (chunk | step): a run reads its slot's state once and writes "
+        "it once."),
     "paddle_tpu_serving_expert_pairs_total": (
         "counter", ("where",),
         "(token, expert) pairs of completed engine steps of a model with "
@@ -300,6 +317,25 @@ METRICS = {
         "Pool blocks in use, added up once per engine step, by cache kind "
         "(full | window) and multiplied by the layers that keep a pool of "
         "that kind: the memory the cache manager holds over time."),
+    "paddle_tpu_cache_byte_steps_total": (
+        "counter", ("kind",),
+        "Bytes of cache in use, added up once per engine step, by cache "
+        "kind and multiplied by the layers that keep a pool of that kind: a "
+        "paged kind's (full | window) blocks in use times a block's bytes, "
+        "a recurrent kind's (linear) slots in use times a slot's state and "
+        "kept convolution inputs."),
+    "paddle_tpu_state_pool_bytes": (
+        "gauge", (),
+        "Device bytes of the recurrent layers' state pool: (max_batch + 1 "
+        "null) slots x layers x (float32 state + kept convolution inputs); "
+        "0 for a model without recurrent layers. Included in "
+        "paddle_tpu_serving_kv_pool_bytes."),
+    "paddle_tpu_state_slots_reset_total": (
+        "counter", (),
+        "State slots handed to a newly admitted request: its first run "
+        "starts at position 0, which the step's program takes from zeros "
+        "whatever the slot holds (counted at the next step that runs; an "
+        "engine without recurrent layers counts nothing)."),
     "paddle_tpu_kv_window_blocks_released_total": (
         "counter", (),
         "Blocks of a sliding-window cache kind handed back to their pool "
@@ -553,6 +589,12 @@ SPANS = {
         "their rows' sliding window, before a step's grants (child of "
         "serving.pack_tokens; recorded only when a block was freed). "
         "attrs: blocks."),
+    "serving.state_slots": (
+        "The scheduler's accounting for recurrent layers in a step's "
+        "schedule phase (child of serving.pack_tokens; only an engine with "
+        "recurrent layers records it). attrs: reset (slots a newly "
+        "admitted request takes over: its first run starts from zeros), "
+        "step_runs, chunk_runs, chunk_tokens."),
     "serving.spec_verify": (
         "One mixed step's speculative verification: draft tokens packed "
         "as extra ragged lanes, accepted by the device-side longest-"

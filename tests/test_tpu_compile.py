@@ -22,7 +22,7 @@ from jax.sharding import SingleDeviceSharding
 import paddle_tpu  # noqa: F401
 from paddle_tpu.incubate.distributed.models.moe import held_experts
 from paddle_tpu.models import paged_kv
-from paddle_tpu.ops.pallas import grouped_matmul
+from paddle_tpu.ops.pallas import gated_delta_rule, grouped_matmul
 from paddle_tpu.ops.pallas.flash_attention import flash_attention_fwd
 from paddle_tpu.ops.pallas.paged_attention import (paged_attention,
                                                    paged_attention_gqa)
@@ -59,7 +59,8 @@ def mosaic(monkeypatch):
     """The kernels ask jax.devices() whether to interpret, and see the CPU
     here: steer them to the real lowering (through sys.modules — the package
     re-exports a function under the module's name)."""
-    for name in ("flash_attention", "paged_attention", "grouped_matmul"):
+    for name in ("flash_attention", "paged_attention", "grouped_matmul",
+                 "gated_delta_rule"):
         mod = sys.modules["paddle_tpu.ops.pallas." + name]
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
@@ -299,6 +300,65 @@ def test_mixed_step_of_an_expert_model_holds_the_grouped_kernels(
     assert kernels.count("held_experts_gmm_up") >= layers
     assert kernels.count("held_experts_gmm_down") >= layers
     assert "ragged-dot" not in kernels and "ragged_dot" not in kernels
+
+
+@pytest.mark.parametrize("lanes,planned", [(32, False), (288, True)],
+                         ids=["burst-32-lanes", "mixed-step-288-lanes"])
+def test_gated_delta_kernels_compile_for_v5e(one_chip, mosaic, monkeypatch,
+                                             lanes, planned):
+    """The recurrent layers' kernels at the Olmo-Hybrid cell's shapes: 30
+    heads of 96 x 192 (pairs of heads fill 384 lanes), 33 slots. A burst's 32
+    lanes run ``gated_delta_step`` alone; a mixed step's 288 lanes are planned
+    inside the program (data: rows, positions, valid) and run both kernels,
+    ``gated_delta_chunk`` over a dynamic number of chunks."""
+    monkeypatch.setattr(gated_delta_rule, "_kernel_applies",
+                        lambda q, v, s: True)
+    H, dk, dv, slots = 30, 96, 192, 33
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def run(q, k, v, g, beta, state, positions, rows, valid):
+        plan = gated_delta_rule.plan_runs(rows, positions, valid, slots) \
+            if planned else None
+        return gated_delta_rule.gated_delta(q, k, v, g, beta, state,
+                                            positions, plan)
+
+    hlo = jax.jit(run).lower(
+        sds((lanes, H, dk)), sds((lanes, H, dk)), sds((lanes, H, dv)),
+        sds((lanes, H)), sds((lanes, H)), sds((slots, H // 2, dk, 2 * dv)),
+        sds((lanes,), jnp.int32), sds((lanes,), jnp.int32),
+        sds((lanes,), jnp.bool_)).compile().as_text()
+    assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 1 + planned
+    assert "gated_delta_step" in hlo
+    assert ("gated_delta_chunk" in hlo) == planned
+
+
+def test_paged_attention_refuses_pools_whose_heads_fill_no_sublane_tile():
+    """30 KV heads on a [blocks, block, heads, 128] pool: the one-row-a-head
+    kernel's slices are refused by the compiler ("must be aligned to tiling
+    (8)"), so the rule sends such a pool to the plain path (and a model with
+    30 heads keeps flat pools, which the grouped-query kernel reads)."""
+    q = jax.ShapeDtypeStruct((8, 30, 128), jnp.bfloat16)
+    pool = jax.ShapeDtypeStruct((9, 64, 30, 128), jnp.bfloat16)
+    ok = jax.ShapeDtypeStruct((9, 64, 32, 128), jnp.bfloat16)
+    flat = jax.ShapeDtypeStruct((9, 64, 30 * 128), jnp.bfloat16)
+    real = jax.devices
+
+    class _Tpu:
+        platform = "tpu"
+
+    try:
+        jax.devices = lambda *a: [_Tpu()]
+        assert not paged_kv._kernel_applies(q, pool)
+        assert paged_kv._kernel_applies(q, ok)
+        for heads, fine in ((1, False), (2, True), (4, True), (6, False),
+                            (8, True), (12, False), (16, True)):
+            small = jax.ShapeDtypeStruct((9, 64, heads, 128), jnp.bfloat16)
+            assert paged_kv._kernel_applies(q, small) == fine, heads
+        assert paged_kv._kernel_applies(q, flat, flat)
+    finally:
+        jax.devices = real
 
 
 def test_paged_decode_attention_fits_the_mixed_step(one_chip):
