@@ -116,7 +116,7 @@ class _Mon:
                  "phase_ns", "steps", "token_gap", "attn_blocks",
                  "kind_blocks", "attn_lanes", "block_steps", "expert_pairs",
                  "window_released", "linear_tokens", "linear_runs",
-                 "byte_steps", "state_bytes", "state_resets")
+                 "byte_steps", "state_bytes", "state_resets", "dispatched")
 
 
 _MON = None
@@ -125,6 +125,8 @@ _MON = None
 # the order a step runs them (spans: serving.pack_tokens / dispatch /
 # wait / route)
 _STEP_PHASES = ("schedule", "dispatch", "wait", "route")
+_PHASE_LABEL = dict(zip(("serving.pack_tokens", "serving.dispatch",
+                         "serving.wait", "serving.route"), _STEP_PHASES))
 
 
 def _mon():
@@ -207,8 +209,54 @@ def _mon():
                                  labelnames=("kind",))
         o.state_bytes = m.gauge("paddle_tpu_state_pool_bytes")
         o.state_resets = m.counter("paddle_tpu_state_slots_reset_total")
+        o.dispatched = m.counter("paddle_tpu_serving_dispatch_total",
+                                 labelnames=("ahead",))
         _MON = o
     return _MON
+
+
+class _FetchFirst(Exception):
+    """Raised inside the schedule where it cannot go on before the step in
+    flight has been routed (the pool is short of blocks: a request may end
+    and free its row there, and a preemption copies KV and tokens that
+    must be on the host); the step fetches, then schedules anew."""
+
+
+class _Flight:
+    """One dispatched step whose output the host has not fetched: what
+    routing needs of it, written when it was dispatched."""
+
+    __slots__ = ("kind", "out", "epoch", "t0", "decode", "chunks",
+                 "forwards", "n_lanes", "n_draft")
+
+    def __init__(self, kind, out, epoch, t0, forwards=1):
+        self.kind = kind                # mixed | burst
+        self.out = out                  # the program's output, on the device
+        self.epoch = epoch
+        self.t0 = t0                    # now_ns of its schedule's start
+        # (slot, request, index of its first token in the flattened
+        # output, draft lanes behind it, tokens granted, whether the last
+        # of them ends the request by length, lens before the step)
+        self.decode = []
+        # (slot, request, start, take, its first token's index or -1,
+        # whether that token ends the request by length)
+        self.chunks = []
+        self.forwards = forwards        # forward passes (a burst: K)
+        self.n_lanes = 0
+        self.n_draft = 0
+
+
+@jax.jit
+def _compose_tokens(pack, prev):
+    """The pack a step's program takes, ``(2, n)``: token ids over
+    positions, from the host's ``(3, n)`` pack, whose third row says for
+    each lane where in the output ``prev`` of the step before (flattened)
+    its token lies, or -1 for a lane whose id the host wrote in row 0.
+    A decode lane's input token goes from one program to the next without
+    a visit to the host."""
+    src = pack[2]
+    tok = jnp.where(src >= 0, prev.reshape(-1)[jnp.maximum(src, 0)], pack[0])
+    return jnp.stack([tok, pack[1]])
 
 
 class _Request:
@@ -217,7 +265,7 @@ class _Request:
     __slots__ = ("rid", "prompt", "prefill_pos", "chunks", "shared_tokens",
                  "max_new", "last_token", "outputs", "token_times",
                  "t_submit", "t_admit", "t_first", "tenant", "priority",
-                 "spill")
+                 "spill", "granted", "done")
 
     def __init__(self, rid, prompt, max_new, t_submit, tenant="",
                  priority=0):
@@ -229,6 +277,10 @@ class _Request:
         self.max_new = max_new          # per-request cap (None = step's)
         self.last_token = 0
         self.outputs = []
+        # output tokens of DISPATCHED steps (>= len(outputs): the scheduler
+        # counts these, routing fills ``outputs`` a fetch later)
+        self.granted = 0
+        self.done = False               # ended: reported, cancelled, aborted
         self.token_times = []           # now_ns of each output's step fetch
         self.t_submit = t_submit
         self.t_admit = 0
@@ -469,6 +521,30 @@ class ContinuousBatchingEngine:
         self._active = np.zeros(self.max_batch, bool)
         self._decode_ready = np.zeros(self.max_batch, bool)
         self._last_tok = np.zeros(self.max_batch, np.int32)
+        # -- the step in flight ------------------------------------------
+        # THE INVARIANT the scheduler's book rests on: programs run on the
+        # device in dispatch order and the pools are threaded through them
+        # (donated), so a block, a table row or a state slot may be handed
+        # on as soon as the last step that reads it has been DISPATCHED.
+        # So the book (lens, prefill_pos, _decode_ready, a request's
+        # granted tokens, released window blocks, noted state slots,
+        # registered prefix blocks, a request's row when its last token
+        # is granted) advances at dispatch, and step() dispatches step
+        # N + 1 before it fetches step N's output: routing keeps only what
+        # needs the token VALUES. ``_flight`` is the one dispatched step
+        # not yet fetched; ``_tok_src[b]`` says where in its (flattened)
+        # output slot b's newest token lies, -1 once ``_last_tok[b]``
+        # holds it; ``_unreported`` (rid -> request) holds the requests
+        # whose row was released at dispatch and whose end routing has
+        # yet to report: in no slot, and while their step is being
+        # fetched in no ``_flight`` either, so recover() finds them here
+        self._flight = None
+        self._tok_src = np.full(self.max_batch, -1, np.int32)
+        self._unreported = {}
+        self._out_shapes = {}           # kind -> its program's output shape
+        # the running step()'s eos_token_id, max_new_tokens and epoch
+        self._call = (None, None, 0)
+        self._out = []                  # ... and what it will return
         # device lane vectors keyed by pack composition: in steady decode
         # the composition repeats every step, so slot_ids/valid upload once
         self._lane_cache = {}
@@ -976,7 +1052,10 @@ class ContinuousBatchingEngine:
         first): its exact KV bits spill to host RAM, its blocks return to
         the pool, and the request rejoins the HEAD of its tenant's lane —
         restored bit-exact by :meth:`_restore` on re-admission. Returns
-        the freed slot, or None when nothing is preemptible."""
+        the freed slot, or None when nothing is preemptible. What it
+        copies (KV, the last token, the outputs so far) must be on the
+        host: its callers fetch the step in flight first
+        (``_short_of_blocks``)."""
         skip = set(int(b) for b in exclude)
         cands = [b for b in range(self.max_batch)
                  if self._slots[b] is not None and b not in skip]
@@ -994,12 +1073,7 @@ class ContinuousBatchingEngine:
             blocks = [int(b) for b in self._pager._tables_np[slot][:nblk]]
             contents = _pk.read_blocks(self._pools, blocks)
         req.spill = (n_tok, contents, bool(self._decode_ready[slot]))
-        self._free_row(slot)
-        self._slots[slot] = None
-        self._active[slot] = False
-        self._decode_ready[slot] = False
-        self.lens[slot] = 0
-        self._chain_cursors.pop(slot, None)
+        self._release(slot)
         if self._drafter is not None:
             self._drafter.drop(req.rid)   # _restore re-admits the context
         self._requeue_front(req)
@@ -1115,6 +1189,8 @@ class ContinuousBatchingEngine:
             knobs, self._pending_knobs = self._pending_knobs, {}
         for name, v in knobs.items():
             if name == "decode_burst" and v != self.decode_burst:
+                # a burst in flight is routed under the K it ran with
+                self._fetch_flight(then_schedule=True)
                 # invalidate the compiled burst program; the cache key is
                 # stable ("burst"), so the sentinel sees ONE recompile
                 # with the new signature, not a cache leak
@@ -1123,10 +1199,31 @@ class ContinuousBatchingEngine:
 
     # -- the mixed step ------------------------------------------------------
     def step(self, eos_token_id=None, max_new_tokens=None):
-        """ONE compiled mixed step: every prefilled slot decodes one
-        token; admitted-but-unprefilled slots consume prefill chunks from
-        the remaining token budget. Returns the finished
-        (request_id, tokens) pairs evicted this step."""
+        """Keep ONE compiled step in flight. A call schedules and packs
+        step N + 1 from the scheduler's book as it stands AFTER step N
+        (every prefilled slot decodes one token, or ``decode_burst`` of
+        them; admitted-but-unprefilled slots consume prefill chunks from
+        the remaining token budget), dispatches it, THEN fetches step N's
+        output, routes it and returns the finished (request_id, tokens)
+        pairs of step N: results arrive ONE CALL LATER than the step that
+        computed them, and while the host routes, returns and packs, the
+        device runs. A call with nothing to dispatch routes the step in
+        flight and returns; ``num_active`` stays true until every request
+        has been handed back, so ``while eng.num_active or
+        eng.num_pending: eng.step()`` returns them all.
+
+        Where the next schedule needs a step's tokens on the host, the
+        same code keeps nothing in flight (depth 0: the step is fetched
+        in the call that dispatched it, as before PR 37): with a drafter
+        (drafts are made from the last tokens) and under the numerics
+        sanitizer, always; before a preemption or any grant the pool
+        cannot fund, before ``cancel`` of a request that is not queued
+        and before a ``decode_burst`` change, for that step; ``recover()``
+        drops the step in flight with its epoch. A request that ends by
+        LENGTH gets no lane in the next step and its row is released when
+        its last token is dispatched (see ``_flight`` in ``__init__`` for
+        the invariant); one that ends by ``eos_token_id`` already has a
+        lane in the step in flight: that lane's tokens are discarded."""
         epoch = self._epoch
         mon = _mon()
         # the host-side twin of the open serving.step span: set while a
@@ -1136,6 +1233,8 @@ class ContinuousBatchingEngine:
         step_ctx = self._phase = mon.trace._NOOP
         self._phases = ()
         self._step_kind = None
+        self._call = (eos_token_id, max_new_tokens, epoch)
+        finished = self._out = []
         if mon.tstate.annotate:
             # an OPEN serving.step span is what a flight dump names when
             # the driving thread hangs or dies mid-step. It and its four
@@ -1149,7 +1248,7 @@ class ContinuousBatchingEngine:
             self._phase = mon.trace.phase(
                 "serving.pack_tokens", parent=sp, t0_ns=step_ctx.t0_ns)
             self._phase.__enter__()
-            self._phases = [self._phase]
+            self._phases = [["schedule", None, self._phase]]
         counted = False
         try:
             # staged controller knobs land here, on the driving thread,
@@ -1169,11 +1268,9 @@ class ContinuousBatchingEngine:
                     # (GL002) — a Tensor host sync inside it is a
                     # regression the tripwire turns into a raise
                     with san.protected_region("serving.step"):
-                        finished = self._step_impl(eos_token_id,
-                                                   max_new_tokens)
+                        self._step_impl(max_new_tokens)
                 else:
-                    finished = self._step_impl(eos_token_id,
-                                               max_new_tokens)
+                    self._step_impl(max_new_tokens)
             except Exception:
                 if epoch != self._epoch:
                     # a hang recovery superseded this SLOW-but-alive
@@ -1187,33 +1284,71 @@ class ContinuousBatchingEngine:
                 # request this step computed for — its results belong
                 # to the dead epoch and must not double-report
                 return []
-            counted = self._step_kind is not None
+            counted = True
             return finished
         finally:
             self.step_open_since = None
             last = self._phase
             last.close()
             step_ctx.close(last.t1_ns)
-            if counted and mon.state.on and len(self._phases) == 4:
-                # a step that ran its program and routed the result: an
-                # early return (no active lane, superseded epoch) or a
-                # raise counts nothing
-                kind = self._step_kind
-                for name, ph in zip(_STEP_PHASES, self._phases):
-                    mon.phase_ns.labels(name, kind).inc(
-                        ph.t1_ns - ph.t0_ns)
-                mon.steps.labels(kind).inc()
+            if counted and mon.state.on:
+                # the phases of a call that dispatched or fetched a step:
+                # schedule and dispatch under the kind of the step they
+                # PREPARED, wait and route under that of the step they
+                # FETCHED. An early return (no active lane, superseded
+                # epoch) or a raise counts nothing
+                for label, kind, ph in self._phases:
+                    if kind is not None:
+                        mon.phase_ns.labels(label, kind).inc(
+                            ph.t1_ns - ph.t0_ns)
 
     def _next_phase(self, name, kind=None):
         """Hand the running step over to its next phase at one shared
-        instant (a no-op with both switches off); ``kind`` (mixed |
-        burst) is known from the dispatch phase on."""
-        if kind is not None:
-            self._step_kind = kind
+        instant (a no-op with both switches off). ``kind`` (mixed |
+        burst) is that of the step the phase works for: the dispatch
+        phase names the step it and the schedule phase before it
+        PREPARE, the wait phase the step it and the route phase after it
+        FETCH (and a schedule phase that prepared nothing)."""
         ph = self._phase.then(name)
-        if ph is not self._phase:
-            self._phase = ph
-            self._phases.append(ph)
+        if ph is self._phase:
+            return
+        phases = self._phases
+        if name == "serving.route":
+            kind = phases[-1][1]
+        if kind is not None:
+            for entry in phases:
+                if entry[1] is None:
+                    entry[1] = kind
+        self._phase = ph
+        phases.append([_PHASE_LABEL[name], kind, ph])
+
+    def _depth(self):
+        """How many dispatched steps step() may leave unfetched: 1, or 0
+        where the NEXT schedule reads this step's tokens on the host (a
+        drafter drafts from them) or every step's output is checked as it
+        lands (the numerics sanitizer)."""
+        return 0 if self._drafter is not None \
+            or _sanitizers._state.numerics else 1
+
+    def _fetch_flight(self, then_schedule=False):
+        """Fetch and route the step in flight, if there is one: every
+        token is on the host after it. ``then_schedule``: the call goes
+        on to schedule (the schedule phase opens again)."""
+        fl, self._flight = self._flight, None
+        if fl is not None:
+            self._fetch(fl)
+            self._tok_src[:] = -1
+            if then_schedule:
+                self._next_phase("serving.pack_tokens")
+
+    def _fetch(self, fl):
+        """The wait and route phases of one dispatched step."""
+        if self._step_kind is None:
+            self._step_kind = fl.kind   # (a call that dispatched nothing)
+        self._next_phase("serving.wait", fl.kind)
+        out = np.asarray(fl.out)
+        self._next_phase("serving.route")
+        self._route(fl, out)
 
     def _tables(self):
         """The block tables the programs take: one a cache kind (None for
@@ -1365,25 +1500,60 @@ class ContinuousBatchingEngine:
         for pg in self._pagers:
             pg.free_sequence(slot)
 
-    def _step_impl(self, eos_token_id, max_new_tokens):
-        # a hang (watchdog-recovered) almost always sits in the compiled
-        # dispatch below, so the epoch captured here + the fence after
-        # the dispatch fetch bound what a superseded step can touch (the
-        # microsecond host-side window before dispatch is accepted —
-        # recover() documents it)
-        epoch = self._epoch
+    def _step_impl(self, max_new_tokens):
         mon = _mon()
+        fl = self._flight
+        if fl is not None and fl.epoch != self._epoch:
+            # the step in flight belongs to a dead epoch: recover()
+            # aborted every request it computed for. Dropped unrouted
+            fl = self._flight = None
+        if fl is not None and not self._depth():
+            self._fetch_flight(then_schedule=True)
         # cancellations first: a cancelled queued request must not be
         # admitted by the drain below, and a cancelled active slot frees
         # its lane (and blocks) before the pack assembles
         self._apply_cancels()
-        self._drain_pending()
-        if not self._active.any():
+        plan, again = None, False
+        while True:
+            self._drain_pending()
+            if not self._active.any():
+                break
+            try:
+                plan = self._schedule(max_new_tokens, mon, again)
+                break
+            except _FetchFirst:
+                self._fetch_flight(then_schedule=True)
+                again = True
+        if plan is None:
+            # nothing to dispatch: route the step in flight and return
+            self._fetch_flight()
             if mon.state.on:
                 self._update_gauges(mon)
-            return []
+            return
+        prev = self._flight
+        self._flight = self._dispatch(plan, prev, max_new_tokens, mon)
+        if prev is not None:
+            self._fetch(prev)
+        if not self._depth():
+            self._fetch_flight()
+
+    def _short_of_blocks(self):
+        """The pool cannot fund a grant. The step in flight may end a
+        request (by EOS) and free its row when it is routed, and what
+        comes next (a preemption, a raise) is the last resort: fetch
+        first, then schedule anew."""
+        if self._flight is not None:
+            raise _FetchFirst()
+
+    def _schedule(self, max_new_tokens, mon, again=False):
+        """What the next step runs, from the book as it stands after every
+        DISPATCHED step: ``(kind, t0, decode_slots, chunks, draft_map)``,
+        or None where nothing can (a request was preempted to unstick the
+        pool). Grants blocks; advances nothing else. ``again``: the call's
+        second schedule, behind an early fetch."""
         t0 = mon.mod.now_ns()
-        self._release_window_blocks(mon)
+        if not again:
+            self._release_window_blocks(mon)
         T = self.max_step_tokens
         decode_slots = np.flatnonzero(self._decode_ready)
         prefill_slots = np.flatnonzero(self._active
@@ -1426,6 +1596,7 @@ class ContinuousBatchingEngine:
                 self._ensure(need)
                 granted = True
             except RuntimeError:
+                self._short_of_blocks()
                 if not self.kv_spill:
                     raise
                 granted = False   # single-step path preempts for room
@@ -1441,9 +1612,7 @@ class ContinuousBatchingEngine:
                     [t[b, f:g + 1] for b, f, g in
                      zip(decode_slots, first, last)])
                 if not (self._pager._refs[targets] > 1).any():
-                    return self._burst_impl(decode_slots, eos_token_id,
-                                            max_new_tokens, mon, t0,
-                                            epoch)
+                    return "burst", t0, decode_slots, (), {}
         if self.policy == "spf":
             prefill_slots.sort(key=lambda b: (
                 -self._slots[b].priority,
@@ -1471,6 +1640,7 @@ class ContinuousBatchingEngine:
                 self._ensure(need)
                 break
             except RuntimeError:
+                self._short_of_blocks()
                 if not self.kv_spill:
                     raise
                 victim = self._preempt_lowest(exclude=decode_slots)
@@ -1508,24 +1678,125 @@ class ContinuousBatchingEngine:
                                                  max_new_tokens)
                 need, draft_map = self._grant_drafts(need, draft_map)
         if not nd and not chunks:
+            self._short_of_blocks()
             if self.kv_spill and self._preempt_lowest() is not None:
                 # pool fully pinned and nothing can progress: spill one
                 # request's KV to host RAM; the freed blocks unstick the
                 # rest next step and the victim resumes bit-exact later
-                return []
+                return None
             # admitted requests exist but nothing can make progress (pool
             # fully pinned by live sequences) — surface it, the caller
             # sized the pool too small for the batch
             raise RuntimeError(
                 "serving step cannot pack any lane: paged KV pool "
                 "exhausted with no evictable prefix-cache blocks")
+        return "mixed", t0, decode_slots, chunks, draft_map
+
+    def _dispatch(self, plan, prev, max_new_tokens, mon):
+        """Pack and dispatch the planned step, then advance the book to
+        where it stands once the step has run. ``prev`` is the step still
+        unfetched (None at depth 0): the lanes whose token it produced
+        take it from its output on the device (``_compose_tokens``).
+        Returns the new step's ``_Flight``."""
+        kind, t0, decode_slots, chunks, draft_map = plan
+        self._step_kind = kind
+        if mon.state.on:
+            mon.dispatched.labels("no" if prev is None else "yes").inc()
+        if kind == "burst":
+            fl = self._dispatch_burst(t0, decode_slots, prev, mon)
+            lanes = [(int(b) * fl.forwards, 0) for b in decode_slots]
+            emits = ()
+        else:
+            fl, lanes, emits = self._dispatch_mixed(
+                t0, decode_slots, chunks, draft_map, prev, mon)
+        # the download starts now: the fetch, a call later, finds it done
+        fl.out.copy_to_host_async()
+        if self._out_shapes.get(kind) != fl.out.shape:
+            # a program's first output (or a burst's after a decode_burst
+            # change): compile now, beside the program, every composition
+            # of a known output into either pack, so that none compiles
+            # when two kinds first follow each other in steady state
+            self._out_shapes[kind] = fl.out.shape
+            for shape in self._out_shapes.values():
+                out = jax.device_put(np.zeros(shape, np.int32))
+                for n in (self.max_step_tokens, self.max_batch):
+                    _compose_tokens(
+                        jax.device_put(np.zeros((3, n), np.int32)), out)
+        if fl.epoch != self._epoch:
+            # a hang recovery superseded this step while it sat in
+            # compile/dispatch: the pools rebind stands, the book is the
+            # new epoch's. The step is dropped by the next call
+            return fl
+        # -- the book ------------------------------------------------------
+        K = fl.forwards
+        ended = []
+        for b, (first, kb) in zip(decode_slots.tolist(), lanes):
+            req = self._slots[b]
+            pre = int(self.lens[b])
+            take, ends = self._grant(req, pre, K, max_new_tokens)
+            self.lens[b] = pre + take
+            self._tok_src[b] = first + K - 1
+            fl.decode.append((b, req, first, kb, take, ends, pre))
+            if ends:
+                ended.append(b)
+        for (b, start, take), emit in zip(chunks, emits):
+            b = int(b)
+            req = self._slots[b]
+            req.prefill_pos = start + take
+            req.chunks += 1
+            self.lens[b] = req.prefill_pos
+            if self.prefix_cache is not None:
+                n = self.prefix_cache.register(
+                    req.prompt, req.prefill_pos, self._pager._tables_np[b])
+                if mon.state.on and n:
+                    mon.pc_blocks.set(len(self.prefix_cache))
+            ends = False
+            if emit >= 0:
+                self._decode_ready[b] = True
+                self._tok_src[b] = emit
+                # (the first token: lens stands at the prompt's length)
+                _, ends = self._grant(req, req.prefill_pos - 1, 1,
+                                      max_new_tokens)
+                if ends:
+                    ended.append(b)
+            fl.chunks.append((b, req, start, take, emit, ends))
+        if self._depth():
+            # a request whose last token has been dispatched needs its
+            # row no more (the invariant, __init__): the slot is free for
+            # the next schedule, routing reports the request
+            for b in ended:
+                req = self._slots[b]
+                self._unreported[req.rid] = req
+                self._release(b)
+        return fl
+
+    def _grant(self, req, lens, n, max_new_tokens):
+        """Grant ``req`` up to ``n`` more output tokens from a row that
+        holds ``lens``: ``(tokens it takes, whether the last of them ends
+        it by length)``, by its own or the step's limit on new tokens or
+        by ``max_len``. At least one: a step's token is always routed."""
+        take = min(n, max(self.max_len - 1 - lens, 1))
+        limit = req.max_new if req.max_new is not None else max_new_tokens
+        if limit is not None:
+            take = min(take, max(limit - req.granted, 1))
+        req.granted += take
+        return take, (limit is not None and req.granted >= limit) \
+            or lens + take + 1 >= self.max_len
+
+    def _dispatch_mixed(self, t0, decode_slots, chunks, draft_map, prev,
+                        mon):
+        T = self.max_step_tokens
+        nd = len(decode_slots)
         # pack assembly (vectorized — this runs every step): decode lanes
         # (each followed by its draft-verify lanes, so accept chains are
         # contiguous for the device-side scan) first, then prefill
-        # chunks. tok_ids/positions ride ONE (2, T) upload; a fresh array
-        # each step so the async transfer never races a host-side reuse
-        pack_np = np.zeros((2, T), np.int32)
-        tok_ids, positions = pack_np[0], pack_np[1]
+        # chunks. tok_ids/positions (and, behind a step in flight, the
+        # lanes' token sources) ride ONE upload; a fresh array each step
+        # so the async transfer never races a host-side reuse
+        pack_np = np.zeros((3, T), np.int32)
+        tok_ids, positions, src = pack_np
+        src[:] = -1
+        fl = _Flight("mixed", None, self._call[2], t0)
         if draft_map:
             dec_lanes = []              # (slot, base lane, n drafts)
             lane = 0
@@ -1552,15 +1823,17 @@ class ContinuousBatchingEngine:
             # the hot path
             dec_lanes = None
             tok_ids[:nd] = self._last_tok[decode_slots]
+            src[:nd] = self._tok_src[decode_slots]
             positions[:nd] = self.lens[decode_slots]
             lane = n_dec_lanes = nd
-        emit_lanes = {}                 # slot -> lane of its LAST prompt tok
+        emits = []                      # a chunk's emitting lane, or -1
         for b, start, take in chunks:
             req = self._slots[b]
             tok_ids[lane:lane + take] = req.prompt[start:start + take]
             positions[lane:lane + take] = np.arange(start, start + take)
-            if start + take == len(req.prompt):
-                emit_lanes[b] = lane + take - 1
+            # the lane of its LAST prompt token emits its first token
+            emits.append(lane + take - 1
+                         if start + take == len(req.prompt) else -1)
             lane += take
         n_lanes = lane
         # copy-on-write: any lane writing into a SHARED block (prefix-
@@ -1640,135 +1913,177 @@ class ContinuousBatchingEngine:
             self._count_attn_blocks(mon, positions, T, slot_np, n_lanes)
         self._note_state_slots(mon, nd, chunks)
         self._next_phase("serving.dispatch", "mixed")
-        out_dev, self._pools = step(
-            jnp.asarray(pack_np), self._pools, self._tables(),
+        pack = jnp.asarray(pack_np[:2]) if prev is None \
+            else _compose_tokens(jnp.asarray(pack_np), prev.out)
+        fl.out, self._pools = step(
+            pack, self._pools, self._tables(),
             slots_dev, valid_dev, chain_dev, self._inner.weights)
-        self._next_phase("serving.wait")
         if _sanitizers._state.numerics:
             self._san_steps += 1
             _sanitizers.numsan_check(
                 "serving.mixed_step",
-                (("tokens", out_dev), ("kv_pools", self._pools)),
+                (("tokens", fl.out), ("kv_pools", self._pools)),
                 step=self._san_steps)
-        out = np.asarray(out_dev)
-        self._next_phase("serving.route")
-        toks, acc = out[0], out[1]
-        if len(out) > 2 and mon.state.on:
-            self._count_expert_pairs(mon, out[2][:4], 1)
-        if epoch != self._epoch:
-            # a hang recovery superseded this step while it sat in
-            # compile/dispatch. The pools rebind above MUST stand — the
-            # jit result is the only live buffer set on donation
-            # platforms, and the radix cache's pinned blocks live in it
-            # untouched (the step only wrote positions the dead epoch's
-            # tables mapped, all freed by the recovery) — but every host
-            # slot/table/token mutation now belongs to the new epoch:
-            # apply nothing.
-            return []
-        t1 = mon.mod.now_ns()
+        fl.n_lanes = n_lanes
+        fl.n_draft = n_dec_lanes - nd
+        # each decode slot's (base lane, draft lanes behind it)
+        lanes = [(i, 0) for i in range(nd)] if dec_lanes is None \
+            else [(lane0, kb) for _b, lane0, kb in dec_lanes]
+        return fl, lanes, emits
+
+    def _dispatch_burst(self, t0, decode_slots, prev, mon):
+        """Steady-state fast path: K fused decode iterations, one
+        dispatch, one (2, B) upload, one (B, K) download."""
+        K = self.decode_burst
+        pack = np.empty((3, self.max_batch), np.int32)
+        pack[0] = self._last_tok
+        pack[1] = self.lens
+        pack[2] = self._tok_src
+        burst = self._burst_jit()
+        if self._phase.span is not None:
+            self._phase.attrs = {"n_decode": len(decode_slots), "burst": K}
+        if mon.state.on:
+            self._count_attn_blocks(
+                mon, np.add.outer(self.lens[decode_slots], np.arange(K)),
+                self.max_batch * K)
+        self._note_state_slots(mon, K * len(decode_slots))
+        self._next_phase("serving.dispatch", "burst")
+        fl = _Flight("burst", None, self._call[2], t0, forwards=K)
+        pack_dev = jnp.asarray(pack[:2]) if prev is None \
+            else _compose_tokens(jnp.asarray(pack), prev.out)
+        fl.out, self._pools = burst(
+            pack_dev, self._pools, self._tables(), self._inner.weights)
+        if _sanitizers._state.numerics:
+            self._san_steps += 1
+            _sanitizers.numsan_check(
+                "serving.decode_burst",
+                (("tokens", fl.out), ("kv_pools", self._pools)),
+                step=self._san_steps)
+        return fl
+
+    def _route(self, fl, out):
+        """What needs the token VALUES of a fetched step: the requests'
+        outputs and token times, TTFT, the token gaps, the finished list
+        (``self._out``), an end by EOS. A lane whose request has ended
+        since the step was dispatched is discarded."""
+        mon = _mon()
+        if fl.epoch != self._epoch:
+            # a recovery superseded this step while it was in flight or
+            # sat in compile/dispatch: every request it computed for was
+            # aborted, and every host slot/table/token mutation now
+            # belongs to the new epoch. Apply nothing. (The pools the
+            # step returned stand, rebound at its dispatch — the jit
+            # result is the only live buffer set on donation platforms,
+            # and the radix cache's pinned blocks live in it untouched:
+            # the step only wrote positions the dead epoch's tables
+            # mapped, all freed by the recovery.)
+            return
+        eos_token_id, max_new_tokens = self._call[:2]
+        finished = self._out
+        K = fl.forwards
+        flat = out.reshape(-1)          # row 0 (a burst: the rows) first
+        if mon.state.on:
+            if fl.kind == "mixed" and len(out) > 2:
+                self._count_expert_pairs(mon, out[2][:4], 1)
+            elif fl.kind == "burst" and len(out) > self.max_batch:
+                self._count_expert_pairs(mon, out[-4:].sum(axis=1), K)
+        t0, t1 = fl.t0, mon.mod.now_ns()
+        nd = len(fl.decode)
         if mon.tstate.on:
-            for b in decode_slots:
-                entry = self._span_entry(self._slots[b].rid)
+            for b, req, *_ in fl.decode:
+                entry = self._span_entry(req.rid)
                 if entry is not None:
+                    attrs = {"slot": b, "n_active": nd}
+                    if fl.kind == "burst":
+                        attrs["burst"] = K
                     mon.trace.record_span(
                         "serving.decode_step", t0, t1, parent=entry[0],
-                        attrs={"slot": int(b), "n_active": nd})
-            for b, start, take in chunks:
-                entry = self._span_entry(self._slots[b].rid)
+                        attrs=attrs)
+            for b, req, start, take, _emit, _ends in fl.chunks:
+                entry = self._span_entry(req.rid)
                 if entry is not None:
                     mon.trace.record_span(
                         "serving.prefill_chunk", t0, t1, parent=entry[0],
-                        attrs={"slot": int(b), "start": start,
-                               "tokens": take})
-        # route decode results: every slot emits its base token plus one
-        # token per ACCEPTED draft (longest agreeing prefix, computed on
-        # device) — the greedy sequence, just several tokens per dispatch
-        finished = []
+                        attrs={"slot": b, "start": start, "tokens": take})
+        # route decode results: every slot emits the tokens it was
+        # granted (a burst's up to K) plus one per ACCEPTED draft (longest
+        # agreeing prefix, computed on device; granted here, where their
+        # number is known) — the greedy sequence, several tokens a dispatch
         emitted = 0
-        n_draft = n_dec_lanes - nd
         n_accept = 0
-        if dec_lanes is None:
-            for i, b in enumerate(decode_slots):
-                pre = int(self.lens[b])
-                self.lens[b] += 1
+        for b, req, first, kb, take, ends, pre in fl.decode:
+            if req.done:
+                continue                # ended since: the lane is discarded
+            n = take
+            if kb:
+                n += int(out[1][first + 1:first + 1 + kb].sum())
+            routed = 0
+            for j in range(n):
+                if j < take:
+                    last = ends and j == take - 1
+                else:
+                    self.lens[b] += 1
+                    last = self._grant(req, pre + j, 1, max_new_tokens)[1]
                 emitted += 1
-                self._note_token(b, int(toks[i]), eos_token_id,
-                                 max_new_tokens, finished, mon, t1)
+                routed += 1
+                if self._note_token(req, b, int(flat[first + j]), last,
+                                    pre + j + 1, eos_token_id, finished,
+                                    mon, t1):
+                    break       # ended: the rest of its lane is discarded
+            # accepted = draft tokens actually DELIVERED: an eos
+            # mid-chain discards the rest of the lane, and the
+            # cataloged counter promises emitted tokens
+            n_accept += max(routed - 1, 0) if kb else 0
+            if self._slots[b] is req:
                 self._register_decode_blocks(b, pre, mon)
-        else:
-            for b, lane0, kb in dec_lanes:
-                a = int(acc[lane0 + 1:lane0 + 1 + kb].sum()) if kb else 0
-                pre = int(self.lens[b])
-                routed = 0
-                for j in range(a + 1):
-                    if self._slots[b] is None:
-                        break           # finished mid-verify: the rest
-                    self.lens[b] += 1   # of its lane is discarded
-                    emitted += 1
-                    routed += 1
-                    self._note_token(b, int(toks[lane0 + j]),
-                                     eos_token_id, max_new_tokens,
-                                     finished, mon, t1)
-                # accepted = draft tokens actually DELIVERED: an eos
-                # mid-chain discards the rest of the lane, and the
-                # cataloged counter promises emitted tokens
-                n_accept += max(routed - 1, 0)
-                self._register_decode_blocks(b, pre, mon)
-        if n_draft:
-            self.spec_drafted += n_draft
+        if fl.n_draft:
+            self.spec_drafted += fl.n_draft
             self.spec_accepted += n_accept
             if mon.state.on:
-                mon.spec_drafted.inc(n_draft)
+                mon.spec_drafted.inc(fl.n_draft)
                 mon.spec_accepted.inc(n_accept)
                 mon.spec_rate.set(self.spec_accepted
                                   / max(self.spec_drafted, 1))
             if mon.tstate.on:
                 mon.trace.record_span(
                     "serving.spec_verify", t0, t1,
-                    attrs={"drafted": n_draft, "accepted": n_accept,
+                    attrs={"drafted": fl.n_draft, "accepted": n_accept,
                            "lanes": nd})
-        # route prefill progress (+ first tokens of completed prefills)
-        for b, start, take in chunks:
-            req = self._slots[b]
-            req.prefill_pos = start + take
-            req.chunks += 1
-            self.lens[b] = req.prefill_pos
-            if self.prefix_cache is not None:
-                n = self.prefix_cache.register(
-                    req.prompt, req.prefill_pos, self._pager._tables_np[b])
-                if mon.state.on and n:
-                    mon.pc_blocks.set(len(self.prefix_cache))
-            if req.prefilled:
-                req.t_first = t1
-                self._decode_ready[b] = True
-                emitted += 1
-                with self._submit_lock:
-                    st = self._stats.get(req.rid)
-                    if st is not None:
-                        st["ttft_ns"] = t1 - req.t_submit
-                        st["prefill_chunks"] = req.chunks
-                if mon.state.on:
-                    mon.ttft.observe(t1 - req.t_submit)
-                    mon.prefill.observe(t1 - req.t_admit)
-                    mon.chunk_depth.observe(req.chunks)
-                entry = self._span_entry(req.rid)
-                if entry is not None:
-                    mon.trace.record_span(
-                        "serving.prefill", req.t_admit, t1,
-                        parent=entry[0],
-                        attrs={"slot": int(b),
-                               "prompt_len": len(req.prompt),
-                               "chunks": req.chunks,
-                               "shared_tokens": req.shared_tokens})
-                self._note_token(b, int(toks[emit_lanes[b]]), eos_token_id,
-                                 max_new_tokens, finished, mon, t1)
+        # first tokens of completed prefills
+        for b, req, _start, _take, emit, ends in fl.chunks:
+            if emit < 0 or req.done:
+                continue
+            req.t_first = t1
+            emitted += 1
+            with self._submit_lock:
+                st = self._stats.get(req.rid)
+                if st is not None:
+                    st["ttft_ns"] = t1 - req.t_submit
+                    st["prefill_chunks"] = req.chunks
+            if mon.state.on:
+                mon.ttft.observe(t1 - req.t_submit)
+                mon.prefill.observe(t1 - req.t_admit)
+                mon.chunk_depth.observe(req.chunks)
+            entry = self._span_entry(req.rid)
+            if entry is not None:
+                mon.trace.record_span(
+                    "serving.prefill", req.t_admit, t1,
+                    parent=entry[0],
+                    attrs={"slot": b,
+                           "prompt_len": len(req.prompt),
+                           "chunks": req.chunks,
+                           "shared_tokens": req.shared_tokens})
+            self._note_token(req, b, int(flat[emit]), ends,
+                             len(req.prompt), eos_token_id, finished, mon,
+                             t1)
         if mon.state.on:
             mon.decode.observe(t1 - t0)
             mon.tokens.inc(emitted)
-            mon.pack.observe(n_lanes)
+            if fl.kind == "mixed":
+                mon.pack.observe(fl.n_lanes)
+            mon.steps.labels(fl.kind).inc()
             self._update_gauges(mon)
             mon.mod.sample()   # chrome-trace counter timeline, per step
-        return finished
 
     def _register_decode_blocks(self, slot, pre_lens, mon):
         """With speculation on, GENERATED full blocks join the radix
@@ -1828,7 +2143,7 @@ class ContinuousBatchingEngine:
             limit = req.max_new if req.max_new is not None \
                 else max_new_tokens
             if limit is not None:
-                cap = min(cap, limit - len(req.outputs) - 1)
+                cap = min(cap, limit - req.granted - 1)
             if cap <= 0:
                 continue
             d = self._drafter.draft(req.rid, cap)
@@ -1872,73 +2187,8 @@ class ContinuousBatchingEngine:
             limit = req.max_new if req.max_new is not None \
                 else max_new_tokens
             useful += K if limit is None \
-                else min(K, max(limit - len(req.outputs), 0))
+                else min(K, max(limit - req.granted, 0))
         return 2 * useful >= K * len(decode_slots)
-
-    def _burst_impl(self, decode_slots, eos_token_id, max_new_tokens,
-                    mon, t0, epoch):
-        """Steady-state fast path: K fused decode iterations, one
-        dispatch, one (2, B) upload, one (B, K) download."""
-        K = self.decode_burst
-        pack = np.empty((2, self.max_batch), np.int32)
-        pack[0] = self._last_tok
-        pack[1] = self.lens
-        burst = self._burst_jit()
-        if self._phase.span is not None:
-            self._phase.attrs = {"n_decode": len(decode_slots), "burst": K}
-        if mon.state.on:
-            self._count_attn_blocks(
-                mon, np.add.outer(self.lens[decode_slots], np.arange(K)),
-                self.max_batch * K)
-        self._note_state_slots(mon, K * len(decode_slots))
-        self._next_phase("serving.dispatch", "burst")
-        toks_dev, self._pools = burst(
-            jnp.asarray(pack), self._pools, self._tables(),
-            self._inner.weights)
-        self._next_phase("serving.wait")
-        if _sanitizers._state.numerics:
-            self._san_steps += 1
-            _sanitizers.numsan_check(
-                "serving.decode_burst",
-                (("tokens", toks_dev), ("kv_pools", self._pools)),
-                step=self._san_steps)
-        toks = np.asarray(toks_dev)            # (B, K) [+ 4 rows of pairs]
-        self._next_phase("serving.route")
-        if len(toks) > self.max_batch and mon.state.on:
-            self._count_expert_pairs(mon, toks[-4:].sum(axis=1), K)
-        if epoch != self._epoch:
-            # superseded mid-dispatch: keep the pools rebind (buffer
-            # validity + the warm radix blocks), apply no host state —
-            # same fence as the mixed step
-            return []
-        t1 = mon.mod.now_ns()
-        nd = len(decode_slots)
-        if mon.tstate.on:
-            for b in decode_slots:
-                entry = self._span_entry(self._slots[b].rid)
-                if entry is not None:
-                    mon.trace.record_span(
-                        "serving.decode_step", t0, t1, parent=entry[0],
-                        attrs={"slot": int(b), "n_active": nd,
-                               "burst": K})
-        finished = []
-        emitted = 0
-        for b in decode_slots:
-            pre = int(self.lens[b])
-            for i in range(K):
-                if self._slots[b] is None:
-                    break               # finished mid-burst: the rest of
-                self.lens[b] += 1       # its lane is discarded
-                emitted += 1
-                self._note_token(b, int(toks[b, i]), eos_token_id,
-                                 max_new_tokens, finished, mon, t1)
-            self._register_decode_blocks(b, pre, mon)
-        if mon.state.on:
-            mon.decode.observe(t1 - t0)
-            mon.tokens.inc(emitted)
-            self._update_gauges(mon)
-            mon.mod.sample()
-        return finished
 
     def _count_expert_pairs(self, mon, pairs, forwards):
         """What a step's program counted (``pairs`` [4]): the (token, expert)
@@ -1955,9 +2205,12 @@ class ContinuousBatchingEngine:
         mon.expert_pairs.labels("expert_calls").inc(
             forwards * e.held_experts * sum("router" in p for p in e.layers))
 
-    def _note_token(self, slot, tok, eos_token_id, max_new_tokens,
+    def _note_token(self, req, slot, tok, last, pos, eos_token_id,
                     finished, mon, t_now):
-        req = self._slots[slot]
+        """Route one output token of ``req`` (in, or lately in, ``slot``);
+        ``last``: it was granted as the request's last (an end by
+        length), ``pos``: what the row holds with it. Returns whether the
+        request ended, by length or by EOS."""
         req.outputs.append(tok)
         # one time per output token: the fetch time of the step that
         # yielded it (tokens of one step share it)
@@ -1966,20 +2219,51 @@ class ContinuousBatchingEngine:
             mon.token_gap.observe(t_now - times[-1])
         times.append(t_now)
         req.last_token = tok
-        self._last_tok[slot] = tok
+        held = self._slots[slot] is req
+        if held:
+            self._last_tok[slot] = tok
         if self._drafter is not None:
             self._drafter.note(req.rid, tok)
-        limit = req.max_new if req.max_new is not None else max_new_tokens
-        done = (eos_token_id is not None and tok == eos_token_id) \
-            or (limit is not None and len(req.outputs) >= limit) \
-            or self.lens[slot] + 1 >= self.max_len
-        if done:
-            finished.append((req.rid, list(req.outputs)))
+        if not last and (eos_token_id is None or tok != eos_token_id):
+            return False
+        finished.append((req.rid, list(req.outputs)))
+        if held:
+            # (an end by EOS: steps dispatched since may have moved the
+            # row on, past the request's end)
+            self.lens[slot] = pos
             self._evict(slot, t_now)
+        else:
+            self._report(req, slot, t_now)
+        return True
+
+    def _release(self, slot):
+        """Free ``slot``: its row in every cache kind and its place in
+        the scheduler's book. Sound as soon as the last step that reads
+        the row has been dispatched (the invariant, ``__init__``)."""
+        self._free_row(slot)
+        self._slots[slot] = None
+        self._active[slot] = False
+        self._decode_ready[slot] = False
+        self.lens[slot] = 0
+        self._tok_src[slot] = -1
+        self._chain_cursors.pop(slot, None)
 
     def _evict(self, slot, t0=None):
-        mon = _mon()
         req = self._slots[slot]
+        # last chance to chain the generation's tail blocks: a finishing
+        # request's final block-crossings happen inside the same routing
+        # loop that evicts it, so register (and pin) them before the row
+        # is freed — a repeated prompt then drafts the WHOLE previous run
+        self._register_decode_blocks(slot, None, _mon())
+        self._release(slot)
+        self._report(req, slot, t0)
+
+    def _report(self, req, slot, t0=None):
+        """The end of a request that holds no slot any more: its stats,
+        its trace tree, the counters."""
+        mon = _mon()
+        req.done = True
+        self._unreported.pop(req.rid, None)
         with self._submit_lock:
             _sanitizers.race_access(self._san_tag, "_req_spans",
                                     write=True)
@@ -1990,17 +2274,6 @@ class ContinuousBatchingEngine:
                 st["tokens"] = len(req.outputs)
                 st["token_times_ns"] = req.token_times
         t0 = t0 or (mon.mod.now_ns() if entry is not None else 0)
-        # last chance to chain the generation's tail blocks: a finishing
-        # request's final block-crossings happen inside the same routing
-        # loop that evicts it, so register (and pin) them before the row
-        # is freed — a repeated prompt then drafts the WHOLE previous run
-        self._register_decode_blocks(slot, None, mon)
-        self._free_row(slot)
-        self._slots[slot] = None
-        self._active[slot] = False
-        self._decode_ready[slot] = False
-        self.lens[slot] = 0
-        self._chain_cursors.pop(slot, None)
         if self._drafter is not None:
             self._drafter.drop(req.rid)
         if entry is not None:
@@ -2029,7 +2302,9 @@ class ContinuousBatchingEngine:
 
     @property
     def num_active(self):
-        return int(self._active.sum())
+        """Requests in a slot; and true while a dispatched step is
+        unfetched, whose routing may still hand a request back."""
+        return int(self._active.sum()) or int(self._flight is not None)
 
     @property
     def num_pending(self):
@@ -2065,6 +2340,11 @@ class ContinuousBatchingEngine:
                         mon.trace.drop(entry[1])
                         mon.trace.end_span(entry[0])
                     n += 1
+        if rids:
+            # not queued: in a slot, or already ended. The step in flight
+            # is routed first (a request that ends there stands), so that
+            # what is dropped below is a request with no token under way
+            self._fetch_flight(then_schedule=True)
         for b in range(self.max_batch):
             req = self._slots[b]
             if req is not None and req.rid in rids:
@@ -2159,10 +2439,18 @@ class ContinuousBatchingEngine:
                 pass           # masks the recovery it documents
             self.last_recovery_dump = path
             aborted = 0
-            for b in range(self.max_batch):
-                req = self._slots[b]
-                if req is None:
-                    continue
+            # the step in flight, and one the driving thread is stuck
+            # fetching, belong to the dead epoch: dropped, as a superseded
+            # step is (step() and _route check its epoch). A request whose
+            # row either released, and which routing had yet to report, is
+            # aborted like those in the slots
+            self._flight = None
+            held = [(b, req) for b, req in enumerate(self._slots)
+                    if req is not None]
+            held += [(None, req) for req in list(self._unreported.values())]
+            self._unreported.clear()
+            for b, req in held:
+                req.done = True
                 # the partial stats ride the typed abort (popped, not
                 # orphaned: nobody ever pops the dead rid's record —
                 # callers track the replacement) so a router can merge
@@ -2183,14 +2471,16 @@ class ContinuousBatchingEngine:
                 if entry is not None:
                     mon.trace.drop(entry[1])
                     mon.trace.end_span(entry[0])
-                self._free_row(b)
-                self._slots[b] = None
+                if b is not None:
+                    self._free_row(b)
+                    self._slots[b] = None
                 if self._drafter is not None:
                     self._drafter.drop(req.rid)
             self._active[:] = False
             self._decode_ready[:] = False
             self.lens[:] = 0
             self._last_tok[:] = 0
+            self._tok_src[:] = -1
             self._lane_cache.clear()
             self._chain_cursors.clear()
             # NOT torn down: the compiled programs (still valid), the
@@ -2280,7 +2570,7 @@ class ContinuousBatchingEngine:
         eos, max_new, poll = self._drive_args
         while not self._drive_stop.is_set():
             try:
-                if not (self._active.any() or self.num_pending):
+                if not (self.num_active or self.num_pending):
                     time.sleep(poll)
                     continue
                 # chaos drills kill the driving thread here, right before

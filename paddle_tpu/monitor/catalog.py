@@ -84,20 +84,31 @@ METRICS = {
         "paddle_tpu_serving_step_phase_ns_total's four phases."),
     "paddle_tpu_serving_step_phase_ns_total": (
         "counter", ("phase", "kind"),
-        "Nanoseconds of completed engine steps, by phase (schedule = "
-        "step() entry to just before the jitted call; dispatch = the "
-        "jitted call's enqueue and pack upload; wait = the blocking "
-        "result fetch: device execution + download; route = after the "
-        "fetch to step() return) and step kind (mixed | burst). The "
-        "four phases share their edges and cover the whole step; a step "
-        "that returns early (no active lane, superseded epoch, a raise) "
-        "counts nothing."),
+        "Nanoseconds of step() calls, by phase (schedule = step() entry "
+        "to just before the jitted call; dispatch = the jitted call's "
+        "enqueue and pack upload; wait = the blocking result fetch: what "
+        "is left of device execution + download; route = after the "
+        "fetch to step() return) and step kind (mixed | burst). A call "
+        "keeps one step in flight: schedule and dispatch carry the kind "
+        "of the step they PREPARE, wait and route the kind of the step "
+        "they FETCH (dispatched a call earlier; in the same call where "
+        "the depth is 0). The phases share their edges and cover the "
+        "whole call; a call that returns early (no active lane and no "
+        "step in flight, superseded epoch, a raise) counts nothing."),
     "paddle_tpu_serving_steps_total": (
         "counter", ("kind",),
         "Completed engine steps by kind: mixed (one token per lane, "
         "prefill chunks aboard) | burst (decode_burst fused decode "
-        "iterations). Counted with the phase nanoseconds, once per "
-        "step() that ran its program and routed the result."),
+        "iterations). Counted once per step, when its result is routed."),
+    "paddle_tpu_serving_dispatch_total": (
+        "counter", ("ahead",),
+        "Engine steps dispatched, by whether the step before was still "
+        "unfetched: ahead=yes, the device had the next program queued "
+        "while the host fetched and routed; ahead=no, a first step, or "
+        "one at depth 0 (a drafter, the numerics sanitizer) or behind "
+        "an early fetch (a preemption or an unfunded grant, cancel of "
+        "an active request, a decode_burst change). yes / (yes + no) is "
+        "steps_dispatched_ahead_share.sat."),
     "paddle_tpu_serving_attn_blocks_total": (
         "counter", ("extent",),
         "KV blocks under the paged attention of completed engine steps: "
@@ -544,23 +555,28 @@ SPANS = {
         "prompt tokens of one request packed alongside the decode lanes "
         "(child of serving.request). attrs: slot, start, tokens."),
     "serving.pack_tokens": (
-        "The SCHEDULE phase of one engine step, mixed or burst (child of "
-        "serving.step): step() entry — cancellations, admission-queue "
-        "drain, admission, block grants, pack assembly — to just before "
-        "the jitted call. attrs: n_decode, n_draft, n_prefill, budget "
-        "(mixed) or n_decode, burst (burst)."),
+        "The SCHEDULE phase of one step() call, for the step it PREPARES, "
+        "mixed or burst (child of serving.step): step() entry — "
+        "cancellations, admission-queue drain, admission, block grants, "
+        "pack assembly — to just before the jitted call. attrs: n_decode, "
+        "n_draft, n_prefill, budget (mixed) or n_decode, burst (burst)."),
     "serving.dispatch": (
-        "The DISPATCH phase of one engine step (child of serving.step): "
-        "the jitted call to its return — enqueue and upload of the pack; "
-        "on a cold engine, the trace + compile."),
+        "The DISPATCH phase of one step() call (child of serving.step): "
+        "the jitted call of the step it prepares to the end of the "
+        "scheduler's book-keeping for it — enqueue and upload of the "
+        "pack, the composition of the lanes' tokens on the device, the "
+        "start of the download; on a cold engine, the trace + compile."),
     "serving.wait": (
-        "The WAIT phase of one engine step (child of serving.step): the "
-        "blocking fetch of the program's result to its return — device "
-        "execution + download; the host does nothing else."),
+        "The WAIT phase of one step() call (child of serving.step): the "
+        "blocking fetch of the result of the step it FETCHES, dispatched "
+        "a call earlier (the same call at depth 0), to its return — what "
+        "is left of device execution + download; the host does nothing "
+        "else, the device has the next program queued."),
     "serving.route": (
-        "The ROUTE phase of one engine step (child of serving.step): "
-        "after the fetch to step() return — token routing, evictions, "
-        "prefix registration, gauges and monitor.sample()."),
+        "The ROUTE phase of one step() call (child of serving.step): "
+        "after the fetch to step() return — the fetched step's token "
+        "routing, the finished requests' reports, gauges and "
+        "monitor.sample()."),
     "serving.decode_step": (
         "One engine step as seen by ONE decoding request, recorded per "
         "active request (n_active copies of one interval a step) so each "
@@ -571,11 +587,12 @@ SPANS = {
         "Slot eviction: block free + host state clear (child of "
         "serving.request). attrs: slot, tokens."),
     "serving.step": (
-        "One whole engine step, OPEN while the step runs — the span a "
+        "One whole step() call, OPEN while it runs — the span a "
         "flight dump names when the driving thread hangs or dies "
         "mid-step; parent of the four phases serving.pack_tokens -> "
-        "serving.dispatch -> serving.wait -> serving.route, which share "
-        "their edges and sum to it. attrs: engine."),
+        "serving.dispatch (the step it prepares) -> serving.wait -> "
+        "serving.route (the step it fetches), which share their edges "
+        "and sum to it. attrs: engine."),
     "serving.recover": (
         "One engine recovery pass: flight dump, in-flight aborts "
         "(typed RequestAborted with partial tokens), warm restart from "

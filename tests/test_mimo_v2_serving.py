@@ -175,6 +175,37 @@ def test_engine_against_the_reference_on_logits(fault):
         assert gap > 2 * TOLERANCE
 
 
+def _dispatched(eng):
+    """Record the kinds of the steps ``eng`` dispatches, in order."""
+    kinds, dispatch = [], eng._dispatch
+
+    def spy(plan, *rest):
+        kinds.append(plan[0])
+        return dispatch(plan, *rest)
+
+    eng._dispatch = spy
+    return kinds
+
+
+def test_one_step_in_flight_serves_the_tokens_and_steps_of_depth_0():
+    """ISSUE 37: the engine dispatches step N + 1 before it fetches step N
+    (window blocks released, expert pairs counted and rows handed on at
+    dispatch). The same engine with every step fetched in the call that
+    dispatched it (depth 0: the engine before) serves the same tokens by
+    the same steps, and both are the reference's."""
+    prompts = _prompts()
+    eng, eng0 = _engine(), _engine()
+    eng0._depth = lambda: 0
+    kinds, kinds0 = _dispatched(eng), _dispatched(eng0)
+    served, _ = _serve(eng, prompts)
+    served0, _ = _serve(eng0, prompts)
+    assert [o.tolist() for o in served] == [o.tolist() for o in served0]
+    assert kinds == kinds0 and {"mixed", "burst"} <= set(kinds)
+    assert any(a != b for a, b in zip(kinds, kinds[1:]))
+    assert _widest_gap(prompts, served) < TOLERANCE
+    assert eng._flight is None and eng0._flight is None
+
+
 def test_lockstep_generate_and_forward_agree_with_the_engine():
     """The same block under lockstep prefill + decode (``generate``) and the
     model's own cache-free forward: greedy tokens equal the engine's."""

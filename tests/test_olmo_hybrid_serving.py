@@ -154,6 +154,38 @@ def test_the_engine_serves_the_references_tokens(reference, burst):
     assert _worst_gap(reference, prompts, answers) < TOL
 
 
+def _dispatched(eng):
+    """Record the kinds of the steps ``eng`` dispatches, in order."""
+    kinds, dispatch = [], eng._dispatch
+
+    def spy(plan, *rest):
+        kinds.append(plan[0])
+        return dispatch(plan, *rest)
+
+    eng._dispatch = spy
+    return kinds
+
+
+def test_one_step_in_flight_serves_the_tokens_and_steps_of_depth_0(reference):
+    """ISSUE 37: the engine dispatches step N + 1 before it fetches step N
+    (state slots noted and handed on at dispatch: a slot's next request
+    starts from zeros in a step that runs behind its last request's last).
+    The same engine with every step fetched in the call that dispatched it
+    (depth 0: the engine before) serves the same tokens by the same steps,
+    and both are the reference's."""
+    prompts = _prompts()
+    eng, eng0 = _engine(), _engine()
+    eng0._depth = lambda: 0
+    kinds, kinds0 = _dispatched(eng), _dispatched(eng0)
+    answers, _ = _serve(eng, prompts)
+    answers0, _ = _serve(eng0, prompts)
+    assert [a.tolist() for a in answers] == [a.tolist() for a in answers0]
+    assert kinds == kinds0 and {"mixed", "burst"} <= set(kinds)
+    assert any(a != b for a, b in zip(kinds, kinds[1:]))
+    assert _worst_gap(reference, prompts, answers) < TOL
+    assert eng._flight is None and eng0._flight is None
+
+
 def test_a_slots_second_request_is_served_as_by_a_fresh_engine():
     """max_batch 1: every request but the first takes over a slot whose state
     and kept convolution inputs another request left."""

@@ -497,10 +497,13 @@ class TestTenants:
             assert active == ({g} if strict else {g, b})
             while strict and g not in done:
                 assert eng.num_pending == 1
-                assert [s.rid for s in eng._slots if s is not None] == [g]
+                # gold alone, until its last token is dispatched and its
+                # row released (ISSUE 37: a call before it is handed back)
+                assert [s.rid for s in eng._slots if s is not None] \
+                    in ([g], [])
                 done.update(eng.step())
-            if strict:                   # gold evicted: the next step admits
-                done.update(eng.step())
+            if strict:      # gold's row was free: the call that handed
+                #             gold back admitted bronze before it routed
                 assert [s.rid for s in eng._slots if s is not None] == [b]
             done.update(_run_all(eng))
             assert len(done[g]) == 6 and len(done[b]) == 3
@@ -608,13 +611,16 @@ class TestKVSpill:
             assert eng.lens[[s is not None and s.rid == ridB
                              for s in eng._slots].index(True)] > 0
             # next step's decode grant explodes: A must keep decoding,
-            # so mid-prefill B is the preemption victim
-            fi.arm("paged_kv.ensure", action="flag", nth=1)
+            # so mid-prefill B is the preemption victim. It explodes
+            # twice: behind the first the engine fetches the step in
+            # flight (ISSUE 37: what a preemption copies must be on the
+            # host) and schedules anew, behind the second it preempts
+            fi.arm("paged_kv.ensure", action="flag", nth=1, times=2)
             for _ in range(400):
                 done.update(eng.step())
                 if not (eng.num_active or eng.num_pending):
                     break
-            assert fi.trips() == [("paged_kv.ensure", "flag")]
+            assert fi.trips() == [("paged_kv.ensure", "flag")] * 2
             snap = monitor.snapshot()["metrics"]
             assert snap["paddle_tpu_serving_preemptions_total"][
                 "values"][""] >= 1

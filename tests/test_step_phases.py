@@ -9,7 +9,9 @@ Contracts under test:
    shared instant;
 2. the engine's four phases (``serving.pack_tokens`` -> ``dispatch`` ->
    ``wait`` -> ``route``) are consecutive children of ``serving.step`` and
-   sum to it, for a mixed step and for a burst;
+   sum to it, for a mixed step and for a burst; since ISSUE 37 a call
+   prepares one step (schedule, dispatch) and fetches the one before it
+   (wait, route): tests/test_step_in_flight.py holds the order of events;
 3. ``paddle_tpu_serving_steps_total`` / ``..._step_phase_ns_total`` move by
    exactly one step's worth per ``step()`` and not at all for a step that
    returns early;
@@ -183,10 +185,28 @@ def _one_step_of(kind):
     return eng
 
 
+def _in_flight(kind):
+    """An engine with one step of ``kind`` in flight whose next call
+    dispatches another of that kind (ISSUE 37: a call prepares one step
+    and fetches the one before it), warmed up."""
+    eng = _engine()
+    _warm(eng)
+    if kind == "mixed":
+        # 13 prompt tokens: a chunk of 8 (in flight), then one of 5
+        eng.submit(np.arange(1, 14, dtype=np.int32), max_new_tokens=12)
+        eng.step()
+    else:
+        eng.submit(np.array([1, 2, 3, 4, 5], np.int32), max_new_tokens=12)
+        eng.step()                      # the prefill: a mixed step
+        eng.step()                      # the first burst; fetches the prefill
+    assert eng._flight.kind == kind
+    return eng
+
+
 class TestEnginePhases:
     @pytest.mark.parametrize("kind", ["mixed", "burst"])
     def test_four_consecutive_phases_sum_to_the_step(self, kind):
-        eng = _one_step_of(kind)
+        eng = _in_flight(kind)
         trace.enable()
         eng.step()
         trace.disable()
@@ -211,7 +231,7 @@ class TestEnginePhases:
 
     @pytest.mark.parametrize("kind", ["mixed", "burst"])
     def test_counters_move_by_exactly_one_steps_worth(self, kind):
-        eng = _one_step_of(kind)
+        eng = _in_flight(kind)
         monitor.enable()
         trace.enable()
         steps0 = _counter("paddle_tpu_serving_steps_total")
@@ -229,7 +249,7 @@ class TestEnginePhases:
         assert sum(moved.values()) == by["serving.step"].duration_ns
 
     def test_monitor_alone_counts_without_writing_a_span(self):
-        eng = _one_step_of("burst")
+        eng = _in_flight("burst")
         monitor.enable()
         eng.step()
         eng.step()
@@ -420,6 +440,56 @@ class TestAttnBlocks:
         assert value == pytest.approx(100.0 * 5 / 9)
         assert note[self.LANES] == {"path=tiled": 5.0, "path=lane": 4.0}
 
+    def test_the_benchmarks_metric_file_reads_the_dispatch_counter(self):
+        """``steps_dispatched_ahead_share.sat`` (ISSUE 37) is data: the
+        accepted reader ``counter_share`` over this PR's counter. A request
+        served from an empty engine: its first step follows nothing, every
+        other one is dispatched while the step before it is unfetched."""
+        import importlib.util
+        import json
+        import os
+
+        bench = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks")
+        with open(os.path.join(
+                bench, "metrics",
+                "steps_dispatched_ahead_share.sat.json")) as f:
+            spec = json.load(f)
+        assert spec["reader"] == "counter_share"
+        assert catalog.spec(spec["params"]["counter"])[:2] == (
+            "counter", ("ahead",))
+        with open(os.path.join(os.path.dirname(bench),
+                               "BENCHMARK.json")) as f:
+            (entry,) = [m for m in json.load(f)["per_layer"]
+                        if m["name"] == "steps_dispatched_ahead_share.sat"]
+        assert entry["layer"] == "serving scheduler" and \
+            entry["moves"] == "serve_tokens_per_s" and len(
+                entry["workloads"]) == 3
+        mod = importlib.util.spec_from_file_location(
+            "bench_counter_share",
+            os.path.join(bench, "readers", "counter_share.py"))
+        reader = importlib.util.module_from_spec(mod)
+        mod.loader.exec_module(reader)
+        monitor.reset()
+        # (the parent of this PR has no such counter: nothing, and no raise)
+        assert reader.read({}, spec["params"], {}) is None
+        eng = _engine()
+        _warm(eng)
+        monitor.enable()
+        eng.submit(np.array([1, 2, 3, 4, 5], np.int32), max_new_tokens=12)
+        calls = 0
+        while eng.num_active or eng.num_pending:
+            eng.step()
+            calls += 1
+        monitor.disable()
+        # the last call dispatches nothing: calls - 1 steps, the first of
+        # them behind an empty engine
+        value, note = reader.read({}, spec["params"], {})
+        assert calls == 5
+        assert value == pytest.approx(100.0 * 3 / 4)
+        assert note["paddle_tpu_serving_dispatch_total"] == {
+            "ahead=no": 1.0, "ahead=yes": 3.0}
+
     def test_kind_blocks_under_a_window_kind(self):
         """``attn_kind_blocks_total`` of a window layer: a lane of its own
         from its window's first block, a tile from its FIRST lane's window's
@@ -494,7 +564,8 @@ class TestTokenTimes:
         _warm(eng)
         rid = eng.submit(np.array([1, 2, 3], np.int32), max_new_tokens=20)
         eng.step()
-        eng.step()
+        eng.step()                      # routes the prefill's token
+        eng.step()                      # ... and the first burst's four
         eng.recover(reason="test")
         (rec,) = eng.pop_aborted()
         assert rec.rid == rid and len(rec.tokens) >= 2
@@ -523,7 +594,7 @@ def test_a_profiled_slice_holds_the_step_and_its_phases_on_a_host_plane(
         tmp_path):
     import jax
 
-    eng = _one_step_of("mixed")
+    eng = _in_flight("mixed")
     monitor.enable()                 # the monitor alone, as a traced bench run
     jax.profiler.start_trace(str(tmp_path))
     try:

@@ -299,7 +299,13 @@ def test_mixed_step_of_an_expert_model_holds_the_grouped_kernels(
         2 * layers
     assert kernels.count("held_experts_gmm_up") >= layers
     assert kernels.count("held_experts_gmm_down") >= layers
-    assert "ragged-dot" not in kernels and "ragged_dot" not in kernels
+    # (the instructions alone: the text's table of stack frames names every
+    # function a cached trace came through, a test of tests/
+    # test_grouped_matmul.py named ..._against_ragged_dot among them when
+    # that file ran before in this process)
+    ops = "\n".join(line for line in kernels.splitlines() if " = " in line)
+    assert "held_experts_gmm_up" in ops
+    assert "ragged-dot" not in ops and "ragged_dot" not in ops
 
 
 @pytest.mark.parametrize("lanes,planned", [(32, False), (288, True)],
