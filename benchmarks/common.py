@@ -88,18 +88,29 @@ def load_weights(model, made):
 class SliceTracer:
     """Profile a short slice of the window into ``directory`` and reduce it.
 
-    ``read_at_edges``, where a driver sets it, is called at the two instants
-    at which the profiler starts and stops (before the one, after the other's
-    clock is read), and ``edges`` keeps what it returned: what the program has
-    counted at each edge of the slice."""
+    The slice is MEASURED on the device's clock (``xtrace.window_seconds``:
+    first operation's start to last operation's end); ``t_start`` and
+    ``t_stop`` are the host's clock after the profiler has started and before
+    it is stopped, which only time the slice for the driver.
+
+    ``rest``, where a driver sets it, is called before each edge and returns
+    once the device has run everything dispatched to it, so that the slice is
+    cut where the device is at rest. ``read_at_edges``, where a driver sets
+    it, is called at the two edges, after that rest (before the profiler
+    starts; before the host's clock is read and the profiler stopped), and
+    ``edges`` keeps what it returned: what the program has counted at each
+    edge of the slice."""
 
     def __init__(self, directory):
         self.directory = directory
         self.t_start = self.t_stop = None
+        self.rest = None
         self.read_at_edges = None
         self.edges = []
 
     def _read_edge(self):
+        if self.rest is not None:
+            self.rest()
         if self.read_at_edges is not None:
             self.edges.append(self.read_at_edges())
 
@@ -113,10 +124,32 @@ class SliceTracer:
     def stop(self):
         import jax
 
-        self.t_stop = time.perf_counter()
         self._read_edge()
+        self.t_stop = time.perf_counter()
         jax.profiler.stop_trace()
 
     @property
     def running(self):
         return self.t_start is not None and self.t_stop is None
+
+
+def device_rest(device):
+    """A wait for whatever has been dispatched to ``device``: a trivial
+    program on an array that already lives there, blocked on. One device runs
+    what one thread dispatched in order, so it ends after everything queued
+    before it; nothing of the program under test is reached into. The first
+    call compiles, so it is made here, in set-up."""
+    import jax
+    import numpy as np
+
+    @jax.jit
+    def bench_rest(x):
+        return x + 1
+
+    x = jax.device_put(np.zeros((), np.int32), device)
+
+    def rest():
+        jax.block_until_ready(bench_rest(x))
+
+    rest()
+    return rest

@@ -17,13 +17,33 @@ The model and its weights are the cell's builder's (``ctx["builder"]``); the
 engine's settings come from the configuration's ``engine`` group. Traffic
 parameters: see the generator, plus ``prime_steps``, ``trace_seconds``,
 ``check_requests``, ``grace_seconds`` and ``counters`` (the program's monitor
-counters whose increase over the window a traced run reads). A traced run
-also reads every counter series of the program's monitor at the two instants
-at which the profiler starts and stops, both between two steps with the device
-at rest, and hands the increase to the readers as ``slice_counters``: what the
-program counted for exactly the steps whose operations the trace holds. Times
-in the records are seconds from the window's start (negative: before it
-opened).
+counters whose increase over the window a traced run reads). Times in the
+records are seconds from the window's start (negative: before it opened).
+
+**The traced slice and its edges.** The engine keeps one step in flight (PR
+37), so between two calls of ``step()`` the device is busy. Before the
+profiler starts, and again before it is stopped, a traced run therefore waits
+for whatever is queued on the device (``common.device_rest``: a trivial
+program of the benchmark's own, blocked on; nothing of the engine is reached
+into), and reads every counter series of the program's monitor at those two
+instants. The step in flight at the first edge ends BEFORE the trace begins;
+every step dispatched between the edges, the one in flight at the last edge
+too, runs INSIDE it. The readers get the increase as ``slice_counters``:
+
+- what the program adds when it DISPATCHES a step (``attn_blocks_total``,
+  ``attn_kind_blocks_total``, ``attn_lanes_total``, ``linear_runs_total``,
+  ``linear_tokens_total``, ``dispatch_total``) is counted for exactly the
+  steps whose operations the trace holds;
+- what it adds when it ROUTES a step, one call later (``steps_total``,
+  ``generated_tokens_total``, ``expert_pairs_total``), lags by the one step
+  in flight: the same NUMBER of steps, moved by one (the first edge's step in
+  flight is in, the last edge's is not).
+
+``notes.slice_dispatches`` is the first kind's step count, to lay beside the
+serving programs on the trace's ``XLA Modules`` line (``run.py`` prints them
+as ``programs``): equal, where the wait does what it is for. The wait costs
+the device one start-up gap at each edge, both outside the slice as the
+device's clock measures it; a traced run reports no end-to-end metric.
 """
 from __future__ import annotations
 
@@ -37,6 +57,7 @@ import compare
 import stats
 
 WARMUP_MAX_NEW = 8        # tokens asked of the warm-up prompt: two bursts
+DISPATCHES = "paddle_tpu_serving_dispatch_total"    # one a dispatched step
 
 
 def submit_all(eng, offered):
@@ -54,9 +75,11 @@ def submit_all(eng, offered):
 
 def step_until(eng, by_rid, stop, samples, tracer=None, trace_from=None):
     """``eng.step()`` until ``stop(now, steps_made)`` or the engine runs dry.
-    Fills the records of what finishes; ``samples`` gets (lanes in use, seconds)
-    of each step. The profiler starts at ``trace_from`` and is stopped by the
-    caller. Returns the number of steps made."""
+    Fills the records of what finishes; ``samples`` gets (requests in a slot
+    after the call, requests queued, requests it handed back, seconds) of each
+    call (``stats.lanes_in_use`` makes a step's lanes of them). The profiler
+    starts at ``trace_from`` and is stopped by the caller. Returns the number
+    of steps made."""
     n = 0
     while eng.num_active or eng.num_pending:
         now = time.perf_counter()
@@ -69,7 +92,8 @@ def step_until(eng, by_rid, stop, samples, tracer=None, trace_from=None):
             finished = eng.step()
         t_done = time.perf_counter()
         n += 1
-        samples.append((eng.num_active + len(finished), t_done - now))
+        samples.append((eng.num_active, eng.num_pending, len(finished),
+                        t_done - now))
         for rid, tokens in finished:
             rec = by_rid[rid]
             rec["finish"] = t_done
@@ -248,6 +272,7 @@ def run(ctx):
         counters = {name: (lambda c=monitor.counter(name): c.value)
                     for name in traffic.get("counters", [])}
         tracer.read_at_edges = counter_series
+        tracer.rest = common.device_rest(jax.devices()[0])
     compiles_before = ctx["compiles"].n
 
     ctx["mark_window_start"]()
@@ -270,16 +295,20 @@ def run(ctx):
                                  "n_prompt", "asked")}
               for r in attempted]
     inside = _finished_inside(attempted, w["window_s"])
-    step_s = sorted(t for _, t in w["occupancy_samples"])
-    sliced = {}
+    step_s = sorted(call[-1] for call in w["occupancy_samples"])
+    sliced, slice_notes = {}, {}
     if tracer is not None and len(tracer.edges) == 2:
         sliced = {"slice_counters": increase(*tracer.edges),
                   "slice_seconds": tracer.t_stop - tracer.t_start}
+        dispatched = sliced["slice_counters"].get(DISPATCHES)
+        if dispatched:                   # a program without the counter: no note
+            slice_notes = {"slice_dispatches": sum(dispatched.values())}
     return {
         **sliced,
         "attempted": len(attempted), "failed": len(failed),
         "window_s": w["window_s"], "records": public, "counters": w["counters"],
-        "occupancy_samples": [n for n, _ in w["occupancy_samples"]],
+        "occupancy_samples": stats.lanes_in_use(
+            [call[:3] for call in w["occupancy_samples"]]),
         "max_batch": int(cfg["engine"]["max_batch"]),
         "n_params": n_params, "memory_peak_bytes": peak,
         "numbers": numbers,
@@ -300,7 +329,7 @@ def run(ctx):
                   "in_flight_at_close": len(attempted) - len(inside),
                   "checked_requests": sample["requests"] if sample else 0,
                   "checked_tokens": int(sample["valid"].sum()) if sample else 0,
-                  "kv_pool_bytes": kv_pool_bytes},
+                  "kv_pool_bytes": kv_pool_bytes, **slice_notes},
     }
 
 

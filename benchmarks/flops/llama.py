@@ -71,11 +71,12 @@ def paged_attention_costs(cfg, engine, counters):
     each such block of K and of V once, at the pool's item size; plus one
     query row read and one output row written per lane that read, for the
     FEWEST lanes that can have read that many blocks (a lane reads at most
-    its whole table row; the program counts no lanes). A lane that pads a
-    step, and a block that the 128 lanes of a prefill chunk each read again
-    though they share a table row, ARE counted by the program and so are
-    here: what the kernel moves above its algorithm's least is its loss, not
-    this count's. FLOPs: QK^T and PV, 4 x query heads x head_dim for every
+    its whole table row; this count takes no lanes from the program). The
+    program counts what the kernels' DMAs bring in: a lane of its own, its
+    blocks; the lanes of a prefill chunk, which share a table row and form a
+    query tile since PR 33, each block from the tile's first to its last
+    ONCE (until then every one of the 128 lanes counted them again). What the
+    kernel moves above that is its loss, not this count's. FLOPs: QK^T and PV, 4 x query heads x head_dim for every
     position of a block read (a lane's last block counts whole, up to
     block_size - 1 positions more than it attends to: at one FLOP a byte
     against the chip's 240 this side never bounds the kernel)."""

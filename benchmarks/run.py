@@ -217,9 +217,17 @@ def main(argv=None, fault=None):
         planes = trace_mod.load(xplane)
         dev = trace_mod.device_ops(planes, rehearsal=args.rehearse)
         env["device_ops"] = dev
+        # busy and window are both the device's clock (first operation's start
+        # to last operation's end), so busy <= window whatever the profiler's
+        # edges did; the host's clock between them is a note, for laying old
+        # readings (1 - busy / host slice) beside new ones, and divides nothing
         device["busy_s"] = trace_mod.busy_seconds(dev)
-        device["window_s"] = tracer.t_stop - tracer.t_start
+        device["window_s"] = trace_mod.window_seconds(dev)
         env["busy_s"], env["traced_window_s"] = device["busy_s"], device["window_s"]
+        print(json.dumps({"traced_slice": {
+            "busy_s": device["busy_s"], "device_window_s": device["window_s"],
+            "host_slice_s": tracer.t_stop - tracer.t_start,
+            "programs": trace_mod.programs(planes)}}), flush=True)
         result["breakdown"] = {
             "device_ops": trace_mod.top_ops(dev),
             "idle_gaps": trace_mod.idle_gaps(dev, planes["host"])}

@@ -50,6 +50,32 @@ def finished_tokens_per_s(records, window_s):
                and 0.0 < r["finish"] <= window_s) / window_s
 
 
+def lanes_in_use(calls):
+    """Lanes in use in each step, from what the driver saw after each call of
+    ``step()``: ``[(requests in a slot, requests queued, requests handed
+    back)]``. The engine keeps one step in flight (PR 37): a call dispatches
+    step N + 1 and hands back the requests that ended in step N, whose rows it
+    released when it dispatched their last token. So the lanes of the step a
+    call dispatched are the requests still in a slot after that call plus
+    those that the NEXT call hands back; the two numbers of ONE call belong to
+    different steps, and their sum reads ``max_batch + 1`` now and then. The
+    last call's step has no next call here and is left out.
+
+    ``num_active`` reads 1 also where NO request is in a slot and a step is in
+    flight (every lane of it ended). Which of the two a 1 means follows from
+    the call before: what was in a slot then, plus what left the queue, less
+    what the dispatched step released (the next call's hand-back)."""
+    lanes, before = [], None
+    for (active, queued, _), (_, _, handed_back) in zip(calls, calls[1:]):
+        if active == 1 and before is not None:
+            held = before[0] + before[1] - queued - handed_back
+            if held in (0, 1):
+                active = held
+        lanes.append(active + handed_back)
+        before = (active, queued)
+    return lanes
+
+
 def occupancy(samples, max_batch):
     """Mean over the window's steps of lanes in use / lanes."""
     if not samples:

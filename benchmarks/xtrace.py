@@ -1,9 +1,12 @@
 """Reduction of a jax profiler trace (``*.xplane.pb``) to what the per-layer
-metrics read: device operations with their intervals, the busy union, idle gaps
-named by the host span that covers them, and the top lists of ``breakdown``.
+metrics read: device operations with their intervals, the busy union and the
+window it lies in (both on the DEVICE's clock), idle gaps named by the host
+span that covers them, the programs that ran, and the top lists of
+``breakdown``.
 
 Device operations are the events of the line ``XLA Ops`` of every plane named
-``/device:TPU:<n>``. A rehearsal on the CPU has no such plane; there (and only
+``/device:TPU:<n>``; the programs that ran are the events of its line ``XLA
+Modules``. A rehearsal on the CPU has no such plane; there (and only
 when asked) the host-side events that carry an ``hlo_op`` stat stand in, so
 that the code path is exercised, never so that a number is reported. Host spans
 are the ``bench.*`` ``TraceAnnotation`` events of the host plane. A trace that
@@ -12,12 +15,14 @@ an empty result for a trace it could not make sense of, and nothing is capped.
 """
 from __future__ import annotations
 
+import collections
 import glob
 import os
 import re
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
 HOST_PREFIX = "bench."
 
 
@@ -35,7 +40,8 @@ def find_xplane(directory):
 
 def load(path):
     """Planes of the file as plain data:
-    ``{"device": {chip: [(name, start_ns, dur_ns, stats)]}, "host": [...]}``."""
+    ``{"device": {chip: [(name, start_ns, dur_ns, stats)]}, "modules": {chip:
+    [name]}, "host": [...]}``."""
     import jax
 
     if os.path.getsize(path) == 0:
@@ -47,7 +53,7 @@ def load(path):
         raise TraceError(f"cannot read {path}: {e}") from e
     if not planes:
         raise TraceError(f"{path} holds no plane: truncated or not a trace")
-    device, host, host_ops = {}, [], []
+    device, modules, host, host_ops = {}, {}, [], []
     for plane in planes:
         m = DEVICE_PLANE.match(plane.name)
         for line in plane.lines:
@@ -55,6 +61,9 @@ def load(path):
                 device.setdefault(int(m.group(1)), []).extend(
                     (e.name, float(e.start_ns), float(e.duration_ns),
                      dict(e.stats)) for e in line.events)
+            elif m and line.name == MODULES_LINE:
+                modules.setdefault(int(m.group(1)), []).extend(
+                    e.name for e in line.events)
             elif plane.name.startswith("/host:"):
                 for e in line.events:
                     if e.name.startswith(HOST_PREFIX):
@@ -65,8 +74,8 @@ def load(path):
                         if "hlo_op" in stats:
                             host_ops.append((e.name, float(e.start_ns),
                                              float(e.duration_ns), stats))
-    return {"device": device, "host": host, "host_ops": host_ops,
-            "planes": [p.name for p in planes]}
+    return {"device": device, "modules": modules, "host": host,
+            "host_ops": host_ops, "planes": [p.name for p in planes]}
 
 
 def device_ops(planes, rehearsal=False):
@@ -98,6 +107,26 @@ def busy_seconds(dev):
     per_chip = [sum(e - s for s, e in union((st, st + du) for _, st, du, _ in ops))
                 for ops in dev.values()]
     return sum(per_chip) / len(per_chip) * 1e-9
+
+
+def window_seconds(dev):
+    """Seconds from the start of a chip's first operation to the end of its
+    last, averaged over the chips as ``busy_seconds`` averages: the traced
+    slice on the device's own clock. The busy union lies inside it chip by
+    chip, so ``busy_seconds(dev) <= window_seconds(dev)`` whatever the host's
+    clock read when the profiler started and stopped."""
+    per_chip = [max(st + du for _, st, du, _ in ops) - min(st for _, st, _, _ in ops)
+                for ops in dev.values()]
+    return sum(per_chip) / len(per_chip) * 1e-9
+
+
+def programs(planes):
+    """``{program: events}`` of the first chip's ``XLA Modules`` line, a
+    program's name cut before its fingerprint (``jit_step(123)`` is
+    ``jit_step``): how often each compiled program ran inside the trace."""
+    modules = planes["modules"]
+    return dict(collections.Counter(
+        name.split("(", 1)[0] for name in (modules[min(modules)] if modules else [])))
 
 
 def short_name(name, width=96):
@@ -172,11 +201,11 @@ def describe(path, limit=12):
         for line in plane.lines:
             events = list(line.events)
             rows.append(f"  LINE {line.name!r}: {len(events)} events")
-            seen = {}
+            seen, count = {}, collections.Counter(e.name for e in events)
             for e in events:
                 seen.setdefault(e.name, e)
             for name, e in list(seen.items())[:limit]:
-                rows.append(f"    {name[:90]!r} start={e.start_ns} "
+                rows.append(f"    {name[:90]!r} x{count[name]} start={e.start_ns} "
                             f"dur={e.duration_ns} stats={dict(e.stats)}"[:600])
             calls = {}
             for e in events:

@@ -167,6 +167,21 @@ def test_serve_mfu_counts_prefill_where_the_first_token_falls_and_decode_by_shar
 def test_train_rate_counts_all_steps_over_the_whole_window():
     assert stats.train_tokens_per_s(40, 8192, 32.0) == pytest.approx(10240.0)
     assert stats.occupancy([16, 8, 16, 8], 16) == pytest.approx(0.75)
+    # one step in flight: 4 lanes full in every step, a request ends in the
+    # steps dispatched by calls 1 and 3 and is handed back one call later, its
+    # row re-given at once from the queue; the two numbers of one call (in a
+    # slot, handed back) sum to 5 twice
+    calls = [(4, 9, 0), (3, 9, 0), (4, 8, 1), (3, 8, 0), (4, 7, 1)]
+    assert [a + f for a, _, f in calls].count(5) == 2
+    assert stats.lanes_in_use(calls) == [4, 4, 4, 4]
+    assert stats.occupancy(stats.lanes_in_use(calls), 4) == 1.0
+    # a step in which ALL four lanes end: num_active then reads 1 for "a step
+    # is in flight" though no request is in a slot, and the queue's loss says so
+    calls = [(4, 9, 0), (1, 9, 0), (4, 5, 4), (4, 5, 0)]
+    assert stats.lanes_in_use(calls) == [4, 4, 4]
+    # where one request does stay in its slot, 1 means one
+    calls = [(4, 9, 0), (1, 9, 0), (4, 6, 3), (4, 6, 0)]
+    assert stats.lanes_in_use(calls) == [4, 4, 4]
 
 
 # -- FLOP counts against hand counts ------------------------------------------
@@ -258,6 +273,60 @@ def test_trace_reduction_on_a_tpu_shaped_trace(tpu_slice):
     reader = U.load("readers", "device_idle_share")
     assert reader.read({}, {}, {"busy_s": 1.0, "traced_window_s": 4.0}) == 75.0
     assert reader.read({}, {}, {"busy_s": None}) is None
+
+
+def test_the_traced_window_is_the_devices_own_first_start_to_last_end(tpu_slice):
+    """Busy and window on one clock: the window is each chip's first
+    operation's start to its last one's end, the busy union lies inside it, and
+    the idle share is the gaps between operations, never under nought. A
+    HOST window shorter than the trace, which is what the profiler's edges
+    gave while a step was in flight at both (PR 37), would have read a device
+    busier than its window."""
+    planes = xtrace.load(str(tpu_slice))
+    dev = xtrace.device_ops(planes)
+    # [0, 2] [2, 3] [2.5, 3.5] . . [6, 8] [8, 9] us: 6.5 busy in 9
+    assert xtrace.window_seconds(dev) == pytest.approx(9000e-9)
+    busy, window = xtrace.busy_seconds(dev), xtrace.window_seconds(dev)
+    assert busy <= window
+    gaps = sum(s for _, s in xtrace.idle_gaps(dev, planes["host"]))
+    assert window - busy == pytest.approx(gaps) == pytest.approx(2500e-9)
+    reader = U.load("readers", "device_idle_share")
+    env = {"busy_s": busy, "traced_window_s": window}
+    assert reader.read({}, {}, env) == pytest.approx(100.0 * 2.5 / 9.0)
+    assert xtrace.programs(planes) == {"jit_step": 1}
+    # the old formula over a host window of 6 us that the events overhang
+    host_window = 6000e-9
+    assert 1.0 - busy / host_window < 0 <= reader.read({}, {}, env)
+    # two chips: each its own first start to last end, then the mean, as the
+    # busy seconds are averaged; a chip whose operations abut idles nought
+    two = {0: dev[0], 1: [("a", 100.0, 50.0, {}), ("b", 150.0, 250.0, {}),
+                          ("c", 120.0, 30.0, {})]}
+    assert xtrace.window_seconds(two) == pytest.approx((9000 + 300) / 2 * 1e-9)
+    assert xtrace.busy_seconds(two) == pytest.approx((6500 + 300) / 2 * 1e-9)
+    one = {1: two[1]}
+    assert xtrace.busy_seconds(one) == xtrace.window_seconds(one)
+    assert reader.read({}, {}, {"busy_s": xtrace.busy_seconds(one),
+                                "traced_window_s": xtrace.window_seconds(one)}) == 0.0
+
+
+@pytest.mark.parametrize("overhang_ns", [0.0, 400.0, 1500.0])
+def test_no_overhang_of_the_hosts_window_reads_a_share_under_nought(overhang_ns):
+    """A saturated slice (operations back to back with 10 ns between) that
+    begins ``overhang_ns`` before the host's window and ends as long after
+    it: the new share is the gaps' and the same whatever the overhang; the
+    old one falls under nought as soon as the overhang outweighs the gaps."""
+    n, op, gap = 200, 990.0, 10.0
+    ops = [("op", -overhang_ns + i * (op + gap), op, {}) for i in range(n)]
+    dev = {0: ops}
+    host_window_ns = n * (op + gap) - 2 * overhang_ns
+    busy, window = xtrace.busy_seconds(dev), xtrace.window_seconds(dev)
+    assert busy == pytest.approx(n * op * 1e-9) and busy <= window
+    new = U.load("readers", "device_idle_share").read(
+        {}, {}, {"busy_s": busy, "traced_window_s": window})
+    assert new == pytest.approx(100.0 * (n - 1) * gap / (n * op + (n - 1) * gap))
+    assert 0.0 <= new <= 100.0
+    old = 100.0 * (1.0 - busy / (host_window_ns * 1e-9))
+    assert (old < 0.0) == (2 * overhang_ns > n * gap)
 
 
 def test_trace_reduction_raises_on_a_truncated_unreadable_or_empty_trace(

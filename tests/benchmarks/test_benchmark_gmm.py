@@ -1,9 +1,10 @@
 """The two metrics of the held experts' grouped Pallas kernels (ISSUE 35), on a
 made-up trace and made-up counters: the kernels' roofline share picks
-``%held_experts_gmm*`` events and is blind to ``%ragged-dot-none`` (and the
-older metric the other way round, over the SAME least work), and the row
-tiles' fill is ``held`` over ``kernel_rows``, or nothing where the program
-has no such series."""
+``%held_experts_gmm*`` events and is blind to ``%ragged-dot-none`` (the older
+metric ``moe_experts_roofline.mix``, whose rule picked those over the SAME
+least work, read nothing from PR 35 on and went in PR 39: a rule that matches
+no event returns nothing), and the row tiles' fill is ``held`` over
+``kernel_rows``, or nothing where the program has no such series."""
 import json
 import os
 
@@ -53,8 +54,8 @@ def test_the_new_roofline_reads_the_kernels_events_by_name(slice_env):
     reader = U.load("readers", "kernel_roofline_counted")
     spec = _metric("moe_experts_gmm_roofline.mix")
     assert spec["reader"] == "kernel_roofline_counted"
-    assert spec["params"]["costs"] == _metric(
-        "moe_experts_roofline.mix")["params"]["costs"]
+    assert spec["params"]["costs"] == {"module": "mimo_v2_flash",
+                                       "function": "held_experts_costs"}
     # 2 forward passes x 16 held experts hit, 10 pairs each, 512 tile rows
     raw = _counted(held=320, experts_hit=32, routed=5120, kernel_rows=512)
     value, note = reader.read(raw, spec["params"], slice_env)
@@ -65,11 +66,19 @@ def test_the_new_roofline_reads_the_kernels_events_by_name(slice_env):
     assert value == pytest.approx(100.0 * nbytes / 819e9 / 2.4e-3)
     assert 80 < value < 85
     assert note[PAIRS]["where=kernel_rows"] == 512.0
-    # the older metric reads the one ragged-dot event against the same work
-    old, old_note = reader.read(
-        raw, _metric("moe_experts_roofline.mix")["params"], slice_env)
+    # another rule over the same costs reads its own events against the same
+    # work (the one leftover ragged-dot), and a rule that matches no event
+    # returns nothing: never 0 for a share of a roofline
+    other = dict(spec["params"], calls={
+        "ragged_dot": {"name_regex": "^%ragged-dot-none"}})
+    old, old_note = reader.read(raw, other, slice_env)
     assert old_note["events"] == 1 and old_note["bytes"] == nbytes
     assert old == pytest.approx(value * 2.4 / 0.8)
+    nothing = dict(spec["params"], calls={
+        "gone": {"name_regex": "^%no_such_kernel"}})
+    assert reader.read(raw, nothing, slice_env) is None
+    assert not os.path.exists(os.path.join(
+        U.BENCH, "metrics", "moe_experts_roofline.mix.json"))
 
 
 def test_the_new_roofline_returns_nothing_where_no_kernel_ran(slice_env):

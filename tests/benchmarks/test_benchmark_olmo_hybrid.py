@@ -153,6 +153,10 @@ def test_the_new_metrics_list_the_cell_and_name_their_readers():
 
 
 # -- planted faults ------------------------------------------------------------
+# The in-process rehearsals' window: alone 80 requests finish in 2 s, beside
+# three busy JAX processes a step takes 0.6-0.9 s and 4 finish in 8 s (PERF.md
+# 7, q17); ``run_cell_with_fault`` tries 4 and 16 times as long where none did.
+WINDOW_S = 8
 def _decay_dropped(eng):
     import jax.numpy as jnp
 
@@ -216,13 +220,17 @@ def test_a_planted_fault_reads_not_correct(restore, fault):
                "beta_not_doubled": _beta_not_doubled,
                "state_not_reset": _state_not_reset(restore),
                "conv_not_carried": _conv_not_carried(restore)}[fault]
-    res, err = U.run_cell_with_fault(CELL, 2 ** 31 + 99, 2, planted)
+    res, err = U.run_cell_with_fault(CELL, 2 ** 31 + 99, WINDOW_S, planted)
     assert res["correct"] is False
     c = res["compared"]["token_logit_gap"]
-    assert c["value"] > c["limit"]
+    # a gap read from served tokens: a window in which nothing finished reads
+    # inf, which is over any limit and says nothing about the fault
+    assert np.isfinite(c["value"]) and c["value"] > c["limit"]
     assert "NOT OK" in err
 
 
 def test_the_unbroken_cell_reads_correct_in_process():
-    res, _ = U.run_cell_with_fault(CELL, 2 ** 31 + 99, 2, lambda eng: None)
+    res, _ = U.run_cell_with_fault(CELL, 2 ** 31 + 99, WINDOW_S,
+                                   lambda eng: None)
     assert res["correct"] is True
+    assert np.isfinite(res["compared"]["token_logit_gap"]["value"])

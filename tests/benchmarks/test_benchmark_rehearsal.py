@@ -1,6 +1,9 @@
 """One rehearsal of each cell end to end, as the driver runs it, and the rest
 of a run with the timed path broken underneath: ``correct`` has to come out
 false for each fault the cell can have."""
+import functools
+import json
+
 import numpy as np
 import pytest
 
@@ -31,9 +34,15 @@ def test_rehearsal_prints_the_contracts_line(cell):
     assert res["compared"]["compiled_in_window"]["value"] == 0
 
 
+@functools.lru_cache(maxsize=None)
+def _traced_rehearsal(cell):
+    """One traced rehearsal a cell, read by every test that needs one."""
+    return U.run_cell(cell, 12345, 4, trace=1)
+
+
 @pytest.mark.parametrize("cell", CELLS)
 def test_traced_rehearsal_reads_the_per_layer_metrics_and_the_breakdown(cell):
-    rc, res, out, err = U.run_cell(cell, 12345, 4, trace=1)
+    rc, res, out, err = _traced_rehearsal(cell)
     assert rc == 0, err[-3000:]
     assert res["correct"] is True
     # what needs a published peak (mfu, roofline) has nothing to read on the CPU
@@ -43,6 +52,28 @@ def test_traced_rehearsal_reads_the_per_layer_metrics_and_the_breakdown(cell):
     assert res["device"]["busy_s"] > 0 and res["device"]["window_s"] > 0
     assert 0 < len(res["breakdown"]["device_ops"]) <= 10
     assert len(res["breakdown"]["idle_gaps"]) <= 10
+    # busy and window are one clock's, the operations': never busier than
+    # the window, whatever the host's clock read at the profiler's edges
+    assert res["device"]["busy_s"] <= res["device"]["window_s"]
+    said = next(json.loads(line)["traced_slice"] for line in out.splitlines()
+                if line.startswith('{"traced_slice"'))
+    assert said["busy_s"] == res["device"]["busy_s"]
+    assert said["device_window_s"] == res["device"]["window_s"]
+    assert said["host_slice_s"] > 0 and isinstance(said["programs"], dict)
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_every_share_of_a_traced_rehearsal_lies_between_0_and_100(cell):
+    """The contract of a unit ``%``, over every per-layer metric the traced
+    rehearsal reads: none under 0 (an idle share of a device busier than its
+    window) and none over 100."""
+    rc, res, out, err = _traced_rehearsal(cell)
+    assert rc == 0, err[-3000:]
+    shares = {name: m["value"] for name, m in res["metrics"].items()
+              if m["unit"] == "%"}
+    assert shares and any(n.startswith("device_idle_share") for n in shares)
+    for name, value in shares.items():
+        assert 0.0 <= value <= 100.0, (name, value)
 
 
 def _train_cell():
@@ -108,7 +139,7 @@ def test_an_altered_served_token_reads_not_correct():
     res, err = U.run_cell_with_fault(_serve_cell(), 99, 2, _altered_token)
     assert res["correct"] is False
     c = res["compared"]["token_logit_gap"]
-    assert c["value"] > c["limit"]
+    assert np.isfinite(c["value"]) and c["value"] > c["limit"]
 
 
 def test_the_unbroken_path_reads_correct_in_process():
@@ -169,6 +200,62 @@ def test_slice_counters_are_the_increase_between_the_profilers_start_and_stop(
         < whole["paddle_tpu_serving_attn_blocks_total"]["values"]["extent=read"]
     assert raw["slice_seconds"] == pytest.approx(tracer.t_stop - tracer.t_start)
     assert raw["slice_seconds"] < raw["window_s"]
+    # the driver gave the tracer a wait of its own for both edges, and what the
+    # program counted at DISPATCH is the slice's steps too: every call between
+    # the edges dispatched one
+    assert tracer.rest is not None
+    assert raw["notes"]["slice_dispatches"] == in_slice
+
+
+def test_the_tracer_waits_for_the_device_before_it_reads_each_edge(
+        tmp_path, monkeypatch):
+    """The order of calls at the two edges, recorded on the CPU: the wait for
+    the device, then the counters, then the profiler's start; the wait, then
+    the counters, then its stop. Without a wait or a reader it is the
+    profiler alone."""
+    import jax
+
+    run = U.load("", "run")
+    run.set_environment(True)
+    import common
+
+    calls = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda directory: calls.append("start_trace"))
+    monkeypatch.setattr(jax.profiler, "stop_trace",
+                        lambda: calls.append("stop_trace"))
+    tracer = common.SliceTracer(str(tmp_path))
+    tracer.rest = lambda: calls.append("rest")
+    tracer.read_at_edges = lambda: calls.append("read") or len(calls)
+    assert not tracer.running
+    tracer.start()
+    assert tracer.running and tracer.t_stop is None
+    tracer.stop()
+    assert calls == ["rest", "read", "start_trace",
+                     "rest", "read", "stop_trace"]
+    assert tracer.edges == [2, 5] and not tracer.running
+    assert tracer.t_stop >= tracer.t_start
+    calls.clear()
+    bare = common.SliceTracer(str(tmp_path))
+    bare.start()
+    bare.stop()
+    assert calls == ["start_trace", "stop_trace"] and bare.edges == []
+
+
+def test_the_wait_for_the_device_compiles_in_set_up_and_never_again():
+    run = U.load("", "run")
+    run.set_environment(True)
+    import jax
+
+    import common
+
+    compiles = common.Compiles()
+    rest = common.device_rest(jax.devices()[0])
+    made = compiles.n
+    assert made >= 1                     # its one program, warmed up at once
+    for _ in range(3):
+        assert rest() is None
+    assert compiles.n == made            # nothing compiles at an edge
 
 
 def test_an_untraced_run_reads_no_slice_counters_and_never_turns_the_monitor_on():
