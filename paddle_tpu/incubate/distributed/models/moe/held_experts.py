@@ -19,10 +19,15 @@ dropped, and there is no capacity. What multiplies is picked by a rule that
 reads only the inputs (``_kernel_applies``): on a TPU, at bfloat16 or float32
 and widths that fill whole 128-lane rows, the Pallas kernels of
 ``ops/pallas/grouped_matmul.py`` (``held_experts_gmm_up``: gate and up side by
-side with the SwiGLU in float32 in VMEM; ``held_experts_gmm_down``), over rows
-gathered with every expert's group padded to whole row tiles; everywhere else
-(the CPU, narrow test widths, other dtypes) three ``jax.lax.ragged_dot`` calls
-over the sorted rows as they are, which is the kernels' reference.
+side with the SwiGLU in float32 in VMEM; ``held_experts_gmm_down``). Their
+rows are every expert's group padded to whole row tiles, and the layer hands
+them the row tiles' plan and each sorted pair's token and weight, never rows
+of activations: ``up`` reads each real row of a tile in use from ``h`` by its
+token, ``down`` adds its weighted product into its token's row of the
+float32 output, and nothing here holds the padded layout. Everywhere else
+(the CPU, narrow test widths, other dtypes) three ``jax.lax.ragged_dot``
+calls over the sorted rows gathered from ``h`` by token, weighted and
+scatter-added by token: the kernels' reference.
 """
 from __future__ import annotations
 
@@ -48,7 +53,11 @@ def route_sigmoid_topk(h, router, bias, top_k):
         h.astype(jnp.float32), router.astype(jnp.float32),
         precision=lax.Precision.HIGHEST))
     _, experts = lax.top_k(scores + bias.astype(jnp.float32), top_k)
-    chosen = jnp.take_along_axis(scores, experts, axis=-1)
+    # the chosen scores picked by comparison: XLA runs a gather of a few
+    # thousand scalars as a loop on the TPU
+    mine = experts[..., None] == jnp.arange(scores.shape[-1],
+                                            dtype=experts.dtype)
+    chosen = jnp.where(mine, scores[:, None, :], 0.0).sum(-1)
     return experts.astype(jnp.int32), chosen / chosen.sum(-1, keepdims=True)
 
 
@@ -58,14 +67,15 @@ def _kernel_applies(h, w1, w2):
     experts' matrices in ONE dtype, bfloat16 or float32, hidden size and
     expert width whole multiples of the 128-lane row (a weight panel is cut
     in 128s; a row tile is the dtype's sublane tile whatever the rows), and a
-    panel of the whole contraction inside the kernels' VMEM. Everything read
-    here is visible in the inputs: no flag picks the path."""
+    panel of the whole contraction, with the tokens' activations and output
+    panel, inside the kernels' VMEM. Everything read here is visible in the
+    inputs: no flag picks the path."""
     hidden, width = w1.shape[1:]
     return (jax.devices()[0].platform == "tpu"
             and h.dtype in (jnp.bfloat16, jnp.float32)
             and w1.dtype == h.dtype and w2.dtype == h.dtype
             and hidden % 128 == 0 and width % 128 == 0
-            and gmm.fits(hidden, width, h.dtype))
+            and gmm.fits(h.shape[0], hidden, width, h.dtype))
 
 
 @jax.named_scope("held_experts")
@@ -88,38 +98,42 @@ def held_experts_mlp(h, router, bias, w1, w3, w2, lo, top_k, valid=None):
     # pairs on absent experts sort behind the last group and are never
     # multiplied: both products stop at the groups' total
     group = jnp.where(here, local, held).astype(jnp.int32)
-    order = jnp.argsort(group, stable=True)
-    sizes = jnp.zeros(held + 1, jnp.int32).at[group].add(1)[:held]
-    # the rows as the product takes them: each group padded to whole row
-    # tiles for the kernels, the sorted rows themselves (tiles of one row)
-    # for ragged_dot
-    kernel = _kernel_applies(h, w1, w2)
-    tm = gmm.row_tile(h.dtype) if kernel else 1
-    plan = gmm.plan_row_tiles(sizes, tm, M)
-    at = jnp.arange(gmm.padded_rows(M, held, tm), dtype=jnp.int32)
-    tile, k = at // tm, at % tm
-    real = k < plan["n"][tile]
-    pair = order[jnp.minimum(plan["row0"][tile] + k, M - 1)]
-    token = pair // top_k
-    xs = h[token]                                          # [rows, hidden]
-    if kernel:
-        y = gmm.gmm_down(gmm.gmm_up(xs, w1, w3, plan), w2, plan)
+    # each pair's token and weight ride the sort, so nothing is gathered by
+    # the order; the groups' sizes and the experts hit are counted by
+    # comparison, so nothing is scatter-added (XLA runs either as a loop on
+    # the TPU)
+    _, token, weight = lax.sort(
+        (group, jnp.arange(M, dtype=jnp.int32) // top_k, weights.reshape(-1)),
+        num_keys=1, is_stable=True)
+    mine = group[:, None] == jnp.arange(held, dtype=jnp.int32)[None, :]
+    sizes = mine.sum(0, dtype=jnp.int32)
+    if _kernel_applies(h, w1, w2):
+        # each group padded to whole row tiles; the kernels read a tile's
+        # real rows from h and add their weighted products into out, by token
+        tm = gmm.row_tile(h.dtype)
+        plan = gmm.plan_row_tiles(sizes, tm, M)
+        out = gmm.gmm_down(gmm.gmm_up(h, w1, w3, plan, token), w2, plan,
+                           token, weight, T)
+        rows = plan["tiles"] * tm
     else:
+        # the sorted rows themselves (tiles of one row)
+        xs = h[token]                                      # [M, hidden]
         a = lax.ragged_dot(xs, w1, sizes)
         b = lax.ragged_dot(xs, w3, sizes)
         y = lax.ragged_dot((jax.nn.silu(a) * b).astype(h.dtype), w2, sizes)
-    w = jnp.where(real, weights.reshape(-1)[pair], 0.0)
-    # (rows no group owns hold whatever the product left there)
-    y = jnp.where((w > 0)[:, None], y.astype(jnp.float32) * w[:, None], 0.0)
-    out = jnp.zeros((T, h.shape[1]), jnp.float32).at[token].add(y)
+        rows = sizes.sum()
+        w = jnp.where(jnp.arange(M) < rows, weight, 0.0)
+        # (rows no group owns hold whatever the product left there)
+        y = jnp.where((w > 0)[:, None], y.astype(jnp.float32) * w[:, None],
+                      0.0)
+        out = jnp.zeros((T, h.shape[1]), jnp.float32).at[token].add(y)
     counted = here.reshape(T, top_k)
     if valid is not None:
         counted = counted & valid[:, None]
         routed = valid.sum() * top_k
     else:
         routed = T * top_k
-    hit = jnp.zeros(held + 1, jnp.int32).at[
-        jnp.where(counted.reshape(-1), group, held)].add(1)[:held] > 0
+    hit = (mine & counted.reshape(-1, 1)).any(0)
     pairs = jnp.stack([counted.sum(), routed, hit.sum(),
-                       plan["tiles"] * tm]).astype(jnp.int32)
+                       rows]).astype(jnp.int32)
     return out.astype(h.dtype), pairs

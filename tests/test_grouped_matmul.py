@@ -69,35 +69,43 @@ def _close(got, want, dtype):
                                rtol=tol)
 
 
+def _own(rows):
+    """Each sorted row its own token, at weight 1: ``gmm_down`` then leaves
+    every sorted row's product in that row."""
+    return jnp.arange(rows, dtype=jnp.int32), jnp.ones(rows, jnp.float32)
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
 @pytest.mark.parametrize("case", list(GROUPS))
 def test_plain_product_against_ragged_dot(case, dtype):
-    """``gmm_down`` (one streamed matrix): every row a group owns is what
-    ``ragged_dot`` gives for it, in the activations' dtype."""
+    """``gmm_down`` (one streamed matrix), each sorted row its own token at
+    weight 1: every row a group owns is what ``ragged_dot`` gives for it,
+    from the float32 accumulator; the rows behind the groups are zero."""
     sizes, rows = GROUPS[case]
     xs, w1, _w3, sizes = _operands(dtype, sizes, rows)
     xp, plan, real, src = _padded(xs, sizes, gmm.row_tile(dtype))
-    got = gmm.gmm_down(xp, w1, plan)
-    assert got.dtype == dtype and got.shape == (xp.shape[0], N)
+    got = gmm.gmm_down(xp, w1, plan, *_own(rows), rows)
+    assert got.dtype == jnp.float32 and got.shape == (rows, N)
     assert int(plan["tiles"]) == sum(-(-s // gmm.row_tile(dtype))
                                      for s in GROUPS[case][0])
     want = lax.ragged_dot(xs, w1, sizes, preferred_element_type=jnp.float32)
-    assert real.sum() == sum(GROUPS[case][0])
-    _close(np.asarray(got, np.float32)[real], np.asarray(want)[src[real]],
-           dtype)
+    total = sum(GROUPS[case][0])
+    assert real.sum() == total
+    _close(got[:total], np.asarray(want)[:total], dtype)
+    assert not np.asarray(got[total:]).any()
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
 @pytest.mark.parametrize("case", list(GROUPS))
 def test_fused_swiglu_against_silu_of_ragged_dots(case, dtype):
-    """``gmm_up``: gate and up side by side, ``silu(a) * b`` on the float32
-    accumulators."""
+    """``gmm_up``, each sorted row read from ``h`` at its own token: gate and
+    up side by side, ``silu(a) * b`` on the float32 accumulators."""
     sizes, rows = GROUPS[case]
     xs, w1, w3, sizes = _operands(dtype, sizes, rows, seed=1)
-    xp, plan, real, src = _padded(xs, sizes, gmm.row_tile(dtype))
-    got = gmm.gmm_up(xp, w1, w3, plan)
+    _, plan, real, src = _padded(xs, sizes, gmm.row_tile(dtype))
+    got = gmm.gmm_up(xs, w1, w3, plan, _own(rows)[0])
     a = lax.ragged_dot(xs, w1, sizes, preferred_element_type=jnp.float32)
     b = lax.ragged_dot(xs, w3, sizes, preferred_element_type=jnp.float32)
     want = jax.nn.silu(a) * b
@@ -114,32 +122,119 @@ def test_every_panel_width_gives_the_same_product(monkeypatch, panel_bytes,
     sizes, rows = GROUPS["shorter-and-longer-than-a-tile"]
     xs, w1, w3, sizes = _operands(jnp.float32, sizes, rows, seed=2)
     xp, plan, real, _ = _padded(xs, sizes, 8)
-    want = np.asarray(gmm.gmm_up(xp, w1, w3, plan))[real]
-    want_down = np.asarray(gmm.gmm_down(xp, w1, plan))[real]
+    token, ones = _own(rows)
+    want = np.asarray(gmm.gmm_up(xs, w1, w3, plan, token))[real]
+    want_down = np.asarray(gmm.gmm_down(xp, w1, plan, token, ones, rows))
     monkeypatch.setattr(gmm, "_PANEL_BYTES", panel_bytes)
     assert gmm._panel(K, N, jnp.float32) == tn
     np.testing.assert_allclose(
-        np.asarray(gmm.gmm_up(xp, w1, w3, plan))[real], want, atol=1e-5,
-        rtol=1e-5)
+        np.asarray(gmm.gmm_up(xs, w1, w3, plan, token))[real], want,
+        atol=1e-5, rtol=1e-5)
     np.testing.assert_allclose(
-        np.asarray(gmm.gmm_down(xp, w1, plan))[real], want_down, atol=1e-5,
-        rtol=1e-5)
+        np.asarray(gmm.gmm_down(xp, w1, plan, token, ones, rows)), want_down,
+        atol=1e-5, rtol=1e-5)
 
 
 def test_rows_no_group_owns_change_no_counted_row():
-    """The padding of a tile and everything behind the last tile in use may
-    hold anything (NaN here): a row is its own product."""
+    """The padding of a tile, everything behind the last tile in use, and
+    the rows of ``h`` that no real row names may hold anything (NaN here):
+    a row is its own product, and a padding row is neither read nor
+    added."""
     sizes, rows = GROUPS["shorter-and-longer-than-a-tile"]
     xs, w1, w3, sizes = _operands(jnp.bfloat16, sizes, rows, seed=3)
     clean, plan, real, _ = _padded(xs, sizes, 16)
     dirty, _, _, _ = _padded(xs, sizes, 16, fill=np.nan)
     assert np.isnan(np.asarray(dirty, np.float32)).any()
-    for run in (lambda x: gmm.gmm_up(x, w1, w3, plan),
-                lambda x: gmm.gmm_down(x, w1, plan)):
-        got = np.asarray(run(dirty), np.float32)[real]
-        np.testing.assert_array_equal(
-            got, np.asarray(run(clean), np.float32)[real])
-        assert np.isfinite(got).all()
+    token, ones = _own(rows)
+    total = sum(GROUPS["shorter-and-longer-than-a-tile"][0])
+    h_dirty = xs.at[total:].set(np.nan)
+    got = np.asarray(gmm.gmm_up(h_dirty, w1, w3, plan, token), np.float32)
+    np.testing.assert_array_equal(
+        got[real], np.asarray(gmm.gmm_up(xs, w1, w3, plan, token),
+                              np.float32)[real])
+    assert np.isfinite(got[real]).all()
+    got = np.asarray(gmm.gmm_down(dirty, w1, plan, token, ones, rows))
+    np.testing.assert_array_equal(
+        got, np.asarray(gmm.gmm_down(clean, w1, plan, token, ones, rows)))
+    assert np.isfinite(got).all()
+
+
+# -- rows in by token, out by token: the kernels against the layer's formula --
+def _formula(h, w1, w3, w2, sizes, token, weight):
+    """What the expert layer computed around ``ragged_dot`` before the rows
+    moved into the kernels: the sorted rows gathered from ``h`` by token,
+    the three grouped products, each row weighted from float32 (rows behind
+    the groups dropped) and scatter-added into its token's row."""
+    xs = h[token]
+    a = lax.ragged_dot(xs, w1, sizes, preferred_element_type=jnp.float32)
+    b = lax.ragged_dot(xs, w3, sizes, preferred_element_type=jnp.float32)
+    y = lax.ragged_dot((jax.nn.silu(a) * b).astype(h.dtype), w2, sizes,
+                       preferred_element_type=jnp.float32)
+    w = jnp.where(jnp.arange(token.shape[0]) < sizes.sum(), weight, 0.0)
+    y = jnp.where((w > 0)[:, None], y * w[:, None], 0.0)
+    return jnp.zeros((h.shape[0], w2.shape[2]), jnp.float32).at[token].add(y)
+
+
+def _routed(dtype, case, top_k=4, seed=4):
+    """A case's sorted rows as pairs of ``top_k`` a token: each sorted row a
+    token (a permutation of the pairs), a routing weight, ``h`` and the
+    three matrices."""
+    sizes, rows = GROUPS[case]
+    rng = np.random.default_rng(seed)
+    T = rows // top_k
+    token = jnp.asarray(rng.permutation(rows) // top_k, jnp.int32)
+    weight = jnp.asarray(rng.uniform(0.05, 1.0, rows), jnp.float32)
+    h = jnp.asarray(rng.normal(size=(T, K)), dtype)
+    w1, w3 = (jnp.asarray(rng.normal(size=(len(sizes), K, N)) * 0.05, dtype)
+              for _ in range(2))
+    w2 = jnp.asarray(rng.normal(size=(len(sizes), N, K)) * 0.05, dtype)
+    return h, w1, w3, w2, jnp.asarray(sizes, jnp.int32), token, weight
+
+
+def _by_token(h, w1, w3, w2, sizes, token, weight):
+    plan = gmm.plan_row_tiles(sizes, gmm.row_tile(h.dtype), token.shape[0])
+    return gmm.gmm_down(gmm.gmm_up(h, w1, w3, plan, token), w2, plan, token,
+                        weight, h.shape[0])
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("case", list(GROUPS))
+def test_rows_in_and_out_by_token_against_the_formula(case, dtype):
+    """``gmm_up`` reading each real row from ``h`` by its token and
+    ``gmm_down`` adding its weighted product into its token's row give what
+    the layer's formula gives around ``ragged_dot``; a step whose groups are
+    empty gives exact zeros."""
+    ops = _routed(dtype, case)
+    got = _by_token(*ops)
+    assert got.dtype == jnp.float32 and got.shape == (ops[0].shape[0], K)
+    _close(got, _formula(*ops), dtype)
+    if not sum(GROUPS[case][0]):
+        assert not np.asarray(got).any()
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_nan_in_rows_no_tile_in_use_reads_reaches_no_output(dtype):
+    """NaN in the rows of ``h`` whose tokens have no pair in a group (the
+    sorted rows behind the groups name them, and the padding of the tiles
+    does too): the output equals the clean one's, and those tokens' rows are
+    zero."""
+    h, w1, w3, w2, sizes, token, weight = _routed(
+        dtype, "shorter-and-longer-than-a-tile")
+    total = int(sizes.sum())
+    # the tokens of the sorted rows behind the groups own no row in a group
+    token = jnp.concatenate([token[:total] % 8,
+                             8 + jnp.arange(token.shape[0] - total) % 16])
+    read = np.zeros(h.shape[0], bool)
+    read[np.asarray(token[:total])] = True
+    dirty = jnp.where(jnp.asarray(read)[:, None], h, np.nan).astype(dtype)
+    got = np.asarray(_by_token(dirty, w1, w3, w2, sizes, token, weight))
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(
+        got, np.asarray(_by_token(h, w1, w3, w2, sizes, token, weight)))
+    assert not got[~read].any()
+    _close(got, _formula(h, w1, w3, w2, sizes, token, weight), dtype)
 
 
 def test_the_plan_at_one_row_a_tile_is_the_sorted_rows():
@@ -232,6 +327,69 @@ def test_kernels_and_ragged_dot_give_one_layer(monkeypatch, dtype):
     assert int(n_kernel[3]) == sum(-(-s // tm) * tm for s in sizes)
 
 
+# (lo, held, a shift of the held experts' selection bias, which lanes are
+# valid): the routing a step of the layer can see
+STEPS = {
+    "no-pair-on-a-held-expert": (2, 4, -50.0, None),
+    "every-pair-on-held-experts": (0, 8, 0.0, None),
+    "a-token-with-several-held-pairs": (2, 4, 50.0, None),
+    "valid-masks-lanes": (2, 4, 0.0, "some"),
+    "traced-lo": (None, 4, 0.0, None),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("step", list(STEPS))
+def test_the_layer_by_token_against_its_formula(monkeypatch, step, dtype):
+    """The layer with the kernels forced (rows in and out by token) against
+    the same layer on ``ragged_dot`` (the sorted rows gathered, weighted and
+    scatter-added): the same sum a token and the same three counts, whatever
+    the routing: no pair on a held expert (the output exactly zero, no row
+    visited), every pair on one (the static worst case), every token with
+    all its pairs on held experts, lanes left out of the counts by
+    ``valid``, and ``lo`` traced."""
+    lo, held, shift, valid = STEPS[step]
+    rng = np.random.default_rng(6)
+    top, T = 3, 16
+    h = jnp.asarray(rng.normal(size=(T, 128)), dtype)
+    p = _layer(rng, 128, 128, 8, dtype)
+    if valid:
+        valid = jnp.asarray(rng.random(T) < 0.6)
+    chosen = {}
+
+    def run(kernel, at):
+        monkeypatch.setattr(held_experts, "_kernel_applies",
+                            lambda *a: kernel)
+        bias = p["mlp.gate.e_score_correction_bias"].at[at:at + held].add(
+            shift)
+        chosen[at] = np.asarray(held_experts.route_sigmoid_topk(
+            h, p["mlp.gate.weight"], bias, top)[0])
+        w = tuple(p["mlp.experts." + n][at:at + held]
+                  for n in ("gate_proj", "up_proj", "down_proj"))
+        layer = jax.jit(lambda h, w, lo: held_experts.held_experts_mlp(
+            h, p["mlp.gate.weight"], bias, *w, lo, top, valid))
+        return layer(h, w, jnp.int32(at))
+
+    for at in ([lo] if lo is not None else [0, 2, 4]):
+        want, n_want = run(False, at)
+        got, n_got = run(True, at)
+        _close(got, want, dtype)
+        np.testing.assert_array_equal(n_got[:3], n_want[:3])
+        here = (chosen[at] >= at) & (chosen[at] < at + held)
+        assert int(n_got[0]) == (here & (np.asarray(valid)[:, None]
+                                         if valid is not None else True)).sum()
+        if step == "no-pair-on-a-held-expert":
+            assert not here.any() and not np.asarray(got).any()
+            assert np.asarray(n_got).tolist() == [0, T * top, 0, 0]
+        if step == "every-pair-on-held-experts":
+            assert here.all() and int(n_got[0]) == T * top
+        if step == "a-token-with-several-held-pairs":
+            assert (here.sum(1) == top).all()
+        if step == "valid-masks-lanes":
+            assert 0 < int(n_got[1]) < T * top
+
+
 def test_the_rule_reads_platform_dtypes_and_widths(monkeypatch):
     """The kernels on a TPU at lane-aligned widths in one dtype, bfloat16 or
     float32; ``ragged_dot`` everywhere else. Nothing but the inputs is
@@ -249,6 +407,9 @@ def test_the_rule_reads_platform_dtypes_and_widths(monkeypatch):
     assert held_experts._kernel_applies(h, w1, w2)
     assert held_experts._kernel_applies(*f32)
     assert held_experts._kernel_applies(h[:1], w1, w2)           # any rows
+    # ... while the tokens' float32 activations and output stay in VMEM
+    assert not held_experts._kernel_applies(
+        jax.ShapeDtypeStruct((65536, 256), jnp.bfloat16), w1, w2)
     assert not held_experts._kernel_applies(h, f32[1], w2)       # mixed
     assert not held_experts._kernel_applies(
         *(a.astype(jnp.float16) for a in (h, w1, w2)))

@@ -315,6 +315,44 @@ def test_the_shares_of_the_expert_layer_add_up_to_the_uncut_layer():
     assert float(jnp.abs(parts[0] - whole).max()) > 1e-3
 
 
+def test_the_kernels_serve_what_the_plain_path_serves_and_count_as_before(
+        monkeypatch):
+    """A tiny lane-aligned MiMo-shaped engine (``test_grouped_matmul.CFG``:
+    hidden and expert width 128, float32) over mixed steps and bursts: with
+    the grouped kernels forced (interpret mode), the rows entering and
+    leaving them by token, the same greedy tokens as the ``ragged_dot``
+    path, and every series of the experts' counter moves by what the layer
+    counted before the rows moved into the kernels (pinned: the same steps
+    run with the padded gather and scatter-add around the kernels read the
+    same numbers)."""
+    from test_grouped_matmul import CFG as ALIGNED, _serve
+
+    model = B.construct(ALIGNED)
+    common.load_weights(model, B.weights(11, ALIGNED, "float32"))
+    model.eval()
+    rng = np.random.default_rng(41)
+    prompts = [(rng.integers(0, ALIGNED["vocab_size"], n, dtype=np.int32), k)
+               for n, k in [(20, 6), (7, 10), (11, 4)]]
+    want, plain = _serve(model, prompts)
+    from paddle_tpu.incubate.distributed.models.moe import held_experts
+
+    monkeypatch.setattr(held_experts, "_kernel_applies", lambda *a: True)
+    got, moved = _serve(model, prompts)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    # (this module draws the selection bias at 0.5: see ``_model``)
+    assert {k: m["where=kernel_rows"] for k, m in moved.items()} == {
+        "mixed": 80.0, "burst": 112.0}
+    assert {k: m["where=kernel_rows"] for k, m in plain.items()} == {
+        "mixed": 35.0, "burst": 36.0}
+    for kind in ("mixed", "burst"):
+        for where in ("held", "routed", "experts_hit", "expert_calls"):
+            assert moved[kind]["where=" + where] == \
+                plain[kind]["where=" + where]
+    assert {k: m["where=held"] for k, m in moved.items()} == {
+        "mixed": 34.0, "burst": 25.0}
+
+
 # -- the kernel against the plain path, in interpret mode ----------------------
 @pytest.mark.parametrize("sink", [False, True], ids=["no-sink", "sink"])
 @pytest.mark.parametrize("window", [None, 8, 9, 16, 20])

@@ -30,7 +30,11 @@ from paddle_tpu.models.serving import ContinuousBatchingEngine  # noqa: E402
 PINS = {
     "llama": ("1bb25ada74c6c66c", "767f4a8e4a8fd4cb"),
     "llama-int8": ("f6ee04e3cab45503", "f6e7d7fa95f9ecb7"),
-    "mimo": ("77fdf965d88234f2", "991274bb9bf7cc47"),
+    # the expert layer's sort carries each pair's token and weight, and its
+    # counts are comparisons where they were scatter-adds (the rows move into
+    # and out of the grouped kernels by token on a TPU); was
+    # ("77fdf965d88234f2", "991274bb9bf7cc47")
+    "mimo": ("a714f0a9a31f631f", "647ad4e666a89a20"),
     "olmo": ("2cb9e0e4838c9bea", "f1ff67ac3445443b"),
 }
 

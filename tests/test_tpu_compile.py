@@ -215,35 +215,43 @@ def test_paged_attention_gqa_tiles_compile_for_v5e(one_chip, mosaic, kv,
     assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2 ** 20
 
 
-@pytest.mark.parametrize("rows", [
-    pytest.param(2560, id="mixed-step-320-lanes"),
-    pytest.param(512, id="burst-64-lanes"),
+@pytest.mark.parametrize("lanes", [
+    pytest.param(320, id="mixed-step-320-lanes"),
+    pytest.param(64, id="burst-64-lanes"),
 ])
-def test_grouped_matmul_kernels_compile_for_v5e(one_chip, mosaic, rows):
-    """The held experts' grouped products (ISSUE 35) at the MiMo-V2-Flash
-    cell's shapes: ``rows`` pairs sorted over 16 held experts of hidden 4096
-    x width 2048 in bfloat16, each group padded to row tiles of 16 (2,800 /
-    752 rows), the tiles in use a dynamic grid bound. TWO kernels (gate and up
-    fused with the SwiGLU, then down), their weight blocks inside the VMEM
-    they ask for, and nothing of the experts' size beside them."""
+def test_grouped_matmul_kernels_compile_for_v5e(one_chip, mosaic, monkeypatch,
+                                                lanes):
+    """The held experts' layer at the MiMo-V2-Flash cell's shapes, the rule
+    answering as on a TPU: ``lanes`` tokens routed top-8 over 256 experts, 16
+    of them held, of hidden 4096 x width 2048 in bfloat16. TWO kernels (gate
+    and up fused with the SwiGLU, then down) compile inside the VMEM they ask
+    for, with the tokens' activations and output panel held beside the weight
+    panels; the rows enter and leave the kernels by token, so the layer's
+    program holds no gather of padded rows (2,800 at 320 lanes) and no
+    scatter of rows of the hidden size, and nothing of the experts' size
+    beside the kernels."""
+    monkeypatch.setattr(held_experts, "_kernel_applies", lambda *a: True)
+
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    tm = grouped_matmul.row_tile(jnp.bfloat16)
-    padded = grouped_matmul.padded_rows(rows, 16, tm)
+    def layer(h, router, bias, w1, w3, w2):
+        return held_experts.held_experts_mlp(h, router, bias, w1, w3, w2,
+                                             jnp.int32(32), 8)
 
-    def products(xp, w1, w3, w2, sizes):
-        plan = grouped_matmul.plan_row_tiles(sizes, tm, rows)
-        return grouped_matmul.gmm_down(
-            grouped_matmul.gmm_up(xp, w1, w3, plan), w2, plan)
-
-    compiled = jax.jit(products).lower(
-        sds((padded, 4096), jnp.bfloat16), sds((16, 4096, 2048), jnp.bfloat16),
+    compiled = jax.jit(layer).lower(
+        sds((lanes, 4096), jnp.bfloat16), sds((4096, 256), jnp.bfloat16),
+        sds((256,), jnp.bfloat16), sds((16, 4096, 2048), jnp.bfloat16),
         sds((16, 4096, 2048), jnp.bfloat16),
-        sds((16, 2048, 4096), jnp.bfloat16), sds((16,), jnp.int32)).compile()
+        sds((16, 2048, 4096), jnp.bfloat16)).compile()
     hlo = compiled.as_text()
     assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 2
     assert "held_experts_gmm_up" in hlo and "held_experts_gmm_down" in hlo
+    padded = grouped_matmul.padded_rows(lanes * 8, 16, 16)
+    ops = [line for line in hlo.splitlines() if " = " in line]
+    assert not [op for op in ops
+                if f"[{padded},4096]" in op.split(" = ")[1].split(" ")[0]]
+    assert not [op for op in ops if " scatter(" in op and ",4096]" in op]
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
 
 
