@@ -35,11 +35,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .....monitor.parts import scope as _part
 from .....ops.pallas import grouped_matmul as gmm
 
 __all__ = ["route_sigmoid_topk", "held_experts_mlp"]
 
 
+@_part("router")
 def route_sigmoid_topk(h, router, bias, top_k):
     """``(experts [T, top_k] int32, weights [T, top_k] float32)``.
 
@@ -78,7 +80,7 @@ def _kernel_applies(h, w1, w2):
             and gmm.fits(h.shape[0], hidden, width, h.dtype))
 
 
-@jax.named_scope("held_experts")
+@_part("experts")
 def held_experts_mlp(h, router, bias, w1, w3, w2, lo, top_k, valid=None):
     """``h`` [T, hidden]; ``router`` [hidden, n_experts]; ``bias``
     [n_experts]; ``w1`` / ``w3`` [held, hidden, width] and ``w2`` [held,

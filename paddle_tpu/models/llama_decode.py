@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..monitor.parts import scope as _part
+
 
 def _rms(x, w, eps):
     xf = x.astype(jnp.float32)
@@ -417,6 +419,7 @@ class LlamaDecodeEngine:
         return x @ w["head_w"], new_cache
 
     # -- paged forward paths (models/paged_kv.py pool + tables) --------------
+    @_part("attn_proj")
     def _qkv_rope(self, p, x, positions, kind=None, rows=False):
         """Shared pre-attention: rms -> q/k/v projections -> RoPE on the
         kind's rotary dims, at ``positions`` (S,) shared by the batch, or
@@ -446,32 +449,39 @@ class LlamaDecodeEngine:
         return (rope(q, positions, kind.theta, kind.rotary_dim),
                 rope(k, positions, kind.theta, kind.rotary_dim), v)
 
-    def _post_attn(self, p, x, attn, valid=None):
+    def _post_attn(self, p, x, attn, valid=None, part="attn_proj"):
         """Shared epilogue: output proj + residual + rms + the layer's MLP,
         dense SwiGLU or (``router`` among the layer's weights) the experts
         this engine holds. Returns ``(x, pairs)``: ``pairs`` [4] int32 counts
         the (token, expert) pairs on held experts and in all, and the held
         experts that got one, over ``valid`` tokens, and the rows of the
-        grouped product's row tiles; None for a dense layer."""
+        grouped product's row tiles; None for a dense layer. The output
+        projection is the device part ``part`` (a recurrent layer's mixer
+        owns its own), the rest ``mlp`` or ``experts``."""
         B, S = x.shape[0], x.shape[1]
-        out = attn.reshape(B, S, -1) @ p["wo"]
-        if "post_attn_norm" in p:       # the norm on the sublayer's OUTPUT
-            out = _rms(out, p["post_attn_norm"], self.eps)
-        x = x + out
-        h2 = _rms(x, p["ln2"], self.eps) if "ln2" in p else x
+        with _part(part):
+            out = attn.reshape(B, S, -1) @ p["wo"]
+            if "post_attn_norm" in p:   # the norm on the sublayer's OUTPUT
+                out = _rms(out, p["post_attn_norm"], self.eps)
+            x = x + out
         if "router" not in p:
-            mlp = (jax.nn.silu(h2 @ p["gate"]) * (h2 @ p["up"])) @ p["down"]
-            if "post_ff_norm" in p:
-                mlp = _rms(mlp, p["post_ff_norm"], self.eps)
-            return x + mlp, None
+            with _part("mlp"):
+                h2 = _rms(x, p["ln2"], self.eps) if "ln2" in p else x
+                mlp = (jax.nn.silu(h2 @ p["gate"]) * (h2 @ p["up"])) \
+                    @ p["down"]
+                if "post_ff_norm" in p:
+                    mlp = _rms(mlp, p["post_ff_norm"], self.eps)
+                return x + mlp, None
         from ..incubate.distributed.models.moe.held_experts import (
             held_experts_mlp)
 
-        y, pairs = held_experts_mlp(
-            h2.reshape(B * S, -1), p["router"], p["router_bias"], p["w1"],
-            p["w3"], p["w2"], self.held_lo, self.top_k,
-            None if valid is None else valid.reshape(B * S))
-        return x + y.reshape(x.shape), pairs
+        with _part("experts"):
+            h2 = _rms(x, p["ln2"], self.eps) if "ln2" in p else x
+            y, pairs = held_experts_mlp(
+                h2.reshape(B * S, -1), p["router"], p["router_bias"],
+                p["w1"], p["w3"], p["w2"], self.held_lo, self.top_k,
+                None if valid is None else valid.reshape(B * S))
+            return x + y.reshape(x.shape), pairs
 
     def _block_paged(self, li, p, x, pool, tables, positions, valid=None,
                      prompt=False, counted=None, rows=None, runs=None):
@@ -512,11 +522,13 @@ class LlamaDecodeEngine:
             from .linear_attention import mixer
 
             B, S, _ = x.shape
-            mix, pool = mixer(kind, p, x.reshape(B * S, -1), pool,
-                              positions, runs, self.eps)
+            with _part("recurrent"):
+                mix, pool = mixer(kind, p, x.reshape(B * S, -1), pool,
+                                  positions, runs, self.eps)
+                mix = mix.reshape(B, S, -1)
             x, pairs = self._post_attn(
-                p, x, mix.reshape(B, S, -1),
-                valid if counted is None else counted)
+                p, x, mix, valid if counted is None else counted,
+                part="recurrent")
             return x, pool, pairs
         sink = p.get("sink")
         if prompt:
@@ -545,27 +557,31 @@ class LlamaDecodeEngine:
             return x, pool, None
         q, k, v = self._qkv_rope(p, x, positions, kind, rows=True)
         if self.kv_int8:
-            kq, kscale = self._quantize_kv(k)      # (T, 1, kv, D)
-            vq, vscale = self._quantize_kv(v)
-            new = (kq[:, 0], kscale[:, 0], vq[:, 0], vscale[:, 0])
-            if valid is None:
-                pool = _pk.paged_write_decode_int8(*pool, tables, positions,
-                                                   *new)
-            else:
-                pool = _pk.paged_write_mixed_int8(*pool, tables, positions,
-                                                  valid, *new)
-            attn = _pk.paged_attention_decode_int8(
-                q[:, 0], *pool, tables, positions)[:, None]
+            with _part("kv_write"):
+                kq, kscale = self._quantize_kv(k)      # (T, 1, kv, D)
+                vq, vscale = self._quantize_kv(v)
+                new = (kq[:, 0], kscale[:, 0], vq[:, 0], vscale[:, 0])
+                if valid is None:
+                    pool = _pk.paged_write_decode_int8(*pool, tables,
+                                                       positions, *new)
+                else:
+                    pool = _pk.paged_write_mixed_int8(*pool, tables,
+                                                      positions, valid, *new)
+            with _part("paged_attention"):
+                attn = _pk.paged_attention_decode_int8(
+                    q[:, 0], *pool, tables, positions)[:, None]
         else:
-            if valid is None:
-                pool = _pk.paged_write_decode(*pool, tables, positions,
-                                              k[:, 0], v[:, 0])
-            else:
-                pool = _pk.paged_write_mixed(*pool, tables, positions, valid,
-                                             k[:, 0], v[:, 0])
-            attn = _pk.paged_attention_decode(
-                q[:, 0], *pool, tables, positions, window=kind.window,
-                sink=sink, rows=rows)[:, None]
+            with _part("kv_write"):
+                if valid is None:
+                    pool = _pk.paged_write_decode(*pool, tables, positions,
+                                                  k[:, 0], v[:, 0])
+                else:
+                    pool = _pk.paged_write_mixed(*pool, tables, positions,
+                                                 valid, k[:, 0], v[:, 0])
+            with _part("paged_attention"):
+                attn = _pk.paged_attention_decode(
+                    q[:, 0], *pool, tables, positions, window=kind.window,
+                    sink=sink, rows=rows)[:, None]
         x, pairs = self._post_attn(p, x, attn,
                                    valid if counted is None else counted)
         return x, pool, pairs
@@ -589,7 +605,8 @@ class LlamaDecodeEngine:
         head) and reaches its own keys and values through the SAME table
         rows offset by ``t`` x the blocks a pass holds, never a slice of a
         pool. One pass emits no loop."""
-        runs = self._plan_runs(x, pools, positions, valid, prompt, rows)
+        with _part("recurrent"):
+            runs = self._plan_runs(x, pools, positions, valid, prompt, rows)
 
         def stack(x, pools, tables):
             new_pools, total = [], None
@@ -608,9 +625,11 @@ class LlamaDecodeEngine:
 
         def one_pass(t, carry):
             x, pools, total = carry
-            x = jnp.where(t > 0, self._between_passes(w, x), x)
-            x, pools, pairs = stack(x, pools,
-                                    self._pass_tables(tables, t, blocks))
+            with _part("head"):
+                x = jnp.where(t > 0, self._between_passes(w, x), x)
+            with _part("tables"):
+                pass_tables = self._pass_tables(tables, t, blocks)
+            x, pools, pairs = stack(x, pools, pass_tables)
             return x, pools, None if pairs is None else total + pairs
 
         total = jnp.zeros((4,), jnp.int32) if self.has_experts else None
@@ -689,36 +708,42 @@ class LlamaDecodeEngine:
             # (one fused upload per step — these are the only per-step
             # transfers; slot_ids/valid/chain are cached per composition)
             token_ids, positions = pack[0], pack[1]
-            x = w["emb"][token_ids][:, None]        # (T, 1, hidden)
+            with _part("embed"):
+                x = w["emb"][token_ids][:, None]    # (T, 1, hidden)
             # (T, max_blocks) a paged kind; a recurrent kind has no table
-            row_tables = tuple(None if t is None else t[slot_ids]
-                               for t in tables)
+            with _part("tables"):
+                row_tables = tuple(None if t is None else t[slot_ids]
+                                   for t in tables)
             x, new_pools, pairs = self._layers_paged(
                 w, x, pools, row_tables, positions, valid, rows=slot_ids)
-            x = _rms(x, w["norm_w"], self.eps)
-            logits = (x @ w["head_w"])[:, -1]
-            # argmax INSIDE the program: the scheduler transfers one
-            # (2, T) int32 lane matrix per step, never a vocab logits row
-            nt = jnp.argmax(logits, -1).astype(jnp.int32)
-            # segmented running-AND along draft chains (accept = my draft
-            # token equals the previous lane's greedy token, and every
-            # draft before me agreed): a (value, segment-start) monoid so
-            # the scan is O(log T) on device
-            prev = jnp.roll(nt, 1)
-            agree = jnp.where(chain, prev == token_ids, True)
-            start = ~chain
+            with _part("head"):
+                x = _rms(x, w["norm_w"], self.eps)
+                logits = (x @ w["head_w"])[:, -1]
+            with _part("sample"):
+                # argmax INSIDE the program: the scheduler transfers one
+                # (2, T) int32 lane matrix per step, never a vocab logits row
+                nt = jnp.argmax(logits, -1).astype(jnp.int32)
+                # segmented running-AND along draft chains (accept = my
+                # draft token equals the previous lane's greedy token, and
+                # every draft before me agreed): a (value, segment-start)
+                # monoid so the scan is O(log T) on device
+                prev = jnp.roll(nt, 1)
+                agree = jnp.where(chain, prev == token_ids, True)
+                start = ~chain
 
-            def comb(a, b):
-                av, as_ = a
-                bv, bs_ = b
-                return jnp.where(bs_, bv, av & bv), as_ | bs_
+                def comb(a, b):
+                    av, as_ = a
+                    bv, bs_ = b
+                    return jnp.where(bs_, bv, av & bv), as_ | bs_
 
-            acc, _ = lax.associative_scan(comb, (agree, start))
-            accept = acc & chain
-            rows = [nt, accept.astype(jnp.int32)]
-            if pairs is not None:
-                rows.append(jnp.zeros_like(nt).at[:pairs.shape[0]].set(pairs))
-            return jnp.stack(rows), new_pools
+                acc, _ = lax.associative_scan(comb, (agree, start))
+                accept = acc & chain
+                rows = [nt, accept.astype(jnp.int32)]
+                if pairs is not None:
+                    rows.append(
+                        jnp.zeros_like(nt).at[:pairs.shape[0]].set(pairs))
+                out = jnp.stack(rows)
+            return out, new_pools
 
         return serving_mixed_step
 
@@ -742,16 +767,20 @@ class LlamaDecodeEngine:
 
             def body(carry, _):
                 toks, pools_c, lens_c = carry
-                x = w["emb"][toks]
+                with _part("embed"):
+                    x = w["emb"][toks]
                 # every row writes (valid None), an idle one into the null
                 # block; pairs are counted over the rows that run
                 x, new_pools, pairs = self._layers_paged(
                     w, x, pools_c, tables, lens_c,
                     counted=lens_c > 0 if counts else None)
-                x = _rms(x, w["norm_w"], self.eps)
-                logits = (x @ w["head_w"])[:, -1]
-                nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-                out = nxt if pairs is None else jnp.concatenate([nxt, pairs])
+                with _part("head"):
+                    x = _rms(x, w["norm_w"], self.eps)
+                    logits = (x @ w["head_w"])[:, -1]
+                with _part("sample"):
+                    nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+                    out = nxt if pairs is None \
+                        else jnp.concatenate([nxt, pairs])
                 return (nxt[:, None], new_pools, lens_c + 1), out
 
             (toks, pools, lens), outs = lax.scan(

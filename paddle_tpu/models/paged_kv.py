@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 
 from ..analysis import faultinject as _fi
+from ..monitor.parts import scope as _part
 
 __all__ = ["PagedKVCache", "CowPoolExhausted", "alloc_blocks",
            "read_blocks",
@@ -615,6 +616,7 @@ def _rows_for(pool, new):
     return new.astype(pool.dtype)
 
 
+@_part("kv_write")
 def paged_write_decode(cache_k, cache_v, block_tables, seq_lens, k_new, v_new):
     """Write ONE new token per sequence into its current tail block.
 
@@ -626,6 +628,7 @@ def paged_write_decode(cache_k, cache_v, block_tables, seq_lens, k_new, v_new):
     return cache_k, cache_v
 
 
+@_part("kv_write")
 def paged_write_mixed(cache_k, cache_v, row_tables, positions, valid,
                       k_new, v_new):
     """Write one token per LANE of a mixed (decode + chunked-prefill) pack.
@@ -644,6 +647,7 @@ def paged_write_mixed(cache_k, cache_v, row_tables, positions, valid,
     return cache_k, cache_v
 
 
+@_part("kv_write")
 def paged_write_prefill(cache_k, cache_v, block_tables, seq_lens,
                         k_new, v_new):
     """Write a full prompt per sequence: k_new/v_new [B, S, kv_heads, D],
@@ -679,6 +683,7 @@ def _flat_rows(x):
     return x.reshape((x.shape[0] * x.shape[1],) + x.shape[2:])
 
 
+@_part("kv_write")
 def paged_write_decode_int8(kq, ks, vq, vs, block_tables, seq_lens,
                             k_new_q, k_new_s, v_new_q, v_new_s):
     """int8 form of paged_write_decode: values [B, kv, D] int8 plus their
@@ -688,6 +693,7 @@ def paged_write_decode_int8(kq, ks, vq, vs, block_tables, seq_lens,
             vq.at[phys, off].set(v_new_q), vs.at[phys, off].set(v_new_s))
 
 
+@_part("kv_write")
 def paged_write_mixed_int8(kq, ks, vq, vs, row_tables, positions, valid,
                            k_new_q, k_new_s, v_new_q, v_new_s):
     """int8 form of paged_write_mixed: one quantized token per LANE of a
@@ -703,6 +709,7 @@ def paged_write_mixed_int8(kq, ks, vq, vs, row_tables, positions, valid,
             vs.at[phys, off].set(v_new_s, mode="drop"))
 
 
+@_part("kv_write")
 def paged_write_prefill_int8(kq, ks, vq, vs, block_tables, seq_lens,
                              k_new_q, k_new_s, v_new_q, v_new_s):
     """int8 form of paged_write_prefill (values [B, S, kv, D] int8 + scales
@@ -716,7 +723,7 @@ def paged_write_prefill_int8(kq, ks, vq, vs, block_tables, seq_lens,
     return w(kq, k_new_q), w(ks, k_new_s), w(vq, v_new_q), w(vs, v_new_s)
 
 
-@jax.named_scope("paged_attention")
+@_part("paged_attention")
 def paged_attention_decode_int8(q, kq, ks, vq, vs, block_tables, seq_lens,
                                 scale=None):
     """One decode step against the int8 paged cache WITHOUT materializing a
@@ -794,7 +801,7 @@ def kernel_applies(q, cache_k, cache_v):
     return _kernel_applies(q, cache_k)
 
 
-@jax.named_scope("paged_attention")
+@_part("paged_attention")
 def paged_attention_decode(q, cache_k, cache_v, block_tables, seq_lens,
                            scale=None, window=None, sink=None, rows=None):
     """One decode step of attention against the paged cache.
@@ -817,9 +824,9 @@ def paged_attention_decode(q, cache_k, cache_v, block_tables, seq_lens,
     walk the row once (``ops.pallas.paged_attention.plan_tiles``). It is
     data about the step, and changes no result; the gather paths ignore it.
 
-    The whole of it runs under ``jax.named_scope("paged_attention")``, so
-    a device trace can tell the serving programs' attention from the rest
-    of a layer by name."""
+    The whole of it is the device part ``paged_attention``
+    (``monitor.parts``), so a device trace can tell the serving programs'
+    attention from the rest of a layer."""
     plain = cache_k.ndim == 4 and (window is not None or sink is not None)
     if not plain and kernel_applies(q, cache_k, cache_v):
         from ..ops.pallas import paged_attention as _k

@@ -117,7 +117,7 @@ class _Mon:
                  "kind_blocks", "attn_lanes", "block_steps", "expert_pairs",
                  "window_released", "linear_tokens", "linear_runs",
                  "byte_steps", "state_bytes", "state_resets", "dispatched",
-                 "forwards", "passes")
+                 "forwards", "passes", "lane_steps")
 
 
 _MON = None
@@ -214,6 +214,8 @@ def _mon():
                                  labelnames=("ahead",))
         o.forwards = m.counter("paddle_tpu_serving_forwards_total")
         o.passes = m.counter("paddle_tpu_serving_stack_passes_total")
+        o.lane_steps = m.counter("paddle_tpu_serving_lane_steps_total",
+                                 labelnames=("use",))
         _MON = o
     return _MON
 
@@ -618,8 +620,51 @@ class ContinuousBatchingEngine:
 
         _obs.register_status_provider(f"serving.{self._san_tag}",
                                       self.status)
+        # the device parts of the programs compiled before the monitor is
+        # turned on are mapped at its enable() (held weakly, like /statusz)
+        from ..monitor import parts as _parts
+
+        self._parts_have = None
+        _parts.on_enable(self._record_parts)
 
     # -- compiled path -------------------------------------------------------
+    def _record_parts(self):
+        """Map the instructions of the programs this engine has compiled
+        (the mixed step, the decode burst, the compositions of a known
+        output into either pack) to their device parts
+        (``monitor.parts``): from the executable each program's jit holds,
+        lowered again with the signature it was called with, which finds
+        that executable in jit's caches and compiles nothing. Only while the
+        monitor is on (``monitor.enable()`` calls it, and a dispatch while
+        the monitor is on); a program set already mapped is skipped."""
+        mon = _mon()
+        if not mon.state.on:
+            return
+        parts = mon.mod.parts
+        have = (parts.generation(), tuple(map(id, self._jit_cache.values())),
+                tuple(self._out_shapes.values()))
+        if have == self._parts_have:
+            return
+        self._parts_have = have
+        sds, i32 = jax.ShapeDtypeStruct, jnp.int32
+        T, B = self.max_step_tokens, self.max_batch
+        lowered = []
+        step, burst = self._jit_cache.get("step"), self._jit_cache.get("burst")
+        if step is not None:
+            lowered.append(step.lower(
+                sds((2, T), i32), self._pools, self._tables(), sds((T,), i32),
+                sds((T,), jnp.bool_), sds((T,), jnp.bool_),
+                self._inner.weights))
+        if burst is not None:
+            lowered.append(burst.lower(sds((2, B), i32), self._pools,
+                                       self._tables(), self._inner.weights))
+        for shape in self._out_shapes.values():
+            for n in (T, B):
+                lowered.append(_compose_tokens.lower(sds((3, n), i32),
+                                                     sds(shape, i32)))
+        for low in lowered:
+            parts.record(low.compile().as_text())
+
     def _step_jit(self):
         cache = self._jit_cache
         mon = _mon()
@@ -1729,10 +1774,14 @@ class ContinuousBatchingEngine:
                 for n in (self.max_step_tokens, self.max_batch):
                     _compose_tokens(
                         jax.device_put(np.zeros((3, n), np.int32)), out)
+        if mon.state.on:
+            self._record_parts()
         if fl.epoch != self._epoch:
             # a hang recovery superseded this step while it sat in
             # compile/dispatch: the pools rebind stands, the book is the
             # new epoch's. The step is dropped by the next call
+            if mon.state.on:
+                self._count_lanes(mon, fl)
             return fl
         # -- the book ------------------------------------------------------
         K = fl.forwards
@@ -1767,6 +1816,8 @@ class ContinuousBatchingEngine:
                 if ends:
                     ended.append(b)
             fl.chunks.append((b, req, start, take, emit, ends))
+        if mon.state.on:
+            self._count_lanes(mon, fl)
         if self._depth():
             # a request whose last token has been dispatched needs its
             # row no more (the invariant, __init__): the slot is free for
@@ -1776,6 +1827,20 @@ class ContinuousBatchingEngine:
                 self._unreported[req.rid] = req
                 self._release(b)
         return fl
+
+    def _count_lanes(self, mon, fl):
+        """``paddle_tpu_serving_lane_steps_total{use}`` for a dispatched
+        step: each of ``max_batch`` rows once a forward pass; the row-passes
+        the book granted work (a token, or a prompt chunk; none for a step
+        a recovery superseded) are granted, the rest of the rows that hold
+        a request held, the others empty. Before the step's ended requests
+        release their rows."""
+        granted = sum(d[4] for d in fl.decode) + len(fl.chunks)
+        rows = int(self._active.sum()) * fl.forwards
+        mon.lane_steps.labels("granted").inc(granted)
+        mon.lane_steps.labels("held").inc(rows - granted)
+        mon.lane_steps.labels("empty").inc(
+            self.max_batch * fl.forwards - rows)
 
     def _grant(self, req, lens, n, max_new_tokens):
         """Grant ``req`` up to ``n`` more output tokens from a row that

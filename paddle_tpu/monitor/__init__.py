@@ -10,6 +10,11 @@ and the dataloader (``io/dataloader.py``), exported three ways:
 - ``monitor.prometheus_text()`` — Prometheus text exposition;
 - chrome-trace counter events merged into the profiler's chrome trace.
 
+Beside the metrics, ``monitor.device_parts()`` maps the instructions of the
+serving programs compiled while the monitor is on (or before it, at
+``enable()``) to the device parts of ``catalog.PARTS`` (``monitor/parts.py``):
+a device trace's time split by part. It is never part of a snapshot.
+
 DISABLED BY DEFAULT. Every instrumented site guards on ``_state.on`` (one
 attribute load on a preallocated object), so the cost when off is a few
 nanoseconds per dispatch — inside the 40us eager budget
@@ -30,7 +35,7 @@ from __future__ import annotations
 import threading
 from collections import deque
 
-from . import catalog, provenance as _provenance_mod, trace  # noqa: F401
+from . import catalog, parts, provenance as _provenance_mod, trace  # noqa: F401
 from .export import (chrome_counter_events as _chrome_events,
                      prometheus_text as _prom_text, snapshot as _snapshot)
 from .registry import (Counter, Gauge, Histogram, Registry,  # noqa: F401
@@ -41,7 +46,7 @@ __all__ = [
     "enable", "disable", "enabled", "reset",
     "counter", "gauge", "histogram", "registry",
     "snapshot", "prometheus_text", "sample", "chrome_counter_events",
-    "provenance", "validate_provenance", "trace",
+    "provenance", "validate_provenance", "trace", "device_parts",
     "server", "slo", "timeline",
 ]
 
@@ -58,6 +63,7 @@ class _State:
 
 _state = _State()
 trace._metrics_state = _state
+parts._metrics_state = _state
 registry = Registry()
 
 # timeline samples for chrome-trace counter export: bounded, so an
@@ -68,9 +74,11 @@ _sample_lock = threading.Lock()
 
 
 def enable():
-    """Turn collection on process-wide."""
+    """Turn collection on process-wide, and map the device parts of the
+    programs compiled before it (``parts.on_enable`` hooks)."""
     _state.on = True
     trace._state.annotate = True    # trace.phase() sites annotate too
+    parts.enabled()
 
 
 def disable():
@@ -84,12 +92,21 @@ def enabled():
 
 
 def reset():
-    """Zero every metric, drop buffered timeline samples AND recorded trace
-    spans (test isolation and between-run hygiene)."""
+    """Zero every metric, drop buffered timeline samples, recorded trace
+    spans AND the device parts' maps (test isolation and between-run
+    hygiene)."""
     registry.reset()
     with _sample_lock:
         _samples.clear()
     trace.reset()
+    parts.reset()
+
+
+def device_parts():
+    """``{program: {instruction: (result shape, part)}}``: the serving
+    programs' instructions by device part (``monitor/parts.py``), built only
+    while the monitor is on and kept after their engine is freed."""
+    return parts.device_parts()
 
 
 def _cataloged(kind, name, labelnames, help):

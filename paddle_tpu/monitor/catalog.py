@@ -114,6 +114,16 @@ METRICS = {
         "Forward passes of the model the engine's dispatched steps run: 1 a "
         "mixed step, decode_burst a burst. Counted at dispatch, so that it "
         "is exact over a traced slice whose edges are at rest."),
+    "paddle_tpu_serving_lane_steps_total": (
+        "counter", ("use",),
+        "Rows of the engine's batch (max_batch) counted once per forward pass "
+        "of each dispatched step (a burst: decode_burst), by use: "
+        "use=granted the row is given work (a token to produce, or a chunk "
+        "of its prompt), use=held the row holds a request given no work "
+        "(waiting for a prefill budget, or past its last token inside a "
+        "burst), use=empty the row holds no request. Counted at dispatch; "
+        "the three sum to max_batch x paddle_tpu_serving_forwards_total. "
+        "granted over all is lanes_used_share.sat."),
     "paddle_tpu_serving_stack_passes_total": (
         "counter", (),
         "Passes of the whole layer stack the engine's dispatched steps run: "
@@ -750,3 +760,53 @@ SPANS = {
 def span_spec(name):
     """Help text for a cataloged span name, or None."""
     return SPANS.get(name)
+
+
+# -- device-part catalog (monitor/parts.py) -----------------------------------
+
+# The parts of a serving program on the device: each is a jax.named_scope at
+# a block's boundaries (monitor.parts.scope), so every instruction of the
+# compiled program carries it in its op_name, and a device trace can be split
+# by part (monitor.device_parts(): instruction -> part). Where scopes nest,
+# the innermost part on an op_name path wins. A scope outside this table is
+# never used. name -> help text
+PARTS = {
+    "embed": (
+        "The gather of the lanes' token embeddings, at the program's entry "
+        "and at each iteration of a burst."),
+    "tables": (
+        "The lanes' block-table rows: a mixed step's gather of each lane's "
+        "row by its slot, a looped stack's offset of the rows to a pass's "
+        "own blocks."),
+    "attn_proj": (
+        "An attention layer's weights around its attention: the norm before "
+        "the projections, the q / k / v projections and their norms, the "
+        "rotary embedding; the output projection, its norm and the "
+        "residual add."),
+    "kv_write": (
+        "The writes of the lanes' keys and values into the paged pools "
+        "(paged_write_*)."),
+    "paged_attention": (
+        "The attention over the paged pools (paged_attention_decode*): the "
+        "Pallas kernels and their glue, or the plain gather path."),
+    "recurrent": (
+        "A recurrent (gated delta-rule) layer's mixer: its projections, the "
+        "convolutions, the recurrence's kernels and their glue, the output "
+        "norm and gate; and the plan of the step's runs."),
+    "mlp": (
+        "A dense layer's MLP: the norm before it, gate / up / down, the norm "
+        "on its output and the residual add."),
+    "router": (
+        "An expert layer's router: the scores, the selection's top-k and "
+        "the chosen weights."),
+    "experts": (
+        "An expert layer's held experts: the pairs' sort, the groups, the "
+        "grouped products and the combine by token; the residual add."),
+    "head": (
+        "The final norm (after every pass of a looped stack) and the "
+        "vocabulary head's product."),
+    "sample": (
+        "The greedy choice over the logits (arg-max), the draft chains' "
+        "accept scan, and the program's output rows."),
+}
+

@@ -453,7 +453,6 @@ def _chunk_plain(qc, kc, vc, gc, bc, state, slot, code, chunks):
     return o, state.at[null].set(jnp.zeros((), state.dtype))
 
 
-@jax.named_scope("gated_delta")
 def gated_delta(q, k, v, g, beta, state, positions, plan=None):
     """One token a lane through the gated delta rule. ``q``, ``k`` [T, H,
     dk] (normalised and scaled), ``v`` [T, H, dv], ``g``, ``beta`` [T, H],
