@@ -15,8 +15,10 @@ functional path against the model's own forward.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
+import threading
 
 import numpy as np
 
@@ -25,6 +27,38 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..monitor.parts import scope as _part
+
+# held by one placement at a time (``place_weights``): engines over one model
+# (a ``FleetRouter``'s replicas, each first stepped in its own thread) find
+# the arrays another placed, and the compile cache's switch is theirs alone
+_PLACING = threading.Lock()
+
+
+def _slot(tree, path):
+    """``(container, key)`` of the leaf at a ``tree_flatten_with_path``
+    path (dict keys and list indices)."""
+    keys = [k.key if hasattr(k, "key") else k.idx for k in path]
+    for k in keys[:-1]:
+        tree = tree[k]
+    return tree, keys[-1]
+
+
+@contextlib.contextmanager
+def _fresh_compiles():
+    """Compile without jax's persistent compilation cache: an executable
+    that writes a layout other than the default one comes back from that
+    cache writing the default one (jax 0.9.0), so a copy into another
+    layout is compiled afresh."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
 
 
 def _rms(x, w, eps):
@@ -310,6 +344,46 @@ class LlamaDecodeEngine:
         head = model.lm_head
         self.head_w = (jnp.swapaxes(self.emb, 0, 1) if head._tied
                        else head.weight.value)
+
+    def place_weights(self, formats, owners=None):
+        """Put every weight whose format in ``formats`` (a pytree shaped as
+        ``weights``: what a compiled program reads) differs from its own in
+        that format: in ``weights``' containers, in the attributes that
+        name its top-level arrays, and in the model's parameter that held
+        it (``owners``: id of a leaf -> that parameter), so that the device
+        holds one copy of each. A leaf at a time, each copy waited for, so
+        that at most one weight is held twice; where the parameter already
+        holds the leaf in that format (another engine over the same model
+        placed it), that array is taken, not copied. Returns the bytes
+        placed in another layout."""
+        owners = owners or {}
+        moves = []
+        for path, fmt in jax.tree_util.tree_flatten_with_path(formats)[0]:
+            box, key = _slot(self.weights, path)
+            if fmt.layout is not None and fmt.layout != box[key].format.layout:
+                moves.append((box, key, fmt))    # (None: a leaf no one reads)
+        if not moves:
+            return 0
+        moved = 0
+        with _PLACING, _fresh_compiles():
+            for box, key, fmt in moves:
+                x = box[key]
+                held = owners.get(id(x))
+                new = x if held is None else held.value
+                if new.format != fmt:
+                    new = jax.device_put(x, fmt).block_until_ready()
+                    if new.format.layout != fmt.layout:
+                        raise RuntimeError(
+                            f"a weight placed in {fmt.layout} came back in "
+                            f"{new.format.layout}")
+                    if held is not None and held.value is x:
+                        held._replace_value(new)
+                box[key] = new
+                moved += new.nbytes
+                del x
+        for key in self.weights.keys() - {"layers"}:
+            setattr(self, key, self.weights[key])
+        return moved
 
     # -- cache ---------------------------------------------------------------
     def init_cache(self, batch):

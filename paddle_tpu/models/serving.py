@@ -50,6 +50,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Format, Layout
 
 from . import paged_kv as _pk
 from ..analysis import faultinject as _fi
@@ -117,7 +118,7 @@ class _Mon:
                  "kind_blocks", "attn_lanes", "block_steps", "expert_pairs",
                  "window_released", "linear_tokens", "linear_runs",
                  "byte_steps", "state_bytes", "state_resets", "dispatched",
-                 "forwards", "passes", "lane_steps")
+                 "forwards", "passes", "lane_steps", "relayout")
 
 
 _MON = None
@@ -216,6 +217,8 @@ def _mon():
         o.passes = m.counter("paddle_tpu_serving_stack_passes_total")
         o.lane_steps = m.counter("paddle_tpu_serving_lane_steps_total",
                                  labelnames=("use",))
+        o.relayout = m.gauge("paddle_tpu_serving_weight_relayout_bytes",
+                             labelnames=("where",))
         _MON = o
     return _MON
 
@@ -262,6 +265,12 @@ def _compose_tokens(pack, prev):
     src = pack[2]
     tok = jnp.where(src >= 0, prev.reshape(-1)[jnp.maximum(src, 0)], pack[0])
     return jnp.stack([tok, pack[1]])
+
+
+def _free_formats(weights):
+    """The formats the mixed step is first compiled with: each weight on the
+    device it lies on, its layout left to the compiler."""
+    return jax.tree.map(lambda x: Format(Layout.AUTO, x.sharding), weights)
 
 
 class _Request:
@@ -407,6 +416,14 @@ class ContinuousBatchingEngine:
                                  block_size=block_size,
                                  kv_cache_dtype=kv_cache_dtype)
         e = self._inner
+        # the model's parameter of each weight the engine took as it is, so
+        # that a weight placed in another layout replaces the parameter's
+        # array (one device copy of each weight; ``_compile_step``)
+        held = {id(p.value): p for p in model.parameters()}
+        self._owners = {id(x): held[id(x)] for x in jax.tree.leaves(e.weights)
+                        if id(x) in held}
+        # weight bytes placed in another layout before the first program ran
+        self.weight_relayout_bytes = 0
         self.max_batch = int(max_batch)
         self.max_len = e.max_len
         self.block_size = int(block_size)
@@ -547,6 +564,9 @@ class ContinuousBatchingEngine:
         self._tok_src = np.full(self.max_batch, -1, np.int32)
         self._unreported = {}
         self._out_shapes = {}           # kind -> its program's output shape
+        # (output shape, pack width) -> the composition of such an output
+        # into such a pack, compiled (``_compose_tokens``)
+        self._compose = {}
         # the running step()'s eos_token_id, max_new_tokens and epoch
         self._call = (None, None, 0)
         self._out = []                  # ... and what it will return
@@ -632,38 +652,49 @@ class ContinuousBatchingEngine:
         """Map the instructions of the programs this engine has compiled
         (the mixed step, the decode burst, the compositions of a known
         output into either pack) to their device parts
-        (``monitor.parts``): from the executable each program's jit holds,
-        lowered again with the signature it was called with, which finds
-        that executable in jit's caches and compiles nothing. Only while the
+        (``monitor.parts``): from the text of each executable the engine
+        holds, with nothing compiled, and set the gauge of the weight bytes placed in another layout at build and
+        of those the programs still re-lay out in a call. Only while the
         monitor is on (``monitor.enable()`` calls it, and a dispatch while
         the monitor is on); a program set already mapped is skipped."""
         mon = _mon()
         if not mon.state.on:
             return
         parts = mon.mod.parts
-        have = (parts.generation(), tuple(map(id, self._jit_cache.values())),
-                tuple(self._out_shapes.values()))
+        programs = [*self._jit_cache.values(), *self._compose.values()]
+        have = (parts.generation(), tuple(map(id, programs)))
         if have == self._parts_have:
             return
         self._parts_have = have
-        sds, i32 = jax.ShapeDtypeStruct, jnp.int32
-        T, B = self.max_step_tokens, self.max_batch
-        lowered = []
-        step, burst = self._jit_cache.get("step"), self._jit_cache.get("burst")
-        if step is not None:
-            lowered.append(step.lower(
-                sds((2, T), i32), self._pools, self._tables(), sds((T,), i32),
-                sds((T,), jnp.bool_), sds((T,), jnp.bool_),
-                self._inner.weights))
-        if burst is not None:
-            lowered.append(burst.lower(sds((2, B), i32), self._pools,
-                                       self._tables(), self._inner.weights))
-        for shape in self._out_shapes.values():
-            for n in (T, B):
-                lowered.append(_compose_tokens.lower(sds((3, n), i32),
-                                                     sds(shape, i32)))
-        for low in lowered:
-            parts.record(low.compile().as_text())
+        texts = [prog.as_text() for prog in programs]
+        for text in texts:
+            parts.record(text)
+        # the programs' weights are their last argument, ``w``
+        mon.relayout.labels("build").set(self.weight_relayout_bytes)
+        mon.relayout.labels("program").set(
+            sum(parts.relayout_bytes(text, "w") for text in texts))
+
+    def _compile_step(self):
+        """The mixed step, compiled with the weights' layouts left to the
+        compiler (``_free_formats``). Each weight it reads in another
+        layout than the one it has is placed in that layout before the
+        program first runs (``place_weights``), so that it, the burst and
+        every other program compiled against the placed arrays read them
+        as they lie, and none re-lays out a weight in a call. Off a TPU
+        the compiler keeps the layouts the weights have: nothing moves."""
+        e = self._inner
+        sds, i32, T = jax.ShapeDtypeStruct, jnp.int32, self.max_step_tokens
+        args = (sds((2, T), i32), self._pools, self._tables(),
+                sds((T,), i32), sds((T,), jnp.bool_), sds((T,), jnp.bool_))
+        step = jax.jit(e.build_mixed_step(), donate_argnums=(1,),
+                       in_shardings=(None,) * len(args)
+                       + (_free_formats(e.weights),))
+        exe = step.lower(*args, jax.tree.map(
+            lambda x: sds(x.shape, x.dtype), e.weights)).compile()
+        self.weight_relayout_bytes = e.place_weights(
+            exe.input_formats[0][-1], self._owners)
+        self._owners = {}
+        return exe
 
     def _step_jit(self):
         cache = self._jit_cache
@@ -682,8 +713,7 @@ class ContinuousBatchingEngine:
                 # budget exists to prevent
                 san.note_compile(f"serving.step[{self._san_tag}]",
                                  signature="step")
-            cache["step"] = jax.jit(self._inner.build_mixed_step(),
-                                    donate_argnums=(1,))
+            cache["step"] = self._compile_step()
         return cache["step"]
 
     def _burst_jit(self):
@@ -704,9 +734,13 @@ class ContinuousBatchingEngine:
                 # actuation — visible to the sentinel, never a storm
                 san.note_compile(f"serving.step[{self._san_tag}]",
                                  signature=("burst", self.decode_burst))
+            # against the weights as the mixed step placed them
             cache["burst"] = jax.jit(
                 self._inner.build_decode_burst(self.decode_burst),
-                donate_argnums=(1,))
+                donate_argnums=(1,)).lower(
+                    jax.ShapeDtypeStruct((2, self.max_batch), jnp.int32),
+                    self._pools, self._tables(),
+                    self._inner.weights).compile()
         return cache["burst"]
 
     # -- admission -----------------------------------------------------------
@@ -1769,11 +1803,14 @@ class ContinuousBatchingEngine:
             # of a known output into either pack, so that none compiles
             # when two kinds first follow each other in steady state
             self._out_shapes[kind] = fl.out.shape
+            sds, i32 = jax.ShapeDtypeStruct, jnp.int32
             for shape in self._out_shapes.values():
-                out = jax.device_put(np.zeros(shape, np.int32))
                 for n in (self.max_step_tokens, self.max_batch):
-                    _compose_tokens(
-                        jax.device_put(np.zeros((3, n), np.int32)), out)
+                    if (shape, n) not in self._compose:
+                        self._compose[shape, n] = _compose_tokens.lower(
+                            sds((3, n), i32),
+                            sds(shape, i32, sharding=fl.out.sharding)
+                        ).compile()
         if mon.state.on:
             self._record_parts()
         if fl.epoch != self._epoch:
@@ -1986,7 +2023,8 @@ class ContinuousBatchingEngine:
         self._note_state_slots(mon, nd, chunks)
         self._next_phase("serving.dispatch", "mixed")
         pack = jnp.asarray(pack_np[:2]) if prev is None \
-            else _compose_tokens(jnp.asarray(pack_np), prev.out)
+            else self._compose[prev.out.shape, T](jnp.asarray(pack_np),
+                                                  prev.out)
         fl.out, self._pools = step(
             pack, self._pools, self._tables(),
             slots_dev, valid_dev, chain_dev, self._inner.weights)
@@ -2022,7 +2060,8 @@ class ContinuousBatchingEngine:
         self._next_phase("serving.dispatch", "burst")
         fl = _Flight("burst", None, self._call[2], t0, forwards=K)
         pack_dev = jnp.asarray(pack[:2]) if prev is None \
-            else _compose_tokens(jnp.asarray(pack), prev.out)
+            else self._compose[prev.out.shape, self.max_batch](
+                jnp.asarray(pack), prev.out)
         fl.out, self._pools = burst(
             pack_dev, self._pools, self._tables(), self._inner.weights)
         if _sanitizers._state.numerics:

@@ -124,6 +124,15 @@ METRICS = {
         "burst), use=empty the row holds no request. Counted at dispatch; "
         "the three sum to max_batch x paddle_tpu_serving_forwards_total. "
         "granted over all is lanes_used_share.sat."),
+    "paddle_tpu_serving_weight_relayout_bytes": (
+        "gauge", ("where",),
+        "Weight bytes in another layout than the model held them: "
+        "where=build, placed before the engine's first program ran, in the "
+        "layout its compiled mixed step reads (one device copy: the model's "
+        "parameter holds the placed array); where=program, what the "
+        "engine's compiled programs still copy into another layout, each "
+        "copy once per program. Set while the monitor is on, from the "
+        "optimized programs monitor.device_parts() maps. 0 off a TPU."),
     "paddle_tpu_serving_stack_passes_total": (
         "counter", (),
         "Passes of the whole layer stack the engine's dispatched steps run: "

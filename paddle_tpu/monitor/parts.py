@@ -41,6 +41,7 @@ from __future__ import annotations
 import collections
 import hashlib
 import logging
+import math
 import re
 import threading
 import time
@@ -49,7 +50,8 @@ import weakref
 from . import catalog
 
 __all__ = ["scope", "part_of", "parse", "record", "device_parts",
-           "build_seconds", "generation", "on_enable", "shape_key"]
+           "build_seconds", "generation", "on_enable", "shape_key",
+           "relayout_bytes"]
 
 _log = logging.getLogger("paddle_tpu.monitor")
 
@@ -136,11 +138,11 @@ _INDEX = re.compile(r"\bindex=(\d+)")
 _BODY = re.compile(r"\bbody=%([\w.\-]+)")
 
 
-def parse(text):
-    """``{instruction: (result shape, part)}`` of one HLO module's text, for
-    the instructions of its computations that run as operations."""
-    comps, info, users, comp = {}, {}, {}, None
-    entry, sequential, roots, params = None, set(), {}, {}
+def _instructions(text):
+    """``(computation, whether it is the entry, whether the instruction is
+    its root, name, result shape, opcode, operands' text, attributes'
+    text)`` of each instruction of one HLO module's text, in order."""
+    comp, is_entry = None, False
     for line in text.splitlines():
         m = _INSTR.match(line)
         if m is None:
@@ -150,15 +152,24 @@ def parse(text):
                 words = line.split()
                 is_entry = words[0] == "ENTRY"
                 comp = words[1 if is_entry else 0].lstrip("%")
-                comps[comp] = []
-                if is_entry:
-                    entry = comp
             continue
         name, rhs = m.groups()
         shape, rest = _split(rhs)
         opcode, _, args = rest.partition("(")
-        opcode = opcode.strip()
         operands, attrs = _split("(" + args, ")")
+        yield (comp, is_entry, line.lstrip().startswith("ROOT"), name, shape,
+               opcode.strip(), operands, attrs)
+
+
+def parse(text):
+    """``{instruction: (result shape, part)}`` of one HLO module's text, for
+    the instructions of its computations that run as operations."""
+    comps, info, users = {}, {}, {}
+    entry, sequential, roots, params = None, set(), {}, {}
+    for comp, is_entry, root, name, shape, opcode, operands, attrs in \
+            _instructions(text):
+        if is_entry:
+            entry = comp
         own = _OP_NAME.search(attrs)
         callees = []
         for attr, ref in _REFS.findall(attrs):
@@ -178,8 +189,8 @@ def parse(text):
         for o in info[name].operands:
             users.setdefault(o, []).append(name)
         if comp is not None:
-            comps[comp].append(name)
-            if line.lstrip().startswith("ROOT"):
+            comps.setdefault(comp, []).append(name)
+            if root:
                 roots[comp] = name
             if opcode == "parameter":
                 params[comp] = name
@@ -251,6 +262,111 @@ def parse(text):
 
     return {name: (info[name].shape, resolve(name))
             for comp in sequential if comp in comps for name in comps[comp]}
+
+
+_ARRAY = re.compile(r"([a-z]\w*)\[([\d,]*)\](\{[^{}]*\})?")
+_SPACE = re.compile(r"S\(\d+\)")
+_PARAM = re.compile(r"\d+")
+# instructions whose value is (a piece of) what they read, in some layout
+# or memory space: a weight stays a weight through them
+_MOVES = ("bitcast", "copy", "copy-start", "copy-done", "slice", "slice-start",
+          "slice-done", "dynamic-slice")
+
+
+def _layout(shape):
+    """``(dims, element bytes, layout without memory space)`` of the first
+    array of a result shape."""
+    m = _ARRAY.search(shape)
+    if m is None:
+        return (), 0, ""
+    dims = tuple(int(d) for d in m.group(2).split(",") if d)
+    bits = _PARAM.search(m.group(1))
+    width = int(bits.group()) // 8 if bits else 1          # pred: a byte
+    return dims, width, _SPACE.sub("", m.group(3) or "")
+
+
+def relayout_bytes(text, argument):
+    """Bytes of the program argument ``argument`` (a pytree: its leaves are
+    the entry parameters whose ``op_name`` is ``argument`` or starts with
+    ``argument[``, as jit names them) that one HLO module's ``copy`` /
+    ``copy-start`` instructions write in another layout than they read,
+    each instruction counted once where it stands (a loop body's, once).
+    A weight is followed through what only moves it (``_MOVES``, a
+    ``ConcatBitcast`` of its slices, a tuple element, a loop's carried
+    value, a fusion's or call's parameter); a change of memory space alone
+    is a prefetch, not a relayout."""
+    info, callers, entry = {}, {}, None
+    for comp, is_entry, _, name, shape, opcode, operands, attrs in \
+            _instructions(text):
+        if is_entry:
+            entry = comp
+        own = _OP_NAME.search(attrs)
+        index = _INDEX.search(attrs)
+        number = _PARAM.match(operands[1:]) if opcode == "parameter" else None
+        info[name] = (shape, opcode, _NAME.findall(operands), comp,
+                      own.group(1) if own else "",
+                      int(index.group(1)) if index else
+                      int(number.group()) if number else None,
+                      'custom_call_target="ConcatBitcast"' in attrs)
+        for _, ref in _REFS.findall(attrs):
+            for callee in _NAME.findall(ref):
+                callers[callee] = name
+    memo = {}
+
+    def element(name, k):
+        """What element ``k`` of a tuple-valued instruction is made of."""
+        i = info.get(name)
+        if i is None:
+            return frozenset()
+        if i[1] == "tuple":
+            return sources(i[2][k]) if k < len(i[2]) else frozenset()
+        if i[1] == "while":                 # what the loop carries
+            return element(i[2][0], k)
+        caller = info.get(callers.get(i[3]))
+        if i[1] == "parameter" and caller is not None \
+                and caller[1] == "while":
+            return element(caller[2][0], k)
+        return frozenset()
+
+    def sources(name):
+        """The entry parameters ``name``'s value only moves."""
+        if name in memo:
+            return memo[name]
+        memo[name] = frozenset()            # a cycle moves nothing
+        i = info.get(name)
+        out = frozenset()
+        if i is None:
+            pass
+        elif i[1] == "parameter":
+            caller = info.get(callers.get(i[3]))
+            if i[3] == entry:
+                out = frozenset([name])
+            elif caller is not None and caller[1] != "while" \
+                    and i[5] is not None and i[5] < len(caller[2]):
+                out = sources(caller[2][i[5]])
+        elif i[1] == "get-tuple-element" and i[5] is not None:
+            out = element(i[2][0], i[5])
+        elif i[1] in _MOVES or i[6]:
+            out = frozenset().union(*(sources(o) for o in i[2]))
+        memo[name] = out
+        return out
+
+    def weight(name):
+        op = info[name][4]
+        return info[name][3] == entry and (op == argument
+                                           or op.startswith(argument + "["))
+
+    total = 0
+    for name, i in info.items():
+        if i[1] not in ("copy", "copy-start") or not i[2] \
+                or i[2][0] not in info:
+            continue
+        dims, width, layout = _layout(i[0])
+        if layout == _layout(info[i[2][0]][0])[2]:
+            continue
+        if any(weight(s) for s in sources(i[2][0])):
+            total += width * math.prod(dims)
+    return total
 
 
 def record(text):

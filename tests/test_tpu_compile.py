@@ -416,6 +416,110 @@ def test_a_looped_stacks_programs_hold_each_layers_kernels_once(
     assert mixed.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
 
 
+def _as_on_a_tpu(rule):
+    """A kernel rule that asks ``jax.devices()`` answering as on a TPU."""
+    def applies(*args):
+        devices = jax.devices
+        jax.devices = lambda *a: [type("Tpu", (), {"platform": "tpu"})()]
+        try:
+            return rule(*args)
+        finally:
+            jax.devices = devices
+    return applies
+
+
+# (configuration, layers, whether the mixed step asks for a weight in another
+# layout than the chip's default for its shape): a few layers of each served
+# configuration at its published widths. Olmo-Hybrid's linear q/k
+# ([3840, 2880]) lie transposed by default already: 2880 is no multiple of 128
+SERVED = [pytest.param("deepseek-llm-7b", 2, True, id="llama-deepseek"),
+          pytest.param("mimo-v2-flash", 2, True, id="mimo"),
+          pytest.param("olmo-hybrid-7b", 4, False, id="olmo-3-linear-1-full"),
+          pytest.param("ouro-2.6b", 2, True, id="ouro-4-passes")]
+
+
+@pytest.mark.parametrize("config,layers,moves", SERVED)
+def test_the_serving_programs_read_the_weights_as_placed(
+        one_chip, mosaic, monkeypatch, config, layers, moves):
+    """The mixed step and the burst of each served configuration (its
+    published widths, a few layers, a vocabulary of 1024, the cell's lanes),
+    compiled for a described v5e as the engine compiles them: the mixed step
+    with the weights' layouts left to the compiler
+    (``serving._free_formats``), the burst against the weights in the
+    formats the mixed step chose (as ``place_weights`` puts them). Neither
+    copies a weight into another layout (``parts.relayout_bytes``), and the
+    burst reads every weight in the format the mixed step chose. Against the
+    weights in the chip's default layouts, the burst copies every weight the
+    placement moves (q/k, and Llama's and Ouro's v), in every call."""
+    import json
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    for path in (os.path.dirname(bench), bench):
+        if path not in sys.path:
+            sys.path.insert(0, path)
+    import importlib
+
+    from jax.experimental.layout import Format
+
+    from paddle_tpu.models import serving
+    from paddle_tpu.monitor import parts
+
+    monkeypatch.setattr(paged_kv, "_kernel_applies",
+                        _as_on_a_tpu(paged_kv._kernel_applies))
+    monkeypatch.setattr(held_experts, "_kernel_applies",
+                        _as_on_a_tpu(held_experts._kernel_applies))
+    monkeypatch.setattr(gated_delta_rule, "_kernel_applies",
+                        lambda q, v, s: True)
+    with open(os.path.join(bench, "configs", config + ".json")) as f:
+        cfg = json.load(f)
+    cfg.update(num_hidden_layers=layers, vocab_size=1024,
+               initializer_range=0.0)
+    model = importlib.import_module("builders." + cfg["builder"]).construct(
+        cfg)
+    model.eval()
+    eng = serving.ContinuousBatchingEngine(
+        model, **dict(cfg["engine"], max_len=256))
+
+    def sds(shape, dtype, sharding=one_chip):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def shapes(tree):
+        return jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
+
+    T, B, weights = eng.max_step_tokens, eng.max_batch, eng._inner.weights
+    held = shapes(weights)
+    args = (sds((2, T), jnp.int32), shapes(eng._pools), shapes(eng._tables()),
+            sds((T,), jnp.int32), sds((T,), jnp.bool_), sds((T,), jnp.bool_))
+    mixed = jax.jit(eng._inner.build_mixed_step(), donate_argnums=(1,),
+                    in_shardings=(None,) * len(args)
+                    + (serving._free_formats(held),)).lower(
+                        *args, held).compile()
+    chosen = mixed.input_formats[0][-1]
+    placed = jax.tree.map(   # (a weight no program reads has no layout)
+        lambda a, fmt: a if fmt.layout is None else sds(a.shape, a.dtype, fmt),
+        held, chosen)
+
+    def burst(w):
+        return jax.jit(eng._inner.build_decode_burst(eng.decode_burst),
+                       donate_argnums=(1,)).lower(
+            sds((2, B), jnp.int32), shapes(eng._pools), shapes(eng._tables()),
+            w).compile()
+
+    after, before = burst(placed), burst(held)
+    assert parts.relayout_bytes(mixed.as_text(), "w") == 0
+    assert parts.relayout_bytes(after.as_text(), "w") == 0
+    assert jax.tree.leaves(after.input_formats[0][-1]) == \
+        jax.tree.leaves(chosen)
+    default = jax.tree.leaves(before.input_formats[0][-1])
+    moved = [(a, fmt) for a, fmt, was in zip(jax.tree.leaves(held),
+                                             jax.tree.leaves(chosen), default)
+             if isinstance(fmt, Format) and fmt.layout != was.layout]
+    assert bool(moved) == moves
+    assert parts.relayout_bytes(before.as_text(), "w") == sum(
+        a.size * a.dtype.itemsize for a, _ in moved)
+
+
 def test_paged_attention_refuses_pools_whose_heads_fill_no_sublane_tile():
     """30 KV heads on a [blocks, block, heads, 128] pool: the one-row-a-head
     kernel's slices are refused by the compiler ("must be aligned to tiling
