@@ -1,4 +1,6 @@
-"""A gated delta-rule (linear-attention) layer's mixer, for the serving block.
+"""A gated delta-rule (linear-attention) layer's mixer, for the serving block:
+Gated DeltaNet's, with a decay a head, and Kimi Delta Attention's (KDA), with
+a decay a key channel.
 
 The layer keeps no keys and values: per sequence it has a float32 STATE
 ``[heads, key_dim, value_dim]`` and the last ``conv_kernel - 1`` inputs of its
@@ -17,6 +19,20 @@ the null slot. With ``x`` [T, hidden], one token a lane:
 - the recurrence (``ops/pallas/gated_delta_rule.py``), float32;
 - per head ``o = RMS(o) * w_o * silu(z)``, then ``Wo``.
 
+The layer's weights' keys say which layer it is. KDA (Kimi Linear,
+arXiv:2510.26692; flash-linear-attention's ``KimiDeltaAttention``) has
+``wfa`` / ``wfb`` in place of ``wa`` and ``wga`` / ``wgb`` in place of ``wz``:
+
+- the decay a key channel, of low rank: ``g = -exp(A_log) softplus(x Wfa Wfb
+  + dt_bias)`` in ``[heads, key_dim]`` a token (``A_log`` a head,
+  ``dt_bias`` a channel), and the recurrence's first factor ``Diag(exp g)
+  S``;
+- the output gate a sigmoid, of low rank: ``o = RMS(o) * w_o *
+  sigmoid(x Wga Wgb)``.
+
+``ln1`` among the weights is a norm before the mixer (a pre-norm stack: the
+mixer reads ``RMS(x)``).
+
 A lane's predecessors are the lanes before it in its run and, before those,
 what the slot holds; a run that starts at position 0 starts from zeros
 whatever the slot holds, which is how a slot is reset for its next request.
@@ -29,6 +45,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.pallas import gated_delta_rule as gdr
+from .llama_decode import _rms
 
 _F32 = jnp.float32
 
@@ -92,6 +109,27 @@ def _l2norm(x):
                              + np.float32(1e-6))
 
 
+def _decay(p, x, T, H, dk):
+    """``g`` (log decay, float32): [T, H] a head, or [T, H, dk] a key
+    channel where the layer has the low-rank decay ``wfa`` / ``wfb``."""
+    if "wfa" in p:
+        a = ((x @ p["wfa"]) @ p["wfb"]).astype(_F32) \
+            + p["dt_bias"].astype(_F32)
+        return -jnp.exp(p["A_log"].astype(_F32))[:, None] \
+            * jax.nn.softplus(a).reshape(T, H, dk)
+    return -jnp.exp(p["A_log"].astype(_F32)) * jax.nn.softplus(
+        (x @ p["wa"]).astype(_F32) + p["dt_bias"].astype(_F32))
+
+
+def _gate(p, x, T, H, dv):
+    """The output gate, float32 [T, H, dv]: ``silu(x Wz)``, or
+    ``sigmoid(x Wga Wgb)`` where the layer has the low-rank one."""
+    if "wgb" in p:
+        return jax.nn.sigmoid(
+            ((x @ p["wga"]) @ p["wgb"]).astype(_F32).reshape(T, H, dv))
+    return jax.nn.silu((x @ p["wz"]).astype(_F32).reshape(T, H, dv))
+
+
 def mixer(kind, p, x, pool, positions, plan, eps):
     """The layer's mixer for ``x`` [T, hidden], one token a lane; ``plan``
     None (lane ``i`` is slot ``i``'s next token) or
@@ -100,6 +138,8 @@ def mixer(kind, p, x, pool, positions, plan, eps):
     state, conv = pool
     T = x.shape[0]
     H, dk, dv = kind.num_heads, kind.key_dim, kind.value_dim
+    if "ln1" in p:
+        x = _rms(x, p["ln1"], eps)
     xin = jnp.concatenate([x @ p["wq"], x @ p["wk"], x @ p["wv"]], axis=-1)
     y, conv = causal_conv(xin, conv, p["conv"], positions, plan)
     y = jax.nn.silu(y)
@@ -109,11 +149,9 @@ def mixer(kind, p, x, pool, positions, plan, eps):
     beta = jax.nn.sigmoid((x @ p["wb"]).astype(_F32))
     if kind.neg_eigval:
         beta = beta * np.float32(2.0)
-    g = -jnp.exp(p["A_log"].astype(_F32)) * jax.nn.softplus(
-        (x @ p["wa"]).astype(_F32) + p["dt_bias"].astype(_F32))
+    g = _decay(p, x, T, H, dk)
     o, state = gdr.gated_delta(q, k, v, g, beta, state, positions, plan)
     o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
                           + np.float32(eps))
-    o = (o.astype(x.dtype) * p["o_norm"]).astype(_F32) * jax.nn.silu(
-        (x @ p["wz"]).astype(_F32).reshape(T, H, dv))
+    o = (o.astype(x.dtype) * p["o_norm"]).astype(_F32) * _gate(p, x, T, H, dv)
     return o.astype(x.dtype).reshape(T, H * dv), (state, conv)

@@ -164,7 +164,8 @@ class CacheKind:
 @dataclasses.dataclass(frozen=True)
 class StateKind:
     """One kind of RECURRENT layer (a gated delta-rule linear-attention
-    layer), as the serving block and the cache manager see it: a sequence's
+    layer: Gated DeltaNet's or KDA's, which the layer's weights tell apart),
+    as the serving block and the cache manager see it: a sequence's
     cache of this kind is not addressed by position. It is one float32 state
     ``[num_heads, key_dim, value_dim]`` and the last ``conv_kernel - 1``
     inputs of the layer's convolutions, in the SLOT of the sequence
@@ -172,7 +173,7 @@ class StateKind:
     nothing of it can be reused by another sequence, spilled in part or
     rolled back."""
 
-    name: str                 # "linear": the counters' label
+    name: str                 # "linear" | "kda": the counters' label
     num_heads: int
     key_dim: int
     value_dim: int
@@ -234,6 +235,22 @@ def _tables(pager):
     if isinstance(pager, _Pagers):
         return tuple(pg.block_tables for pg in pager.pagers)
     return (pager.block_tables,)
+
+
+def prefill_logits(model, input_ids):
+    """Logits [B, S, vocab] of whole sequences through ``model``'s decode
+    engine (``model.decode_engine_class``): its lockstep prefill over pools
+    made for this call. Inference only, and no cache is kept."""
+    ids = jnp.asarray(getattr(input_ids, "value", input_ids), jnp.int32)
+    B, S = ids.shape
+    eng = model.decode_engine_class(model, max_len=S,
+                                    kv_cache_layout="paged")
+    pager, pools = eng._init_paged(B)
+    pager.ensure_capacity([S] * B)
+    logits, _ = eng._prefill_paged_jit(
+        ids, pools, _tables(pager), jnp.full((B,), S, jnp.int32),
+        eng.weights)
+    return logits
 
 
 class LlamaDecodeEngine:
@@ -526,14 +543,24 @@ class LlamaDecodeEngine:
     def _post_attn(self, p, x, attn, valid=None, part="attn_proj"):
         """Shared epilogue: output proj + residual + rms + the layer's MLP,
         dense SwiGLU or (``router`` among the layer's weights) the experts
-        this engine holds. Returns ``(x, pairs)``: ``pairs`` [4] int32 counts
-        the (token, expert) pairs on held experts and in all, and the held
-        experts that got one, over ``valid`` tokens, and the rows of the
-        grouped product's row tiles; None for a dense layer. The output
+        this engine holds, and beside them a shared expert every token goes
+        through (``shared_gate`` / ``shared_up`` / ``shared_down``). Returns
+        ``(x, pairs)``: ``pairs`` [4] int32 counts the (token, expert) pairs
+        on held experts and in all, and the held experts that got one, over
+        ``valid`` tokens, and the rows of the grouped product's row tiles;
+        None for a dense layer. The output
         projection is the device part ``part`` (a recurrent layer's mixer
-        owns its own), the rest ``mlp`` or ``experts``."""
+        owns its own), the rest ``mlp`` or ``experts``. ``attn_gate`` among
+        the weights gates the attention's output elementwise before the
+        projection, ``sigmoid(h Wg)`` of the layer's input ``h`` (after
+        ``ln1``)."""
         B, S = x.shape[0], x.shape[1]
         with _part(part):
+            if "attn_gate" in p:
+                h = _rms(x, p["ln1"], self.eps) if "ln1" in p else x
+                attn = (attn.reshape(B, S, -1).astype(jnp.float32)
+                        * jax.nn.sigmoid((h @ p["attn_gate"]).astype(
+                            jnp.float32))).astype(attn.dtype)
             out = attn.reshape(B, S, -1) @ p["wo"]
             if "post_attn_norm" in p:   # the norm on the sublayer's OUTPUT
                 out = _rms(out, p["post_attn_norm"], self.eps)
@@ -555,7 +582,11 @@ class LlamaDecodeEngine:
                 h2.reshape(B * S, -1), p["router"], p["router_bias"],
                 p["w1"], p["w3"], p["w2"], self.held_lo, self.top_k,
                 None if valid is None else valid.reshape(B * S))
-            return x + y.reshape(x.shape), pairs
+            y = y.reshape(x.shape)
+            if "shared_gate" in p:      # every chip computes it alike
+                y = y + (jax.nn.silu(h2 @ p["shared_gate"])
+                         * (h2 @ p["shared_up"])) @ p["shared_down"]
+            return x + y, pairs
 
     def _block_paged(self, li, p, x, pool, tables, positions, valid=None,
                      prompt=False, counted=None, rows=None, runs=None):

@@ -36,7 +36,8 @@ from ..framework.core import Tensor
 from ..nn.initializer import Assign, Constant, Normal
 from ..nn.layer.container import LayerList
 from ..nn.layer.layers import Layer
-from .llama_decode import (CacheKind, LlamaDecodeEngine, StateKind, _tables)
+from .llama_decode import (CacheKind, LlamaDecodeEngine, StateKind,
+                           prefill_logits)
 from .mimo_v2 import _Weight
 
 LINEAR = "linear_attention"
@@ -241,16 +242,7 @@ class OlmoHybridForCausalLM(Layer):
         """Logits [B, S, vocab] of whole sequences: inference only, and no
         cache is kept. It IS the serving block's lockstep prefill, over pools
         made for this call."""
-        ids = jnp.asarray(getattr(input_ids, "value", input_ids), jnp.int32)
-        B, S = ids.shape
-        eng = self.decode_engine_class(self, max_len=S,
-                                       kv_cache_layout="paged")
-        pager, pools = eng._init_paged(B)
-        pager.ensure_capacity([S] * B)
-        logits, _ = eng._prefill_paged_jit(
-            ids, pools, _tables(pager), jnp.full((B,), S, jnp.int32),
-            eng.weights)
-        return Tensor(logits)
+        return Tensor(prefill_logits(self, input_ids))
 
 
 __all__ = ["OlmoHybridConfig", "OlmoHybridForCausalLM",

@@ -31,13 +31,11 @@ model runs, told that the stack runs ``total_ut_steps`` times.
 """
 from __future__ import annotations
 
-import jax.numpy as jnp
-
 from ..framework.core import Tensor
 from ..nn.initializer import Constant, Normal
 from ..nn.layer.container import LayerList
 from ..nn.layer.layers import Layer
-from .llama_decode import CacheKind, LlamaDecodeEngine, _tables
+from .llama_decode import CacheKind, LlamaDecodeEngine, prefill_logits
 from .mimo_v2 import _Weight
 
 
@@ -177,16 +175,7 @@ class OuroForCausalLM(Layer):
         """Logits [B, S, vocab] of whole sequences: inference only, and no
         cache is kept. It IS the serving block's lockstep prefill, over pools
         made for this call."""
-        ids = jnp.asarray(getattr(input_ids, "value", input_ids), jnp.int32)
-        B, S = ids.shape
-        eng = self.decode_engine_class(self, max_len=S,
-                                       kv_cache_layout="paged")
-        pager, pools = eng._init_paged(B)
-        pager.ensure_capacity([S] * B)
-        logits, _ = eng._prefill_paged_jit(
-            ids, pools, _tables(pager), jnp.full((B,), S, jnp.int32),
-            eng.weights)
-        return Tensor(logits)
+        return Tensor(prefill_logits(self, input_ids))
 
 
 __all__ = ["OuroConfig", "OuroForCausalLM", "OuroDecodeEngine"]

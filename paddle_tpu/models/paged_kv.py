@@ -100,7 +100,7 @@ class StateSlots:
 
         self.batch = int(batch)
         self.slots = self.batch + 1
-        pack = pack_of(kind.num_heads)
+        pack = pack_of(kind.num_heads, kind.value_dim)
         shape = (self.slots, kind.num_heads // pack, kind.key_dim,
                  pack * kind.value_dim)
         cshape = (self.slots, kind.conv_kernel - 1, kind.conv_width)

@@ -118,7 +118,8 @@ class _Mon:
                  "kind_blocks", "attn_lanes", "block_steps", "expert_pairs",
                  "window_released", "linear_tokens", "linear_runs",
                  "byte_steps", "state_bytes", "state_resets", "dispatched",
-                 "forwards", "passes", "lane_steps", "relayout")
+                 "forwards", "passes", "lane_steps", "relayout",
+                 "shared_rows")
 
 
 _MON = None
@@ -219,6 +220,9 @@ def _mon():
                                  labelnames=("use",))
         o.relayout = m.gauge("paddle_tpu_serving_weight_relayout_bytes",
                              labelnames=("where",))
+        o.shared_rows = m.counter(
+            "paddle_tpu_serving_shared_expert_rows_total",
+            labelnames=("lanes",))
         _MON = o
     return _MON
 
@@ -509,6 +513,8 @@ class ContinuousBatchingEngine:
                         if not k.paged]
         self.state_pool_bytes = sum(layers * c.slots * c.slot_bytes
                                     for _, layers, c in self._states)
+        # expert layers with a shared expert beside the routed ones
+        self._shared_layers = sum("shared_gate" in p for p in e.layers)
         self._slot_resets = 0           # admissions since the last step
         # the capacity lever the pool-bytes gauge documents: equal byte
         # budgets admit ~2x the requests when the pools are quantized
@@ -1795,6 +1801,8 @@ class ContinuousBatchingEngine:
         else:
             fl, lanes, emits = self._dispatch_mixed(
                 t0, decode_slots, chunks, draft_map, prev, mon)
+        if mon.state.on and self._shared_layers:
+            self._count_shared_rows(mon, fl, len(decode_slots))
         # the download starts now: the fetch, a call later, finds it done
         fl.out.copy_to_host_async()
         if self._out_shapes.get(kind) != fl.out.shape:
@@ -1878,6 +1886,21 @@ class ContinuousBatchingEngine:
         mon.lane_steps.labels("held").inc(rows - granted)
         mon.lane_steps.labels("empty").inc(
             self.max_batch * fl.forwards - rows)
+
+    def _count_shared_rows(self, mon, fl, decoding):
+        """``paddle_tpu_serving_shared_expert_rows_total{lanes}`` for a
+        dispatched step of a model with a shared expert: the rows its shared
+        experts multiply, each lane of the program once a forward pass and a
+        layer that has one. lanes=used the valid lanes (a burst: the
+        ``decoding`` rows), lanes=idle the program's padding lanes and idle
+        rows, which the shared expert multiplies all the same."""
+        if fl.kind == "burst":
+            used, total = decoding * fl.forwards, self.max_batch * fl.forwards
+        else:
+            used, total = fl.n_lanes, self.max_step_tokens
+        mon.shared_rows.labels("used").inc(used * self._shared_layers)
+        mon.shared_rows.labels("idle").inc(
+            (total - used) * self._shared_layers)
 
     def _grant(self, req, lens, n, max_new_tokens):
         """Grant ``req`` up to ``n`` more output tokens from a row that

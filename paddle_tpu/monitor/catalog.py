@@ -124,6 +124,15 @@ METRICS = {
         "burst), use=empty the row holds no request. Counted at dispatch; "
         "the three sum to max_batch x paddle_tpu_serving_forwards_total. "
         "granted over all is lanes_used_share.sat."),
+    "paddle_tpu_serving_shared_expert_rows_total": (
+        "counter", ("lanes",),
+        "Rows a model's shared experts multiply (the expert every token goes "
+        "through beside the routed ones): each lane of a dispatched step's "
+        "program once a forward pass and an expert layer that has one. "
+        "lanes=used the valid lanes (a burst: its decoding rows), "
+        "lanes=idle the program's padding lanes and idle rows, which the "
+        "shared expert multiplies all the same. Counted at dispatch; 0 "
+        "series for a model without a shared expert."),
     "paddle_tpu_serving_weight_relayout_bytes": (
         "gauge", ("where",),
         "Weight bytes in another layout than the model held them: "
@@ -177,7 +186,7 @@ METRICS = {
     "paddle_tpu_serving_linear_tokens_total": (
         "counter", ("path",),
         "Tokens of completed engine steps that went through a recurrent "
-        "(gated delta-rule) layer's recurrence, one layer once, by the "
+        "(gated delta-rule or KDA) layer's recurrence, one layer once, by the "
         "path that served them: path=chunk the lanes of prefill runs of two "
         "tokens or more (the chunked form, gated_delta_chunk), path=step "
         "runs of one (decode lanes, every iteration of a burst, a one-token "
@@ -366,8 +375,8 @@ METRICS = {
         "kind and multiplied by the layer caches that keep a pool of that "
         "kind (a looped stack's layers x passes for a paged kind): a "
         "paged kind's (full | window) blocks in use times a block's bytes, "
-        "a recurrent kind's (linear) slots in use times a slot's state and "
-        "kept convolution inputs."),
+        "a recurrent kind's (linear | kda) slots in use times a slot's state "
+        "and kept convolution inputs."),
     "paddle_tpu_state_pool_bytes": (
         "gauge", (),
         "Device bytes of the recurrent layers' state pool: (max_batch + 1 "
@@ -790,8 +799,8 @@ PARTS = {
     "attn_proj": (
         "An attention layer's weights around its attention: the norm before "
         "the projections, the q / k / v projections and their norms, the "
-        "rotary embedding; the output projection, its norm and the "
-        "residual add."),
+        "rotary embedding; the gate on the attention's output, the output "
+        "projection, its norm and the residual add."),
     "kv_write": (
         "The writes of the lanes' keys and values into the paged pools "
         "(paged_write_*)."),
@@ -799,9 +808,10 @@ PARTS = {
         "The attention over the paged pools (paged_attention_decode*): the "
         "Pallas kernels and their glue, or the plain gather path."),
     "recurrent": (
-        "A recurrent (gated delta-rule) layer's mixer: its projections, the "
-        "convolutions, the recurrence's kernels and their glue, the output "
-        "norm and gate; and the plan of the step's runs."),
+        "A recurrent (gated delta-rule or KDA) layer's mixer: the norm before "
+        "it, its projections, the convolutions, the decay and gates, the "
+        "recurrence's kernels and their glue, the output norm, gate and "
+        "projection; and the plan of the step's runs."),
     "mlp": (
         "A dense layer's MLP: the norm before it, gate / up / down, the norm "
         "on its output and the residual add."),
@@ -810,7 +820,8 @@ PARTS = {
         "the chosen weights."),
     "experts": (
         "An expert layer's held experts: the pairs' sort, the groups, the "
-        "grouped products and the combine by token; the residual add."),
+        "grouped products and the combine by token; the shared expert "
+        "beside them; the residual add."),
     "head": (
         "The final norm (after every pass of a looped stack) and the "
         "vocabulary head's product."),

@@ -35,10 +35,25 @@ It is the same function of its inputs as ``C`` single steps. Rows of zeros
 (``k``, ``v``, ``g``, ``beta`` all 0) change nothing, so a run's last chunk
 is padded with them.
 
+**A decay per key channel** (Kimi Delta Attention, arXiv:2510.26692): ``g``
+is ``[dk]`` a token and the step's first factor is ``Diag(exp g) S``. Every
+op below takes it where it takes ``g``: ``g`` ``[T, H, dk]`` in place of ``[T,
+H]`` says which rule runs, and the kernels are ``%kda_step.N`` and
+``%kda_chunk.N``. In the chunked form ``c`` is a running sum a channel and
+``D`` has a channel axis: ``(q k^T) * D`` becomes ``sum_d q_id k_jd exp(c_id
+- c_jd)``. Unbounded decays make ``exp(-c_j)`` overflow float32 within a few
+tokens, so that sum is never split into ``exp(c_i)`` and ``exp(-c_j)``. The
+chunk is cut into sub-chunks of ``SUB`` rows: a pair in different sub-chunks
+is split at the first row ``r`` of ``i``'s, ``exp(c_i - c_r) exp(c_r - c_j)``,
+both factors at most 1, and one MXU product a sub-chunk covers them all; a
+pair in one sub-chunk is taken whole, a diagonal ``i - j = o`` at a time, from
+``k`` and ``c`` shifted down ``o`` rows.
+
 **Layouts.** The state pool is ``[slots, H / P, dk, P * dv]``: ``P``
 (``pack``) heads side by side along the lanes, 2 where the heads pair up, so
 that a row fills whole 128-lane registers where one head's ``dv`` would not
-(192 -> 384: no lane is padded in HBM or in VMEM); ``pack_state`` /
+(192 -> 384: no lane is padded in HBM or in VMEM), and 1 where one head's
+``dv`` fills whole registers itself (128); ``pack_state`` /
 ``unpack_state`` convert from and to ``[slots, H, dk, dv]``. A kernel scores
 ``k`` [.., dk] against a pair's state by a product over all ``P * dv`` lanes
 per head and keeps each head's own lanes (a select by the lane's index).
@@ -72,16 +87,19 @@ from jax.experimental.pallas import tpu as pltpu
 from .flash_attention import _interpret
 
 __all__ = ["CHUNK", "pack_of", "pack_state", "unpack_state", "plan_runs",
-           "max_chunks", "step_reference", "chunk_reference", "gated_delta"]
+           "max_chunks", "step_reference", "chunk_reference",
+           "kda_chunk_reference", "gated_delta"]
 
 CHUNK = 64
+SUB = 8                 # rows of a sub-chunk of the per-channel chunked form
 _HI = lax.Precision.HIGHEST
 _F32 = jnp.float32
 
 
-def pack_of(heads):
-    """Heads side by side along a state row's lanes."""
-    return 2 if heads % 2 == 0 else 1
+def pack_of(heads, value_dim):
+    """Heads side by side along a state row's lanes: two where they pair up
+    and one head's ``value_dim`` fills no whole 128-lane register."""
+    return 2 if heads % 2 == 0 and value_dim % 128 else 1
 
 
 def pack_state(s, pack):
@@ -102,10 +120,11 @@ def unpack_state(s, pack):
 # the plain forms: the kernels' references, and what runs off the TPU
 # ---------------------------------------------------------------------------
 def step_reference(q, k, v, g, beta, s):
-    """One token a lane. ``q``, ``k`` [N, H, dk], ``v`` [N, H, dv], ``g``,
-    ``beta`` [N, H], ``s`` [N, H, dk, dv], all float32. Returns ``(o [N, H,
-    dv], s')``."""
-    s = s * jnp.exp(g)[..., None, None]
+    """One token a lane. ``q``, ``k`` [N, H, dk], ``v`` [N, H, dv], ``g``
+    [N, H] (or a decay a key channel, [N, H, dk]), ``beta`` [N, H], ``s`` [N,
+    H, dk, dv], all float32. Returns ``(o [N, H, dv], s')``."""
+    s = s * (jnp.exp(g)[..., None] if g.ndim == 3
+             else jnp.exp(g)[..., None, None])
     u = beta[..., None] * (v - jnp.einsum("nhd,nhdv->nhv", k, s,
                                           precision=_HI))
     s = s + k[..., :, None] * u[..., None, :]
@@ -139,6 +158,36 @@ def chunk_reference(q, k, v, g, beta, s):
     last = c[:, -1]
     s = s * jnp.exp(last)[:, None, None] + mm(
         "hid,hiv->hdv", k * jnp.exp(last[:, None] - c)[..., None], vn)
+    return jnp.swapaxes(o, 0, 1), s
+
+
+def kda_chunk_reference(q, k, v, g, beta, s):
+    """``chunk_reference`` with a decay a key channel: ``g`` [C, H, dk]. The
+    decays between every two tokens are taken whole, ``exp(c_i - c_j)`` of
+    each channel, never split."""
+    C = q.shape[0]
+    q, k, v, g = (jnp.swapaxes(x, 0, 1) for x in (q, k, v, g))   # [H, C, .]
+    beta = beta.T                                            # [H, C]
+    c = jnp.cumsum(g, axis=1)
+    i = jnp.arange(C, dtype=jnp.int32)
+    low = (i[:, None] >= i[None, :])[None, :, :, None]
+    d = jnp.exp(jnp.where(low, c[:, :, None] - c[:, None], -1e30))
+    kb = k * beta[..., None]
+    mm = functools.partial(jnp.einsum, precision=_HI)
+    lmat = jnp.where(i[:, None] > i[None, :], mm("hid,hjd,hijd->hij", kb, k, d),
+                     0.0)
+    t = jax.scipy.linalg.solve_triangular(
+        lmat + jnp.eye(C, dtype=_F32), jnp.broadcast_to(
+            jnp.eye(C, dtype=_F32), lmat.shape), lower=True,
+        unit_diagonal=True)
+    ec = jnp.exp(c)
+    vn = mm("hij,hjv->hiv", t,
+            v * beta[..., None] - mm("hid,hdv->hiv", kb * ec, s))
+    o = mm("hid,hdv->hiv", q * ec, s) \
+        + mm("hij,hjv->hiv", mm("hid,hjd,hijd->hij", q, k, d), vn)
+    last = c[:, -1]                                          # [H, dk]
+    s = s * jnp.exp(last)[..., None] + mm(
+        "hid,hiv->hdv", k * jnp.exp(last[:, None] - c), vn)
     return jnp.swapaxes(o, 0, 1), s
 
 
@@ -263,9 +312,11 @@ def _step_kernel(order_ref, slot_ref, fresh_ref, qt_ref, kt_ref, v_ref,
 
 def _step_call(q, k, v, g, beta, state, order, slot, fresh, count):
     """``q``, ``k`` [T, H, dk], ``v`` [T, H, dv], ``g``, ``beta`` [T, H]
-    float32; ``state`` the pool; the lanes ``order[:count]`` run, each on
-    ``slot[i]``, from zero where ``fresh[i]``. Returns ``(o [T, H, dv], rows
-    of lanes not run left as allocated; state')``."""
+    float32 (``g`` [T, H, dk]: a decay a key channel, ``%kda_step``);
+    ``state`` the pool; the lanes ``order[:count]`` run, each on ``slot[i]``,
+    from zero where ``fresh[i]``. Returns ``(o [T, H, dv], rows of lanes not
+    run left as allocated; state')``. The kernel reads a head's decay as a
+    column down the state's key rows either way: one number, or one a row."""
     T, H, dk = q.shape
     slots, pairs, _, width = state.shape
     pack = H // pairs
@@ -275,6 +326,7 @@ def _step_call(q, k, v, g, beta, state, order, slot, fresh, count):
     pool = pl.BlockSpec((1, pairs, dk, width),
                         lambda i, order, slot, fresh: (slot[i], zero, zero,
                                                        zero))
+    channel = g.ndim == 3
     o, state = pl.pallas_call(
         functools.partial(_step_kernel, pack=pack, dv=dv),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -285,7 +337,7 @@ def _step_call(q, k, v, g, beta, state, order, slot, fresh, count):
             in_specs=[pl.BlockSpec((1, dk, H), lane3),
                       pl.BlockSpec((1, dk, H), lane3),
                       pl.BlockSpec((1, pairs, width), lane3),
-                      pl.BlockSpec((1, 1, H), lane3),
+                      pl.BlockSpec((1, dk if channel else 1, H), lane3),
                       pl.BlockSpec((1, 1, H), lane3), pool],
             out_specs=[pl.BlockSpec((1, pairs, width), lane3), pool]),
         out_shape=[jax.ShapeDtypeStruct((T, pairs, width), _F32),
@@ -295,10 +347,11 @@ def _step_call(q, k, v, g, beta, state, order, slot, fresh, count):
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=32 * 2 ** 20),
         interpret=_interpret(),
-        name="gated_delta_step",
+        name="kda_step" if channel else "gated_delta_step",
     )(order, slot, fresh, jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
-      v.reshape(T, pairs, width), jnp.exp(g)[:, None, :], beta[:, None, :],
-      state)
+      v.reshape(T, pairs, width),
+      jnp.swapaxes(jnp.exp(g), 1, 2) if channel else jnp.exp(g)[:, None, :],
+      beta[:, None, :], state)
     return o.reshape(T, H, dv), state
 
 
@@ -312,8 +365,77 @@ _ABT = ((1,), (1,))         # a @ b^T
 _ATB = ((0,), (0,))         # a^T @ b
 
 
+def _within(x, y, c, strict):
+    """``[C, C]``: ``sum_d x_id y_jd exp(c_id - c_jd)`` for ``j <= i`` (``j <
+    i`` where ``strict``), else 0, for ``x``, ``y``, ``c`` [C, dk] with
+    ``c`` falling down each column. Pairs in different sub-chunks of ``SUB``
+    rows are split at the first row ``r`` of ``i``'s, each factor's exponent
+    at most 0 (one product a sub-chunk); pairs in one sub-chunk are taken a
+    diagonal ``i - j = o`` at a time from ``y`` and ``c`` shifted down ``o``
+    rows (a product with a shift matrix, which moves values exactly)."""
+    C, dk = x.shape
+    sub = lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    lane = lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    row = lax.broadcasted_iota(jnp.int32, (C, dk), 0)
+    out = jnp.zeros((C, C), _F32)
+    for r in range(SUB, C, SUB):
+        ref = c[r:r + 1, :]
+        xs = x * jnp.exp(jnp.where((row >= r) & (row < r + SUB), c - ref,
+                                   np.float32(-1e30)))
+        ys = y * jnp.exp(jnp.where(row < r, ref - c, np.float32(-1e30)))
+        out = out + _dot(xs, ys, _ABT)
+    for o in range(int(strict), SUB):
+        shift = (lane == sub - o).astype(_F32)
+        e = jnp.exp(jnp.where((row & np.int32(SUB - 1)) >= o,
+                              c - _dot(shift, c, _AB), np.float32(-1e30)))
+        d = jnp.sum(x * _dot(shift, y, _AB) * e, axis=1, keepdims=True)
+        out = out + jnp.where(lane == sub - o, d, np.float32(0))
+    return out
+
+
+def _kda_heads(q_ref, k_ref, kb_ref, c_ref, t_scr, row_id, pack):
+    """What ``_chunk_kernel``'s pair takes of each head with a decay a key
+    channel: ``c_ref`` holds the running sums [C, dk]."""
+    C = q_ref.shape[2]
+    eye = (lax.broadcasted_iota(jnp.int32, (C, C), 0)
+           == lax.broadcasted_iota(jnp.int32, (C, C), 1)).astype(_F32)
+    heads = []
+    for a in range(pack):
+        q, k, kb, c = q_ref[0, a], k_ref[0, a], kb_ref[0, a], c_ref[0, a]
+        last = c[C - 1:C, :]                                 # [1, dk]
+        dk = last.shape[1]
+        # the decay of the state's rows, down its sublanes
+        down = lax.broadcasted_iota(jnp.int32, (dk, dk), 0)
+        across = lax.broadcasted_iota(jnp.int32, (dk, dk), 1)
+        decay = jnp.sum(jnp.where(down == across, jnp.exp(last),
+                                  np.float32(0)), axis=1, keepdims=True)
+        # lt[j, i] = L[i, j]
+        _substitute(t_scr, a, _dot(eye, _within(kb, k, c, True), _ABT),
+                    row_id)
+        heads.append({"q": q * jnp.exp(c), "kb": kb * jnp.exp(c),
+                      "aqk": _within(q, k, c, False), "t": a,
+                      "k": k * jnp.exp(last - c), "decay": decay})
+    return heads
+
+
+def _substitute(t_scr, a, lt, row_id):
+    """``t_scr[a] = (I + L)^-1`` by forward substitution, row by row, from
+    ``lt[j, i] = L[i, j]`` (row ``i`` of ``L`` down the sublanes of column
+    ``i``); ``row_id`` [1, C] the lanes' indices."""
+    C = lt.shape[0]
+    t_scr[a] = jnp.zeros((C, C), _F32)
+    for i in range(C):
+        e_i = (row_id == np.int32(i)).astype(_F32)
+        if i == 0:
+            t_scr[a, 0:1, :] = e_i
+            continue
+        top = -(-i // 8) * 8
+        t_scr[a, i:i + 1, :] = e_i - jnp.sum(
+            lt[:top, i:i + 1] * t_scr[a, :top, :], axis=0, keepdims=True)
+
+
 def _chunk_kernel(slot_ref, code_ref, q_ref, k_ref, kb_ref, vb_ref, g_ref,
-                  s_ref, o_ref, so_ref, t_scr, *, pack, dv):
+                  s_ref, o_ref, so_ref, t_scr, *, pack, dv, channel=False):
     del slot_ref
     c = pl.program_id(1)
     code = code_ref[c]
@@ -334,8 +456,9 @@ def _chunk_kernel(slot_ref, code_ref, q_ref, k_ref, kb_ref, vb_ref, g_ref,
     row_id = lax.broadcasted_iota(jnp.int32, (1, C), 1)
     s = so_ref[0, 0]                                         # [dk, width]
 
-    heads = []
-    for a in range(pack):
+    heads = _kda_heads(q_ref, k_ref, kb_ref, g_ref, t_scr, row_id, pack) \
+        if channel else []
+    for a in range(0 if channel else pack):
         q, k, kb = q_ref[0, a], k_ref[0, a], kb_ref[0, a]    # [C, dk]
         g_row = g_ref[0, 0, a:a + 1, :]                      # [1, C]
         # the running sum as a column (down the sublanes) and as a row
@@ -347,16 +470,7 @@ def _chunk_kernel(slot_ref, code_ref, q_ref, k_ref, kb_ref, vb_ref, g_ref,
         # lt[j, i] = L[i, j]: row i of L down the sublanes of column i
         lt = _dot(k, kb, _ABT) * jnp.exp(
             jnp.where(lane > sub, c_row - c_col, np.float32(-1e30)))
-        t_scr[a] = jnp.zeros((C, C), _F32)
-        for i in range(C):
-            e_i = (row_id == np.int32(i)).astype(_F32)
-            if i == 0:
-                t_scr[a, 0:1, :] = e_i
-                continue
-            top = -(-i // 8) * 8
-            t_scr[a, i:i + 1, :] = e_i - jnp.sum(
-                lt[:top, i:i + 1] * t_scr[a, :top, :], axis=0,
-                keepdims=True)
+        _substitute(t_scr, a, lt, row_id)
         aqk = _dot(q, k, _ABT) * jnp.exp(
             jnp.where(sub >= lane, c_col - c_row, np.float32(-1e30)))
         ec = jnp.exp(c_col)
@@ -379,7 +493,9 @@ def _chunk_call(qc, kc, vc, gc, bc, state, slot, code, chunks):
     [NC, C, H] float32: the chunks' lanes, rows past a chunk's lanes zeros;
     chunk ``c`` of the ``chunks`` in use runs on ``slot[c]``, going on from
     the chunk before it (``code[c]`` 0), from the slot's state (1) or from
-    zero (2). Returns ``(o [NC, C, H, dv], state')``."""
+    zero (2). Returns ``(o [NC, C, H, dv], state')``. ``gc`` [NC, C, H, dk]:
+    a decay a key channel (``%kda_chunk``), whose running sums a chunk the
+    kernel takes as it takes ``q``."""
     NC, C, H, dk = qc.shape
     slots, pairs, _, width = state.shape
     pack = H // pairs
@@ -388,15 +504,17 @@ def _chunk_call(qc, kc, vc, gc, bc, state, slot, code, chunks):
     heads = lambda x: jnp.swapaxes(x, 1, 2)                  # noqa: E731
     per_head = pl.BlockSpec((1, pack, C, dk),
                             lambda p, c, slot, code: (c, p, zero, zero))
+    channel = gc.ndim == 4
     pool = pl.BlockSpec((1, 1, dk, width),
                         lambda p, c, slot, code: (slot[c], p, zero, zero))
     rows = pl.BlockSpec((1, C, width), lambda p, c, slot, code: (c, zero, p))
     o, state = pl.pallas_call(
-        functools.partial(_chunk_kernel, pack=pack, dv=dv),
+        functools.partial(_chunk_kernel, pack=pack, dv=dv, channel=channel),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(pairs, jnp.maximum(chunks, 1)),
             in_specs=[per_head, per_head, per_head, rows,
+                      per_head if channel else
                       pl.BlockSpec((1, 1, pack, C),
                                    lambda p, c, slot, code: (c, p, zero,
                                                              zero)),
@@ -409,10 +527,11 @@ def _chunk_call(qc, kc, vc, gc, bc, state, slot, code, chunks):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=_interpret(),
-        name="gated_delta_chunk",
+        name="kda_chunk" if channel else "gated_delta_chunk",
     )(slot, code, heads(qc), heads(kc), heads(kc * bc[..., None]),
       (vc * bc[..., None]).reshape(NC, C, H * dv),
-      jnp.swapaxes(gc, 1, 2).reshape(NC, pairs, pack, C), state)
+      heads(jnp.cumsum(gc, axis=1)) if channel
+      else jnp.swapaxes(gc, 1, 2).reshape(NC, pairs, pack, C), state)
     return o.reshape(NC, C, H, dv), state
 
 
@@ -444,7 +563,8 @@ def _chunk_plain(qc, kc, vc, gc, bc, state, slot, code, chunks):
     def one(state, x):
         q, k, v, g, b, sl, cd, used = x
         s = unpack_state(state[sl][None], pack)[0] * (cd != 2)
-        o, s = chunk_reference(q, k, v, g, b, s)
+        o, s = (kda_chunk_reference if g.ndim == 3 else chunk_reference)(
+            q, k, v, g, b, s)
         return state.at[jnp.where(used, sl, null)].set(
             pack_state(s[None], pack)[0]), o
 
@@ -455,9 +575,10 @@ def _chunk_plain(qc, kc, vc, gc, bc, state, slot, code, chunks):
 
 def gated_delta(q, k, v, g, beta, state, positions, plan=None):
     """One token a lane through the gated delta rule. ``q``, ``k`` [T, H,
-    dk] (normalised and scaled), ``v`` [T, H, dv], ``g``, ``beta`` [T, H],
-    all float32; ``state`` the pool ``[slots, H / P, dk, P * dv]`` float32,
-    its last slot the null slot; ``positions`` [T]. ``plan`` None: lane
+    dk] (normalised and scaled), ``v`` [T, H, dv], ``g``, ``beta`` [T, H]
+    (``g`` [T, H, dk]: a decay a key channel, the KDA rule), all float32;
+    ``state`` the pool ``[slots, H / P, dk, P * dv]`` float32, its last slot
+    the null slot; ``positions`` [T]. ``plan`` None: lane
     ``i`` is the next token of slot ``i`` (T < slots); else ``plan_runs``'
     of the step's lanes. A run that starts at position 0 starts from the
     zero state whatever its slot holds: that IS the slot's reset. Returns

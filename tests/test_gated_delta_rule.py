@@ -71,7 +71,7 @@ def test_rows_of_zeros_change_no_state():
 
 @pytest.mark.parametrize("heads", [4, 3])
 def test_packing_the_state_is_undone_by_unpacking(heads):
-    pack = G.pack_of(heads)
+    pack = G.pack_of(heads, DV)
     s = _normal(jax.random.PRNGKey(0), (5, heads, DK, DV))
     packed = G.pack_state(s, pack)
     assert packed.shape == (5, heads // pack, DK, pack * DV)
@@ -137,7 +137,7 @@ def test_a_step_of_runs_is_its_lanes_one_by_one(monkeypatch, kernels):
         monkeypatch.setattr(G, "_kernel_applies", lambda q, v, s: True)
     rows, pos, valid = _pack()
     q, k, v, g, beta = _draw(4, T)
-    pack = G.pack_of(H)
+    pack = G.pack_of(H, DV)
     state_h = _normal(jax.random.PRNGKey(5), (SLOTS, H, DK, DV)
                       ).at[SLOTS - 1].set(0.0)
     want_o, want_s = _expected(q, k, v, g, beta, state_h)
@@ -165,7 +165,7 @@ def test_a_burst_lane_is_its_slots_next_token(monkeypatch, kernels):
         monkeypatch.setattr(G, "_kernel_applies", lambda q, v, s: True)
     B = 4
     q, k, v, g, beta = _draw(6, B)
-    pack = G.pack_of(H)
+    pack = G.pack_of(H, DV)
     state_h = _normal(jax.random.PRNGKey(7), (SLOTS, H, DK, DV)
                       ).at[SLOTS - 1].set(0.0)
     pos = jnp.asarray([5, 0, 2, 9], jnp.int32)
@@ -183,7 +183,7 @@ def test_a_run_across_two_steps_is_the_run_in_one():
     """A request's runs follow each other across steps at any length: 37
     tokens, then 91 from the state the first left, against 128 at once."""
     q, k, v, g, beta = _draw(8, 128)
-    pack = G.pack_of(H)
+    pack = G.pack_of(H, DV)
     zeros = G.pack_state(jnp.zeros((2, H, DK, DV), jnp.float32), pack)
 
     def run(lo, hi, state):

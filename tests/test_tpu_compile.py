@@ -348,6 +348,40 @@ def test_gated_delta_kernels_compile_for_v5e(one_chip, mosaic, monkeypatch,
     assert ("gated_delta_chunk" in hlo) == planned
 
 
+@pytest.mark.parametrize("lanes,planned", [(128, False), (384, True)],
+                         ids=["burst-128-lanes", "mixed-step-384-lanes"])
+def test_kda_kernels_compile_for_v5e(one_chip, mosaic, monkeypatch, lanes,
+                                     planned):
+    """The KDA layers' kernels at the Solar-Open2 cell's shapes: 64 heads of
+    128 x 128 (one head fills a 128-lane row: no pairs), a decay a key
+    channel, 129 slots. A burst's 128 lanes run ``kda_step`` alone; a mixed
+    step's 384 lanes are planned inside the program and run both kernels,
+    ``kda_chunk`` over a dynamic number of chunks; neither is the scalar
+    decay's kernel."""
+    monkeypatch.setattr(gated_delta_rule, "_kernel_applies",
+                        lambda q, v, s: True)
+    H, d, slots = 64, 128, 129
+    assert gated_delta_rule.pack_of(H, d) == 1
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def run(q, k, v, g, beta, state, positions, rows, valid):
+        plan = gated_delta_rule.plan_runs(rows, positions, valid, slots) \
+            if planned else None
+        return gated_delta_rule.gated_delta(q, k, v, g, beta, state,
+                                            positions, plan)
+
+    hlo = jax.jit(run).lower(
+        sds((lanes, H, d)), sds((lanes, H, d)), sds((lanes, H, d)),
+        sds((lanes, H, d)), sds((lanes, H)), sds((slots, H, d, d)),
+        sds((lanes,), jnp.int32), sds((lanes,), jnp.int32),
+        sds((lanes,), jnp.bool_)).compile().as_text()
+    assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 1 + planned
+    assert "kda_step" in hlo and ("kda_chunk" in hlo) == planned
+    assert "gated_delta_step" not in hlo and "gated_delta_chunk" not in hlo
+
+
 def test_a_looped_stacks_programs_hold_each_layers_kernels_once(
         one_chip, mosaic, monkeypatch):
     """The compiled mixed step and burst of a tiny lane-aligned looped model
